@@ -54,4 +54,8 @@ def main(argv: Optional[List[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    if sys.argv[1:2] in (["kube-scheduler"], ["scheduler"]):
+        from ..utils import compile_cache
+
+        compile_cache.enable()
     sys.exit(main())

@@ -654,4 +654,9 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # the process entry point, not main(): tests call main() in-process
+    # and must never turn the persistent compilation cache on
+    from ..utils import compile_cache
+
+    compile_cache.enable()
     sys.exit(main())

@@ -119,3 +119,21 @@ def test_selector_spreading():
                    feat=feat)
     # must avoid n0 (it already holds a replica)
     assert snap.node_names[int(res.chosen[0])] != "n0"
+
+
+def test_graft_entry_step_jits():
+    """__graft_entry__.entry() hands out a step that traces under an
+    outer jax.jit (no host-side numpy read of a tracer) and computes
+    what the plain call computes."""
+    import jax
+
+    import __graft_entry__
+
+    fn, args = __graft_entry__.entry()
+    jitted = jax.jit(fn)(*args)
+    plain = fn(*args)
+    for field in ("chosen", "score", "feasible_count", "fail_counts",
+                  "rr_end"):
+        np.testing.assert_array_equal(np.asarray(getattr(jitted, field)),
+                                      np.asarray(getattr(plain, field)))
+    assert (np.asarray(jitted.chosen)[:8] >= 0).all()
