@@ -1,0 +1,13 @@
+"""commit_ms_per_kpod.drain: host time of the program's
+pipeline/committed step (the exact recheck, assume and bind of
+Scheduler._commit) accrued inside the window, per thousand pods bound
+in it. Window delta of the step profiler."""
+
+STEP = "pipeline/committed"
+
+
+def read(r):
+    n = r.window_binds()
+    if r.cell["traffic"]["loop"] != "closed" or not n or STEP not in r.step_delta:
+        return None
+    return 1000.0 * r.step_delta[STEP] / (n / 1000.0)
