@@ -1931,46 +1931,6 @@ def run_hetero_config(nodes, pods, wave, mesh=None, margin=HETERO_MARGIN):
     return placed_s + placed_c, dt, compact_racks, scattered_racks, skew
 
 
-def stage_breakdown(top=12):
-    """Per-stage wall-time totals from the step profiler (fed by every
-    Trace the scheduler emits) — the bench json carries WHERE the run's
-    seconds went, not just the throughput. Includes warm-up/fill phases:
-    this attributes the whole process's scheduling work."""
-    from kubernetes_tpu.utils import profiling
-
-    prof = profiling.active()
-    if prof is None:
-        return None
-    return {k: round(v, 3) for k, v in prof.step_totals(top=top).items()}
-
-
-def telemetry_trajectory(max_points=32):
-    """Fragmentation/utilization trajectory from the flight recorder's
-    round ledger (present when --telemetry enabled the recorder): the
-    last ring-buffer's worth of rounds, downsampled to max_points —
-    enough to see whether a drain fragments the cluster as it fills."""
-    from kubernetes_tpu.utils import tracing
-
-    rec = tracing.active()
-    if rec is None:
-        return None
-    rows = [r["telemetry"] for r in rec.ledger_rows() if "telemetry" in r]
-    if not rows:
-        return None
-    if len(rows) > max_points:
-        step = (len(rows) - 1) / (max_points - 1)
-        rows_s = [rows[round(i * step)] for i in range(max_points)]
-    else:
-        rows_s = rows
-    return {
-        "rounds": len(rows),
-        "cpu_util": [t["util"].get("cpu") for t in rows_s],
-        "cpu_frag": [t["frag"].get("cpu") for t in rows_s],
-        "mem_frag": [t["frag"].get("memory") for t in rows_s],
-        "headroom_final": rows[-1]["headroom"],
-    }
-
-
 def emit(name, nodes, pods, placed, dt, p99, p99_round, wave, path="?"):
     if placed != pods:
         print(f"FATAL: {name}: placed {placed}/{pods}", file=sys.stderr)
@@ -1987,12 +1947,6 @@ def emit(name, nodes, pods, placed, dt, p99, p99_round, wave, path="?"):
         # comparable across configs without unifying the knob
         "wave": wave,
     }
-    stages = stage_breakdown()
-    if stages:
-        rec["stages"] = stages
-    tele = telemetry_trajectory()
-    if tele:
-        rec["telemetry"] = tele
     if _SHADOW_SUMMARY:
         # per-candidate-profile counterfactual divergence over the whole
         # run (--shadow profile.json): {profile: {pods, flips,
@@ -2115,7 +2069,7 @@ DRIVER_SUITE = [
 
 
 def run_subprocess_suite(suite, wave, cpu, tracing=False, trace_ledger=None,
-                         telemetry=False, shadow=None):
+                         shadow=None):
     # one subprocess per config, and this parent never touches JAX: one
     # process owns the chip, so each config's child holds it alone and
     # starts from a fresh runtime and a fresh scheduler
@@ -2131,8 +2085,6 @@ def run_subprocess_suite(suite, wave, cpu, tracing=False, trace_ledger=None,
         cmd += extra
         if tracing:
             cmd.append("--tracing")
-        if telemetry:
-            cmd.append("--telemetry")
         if shadow:
             # threaded through every child: configs that drain through
             # run_config shadow-score the run and emit the divergence
@@ -2226,11 +2178,6 @@ def main():
     ap.add_argument("--trace-ledger", default=None,
                     help="append per-round JSONL ledger records here "
                          "(implies --tracing)")
-    ap.add_argument("--telemetry", action="store_true",
-                    help="per-round cluster-state telemetry (implies "
-                         "--tracing): the emitted JSON lines carry "
-                         "fragmentation/utilization trajectories and "
-                         "final feasibility headroom")
     ap.add_argument("--shadow", default=None, metavar="PROFILE_JSON",
                     help="shadow-score the run under the candidate "
                          "WeightProfiles in this JSON file (implies "
@@ -2258,14 +2205,12 @@ def main():
         run_subprocess_suite(SUITE, args.wave, args.cpu,
                              tracing=args.tracing,
                              trace_ledger=args.trace_ledger,
-                             telemetry=args.telemetry,
                              shadow=args.shadow)
         return
     if not explicit:
         run_subprocess_suite(DRIVER_SUITE, args.wave, args.cpu,
                              tracing=args.tracing,
                              trace_ledger=args.trace_ledger,
-                             telemetry=args.telemetry,
                              shadow=args.shadow)
         return
 
@@ -2285,13 +2230,9 @@ def main():
 
     compile_cache.enable()
 
-    # the step profiler feeds the per-stage wall-time breakdown in the
-    # emitted json; the flight recorder is opt-in (its off-cost is one
-    # attribute read per site)
-    from kubernetes_tpu.utils import profiling
-
-    profiling.enable()
-    if args.tracing or args.trace_ledger or args.telemetry or args.shadow:
+    # the flight recorder is opt-in (its off-cost is one attribute read
+    # per site)
+    if args.tracing or args.trace_ledger or args.shadow:
         # --shadow implies tracing: the shadow pass re-weights the
         # per-priority decomposition, which only rides out of traced
         # rounds
@@ -2401,9 +2342,6 @@ def main():
                             if high_p99 > 0 else 0.0),
             "wave": args.wave,
         }
-        stages = stage_breakdown()
-        if stages:
-            rec["stages"] = stages
         if _MESH_SUMMARY:
             rec["mesh"] = _MESH_SUMMARY
         print(json.dumps(rec), flush=True)
@@ -2452,12 +2390,6 @@ def main():
             "vs_baseline": round(5.0 / p99, 2) if p99 > 0 else 0.0,
             "wave": args.wave,
         }
-        stages = stage_breakdown()
-        if stages:
-            rec["stages"] = stages
-        tele = telemetry_trajectory()
-        if tele:
-            rec["telemetry"] = tele
         print(json.dumps(rec), flush=True)
         print(f"# {name}: placed={placed} wall={dt:.2f}s "
               f"offered={offered:.0f}pods/s (target {args.rate:.0f}) "

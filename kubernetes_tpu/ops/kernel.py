@@ -354,64 +354,67 @@ def _wave_body(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
     P = pb.req.shape[0]
     R = nt.alloc.shape[1]
     is_core = jnp.arange(R) < enc.RES_FIXED
-    masks = static_predicate_masks(nt, pb, is_core, use_pallas,
-                                   pallas_interpret,
-                                   taint_ports)  # [Q-1, P, N]
-    # placeholder rows for the scan-filled predicates (PodTopologySpread,
-    # MatchInterPodAffinity), in DEVICE_PREDICATES order
-    ts_placeholder = jnp.ones((1, P, N), bool)
-    ipa_placeholder = jnp.ones((1, P, N), bool)
-    masks = jnp.concatenate([masks, ts_placeholder, ipa_placeholder,
-                             extra_mask[None]], axis=0)
-    res_i = enc.PRED_IDX["PodFitsResources"]
-    ipa_i = enc.PRED_IDX["MatchInterPodAffinity"]
-    ts_i = enc.PRED_IDX["PodTopologySpread"]
-    static_nonres = jnp.all(masks.at[res_i].set(True), axis=0)  # [P, N]
-    alloc2 = nt.alloc[:, :2]
-    ipa = (incoming_statics(nt, pm, tt, pb, num_label_values,
-                            weights.hard_pod_affinity)
-           if has_ipa else None)
-    topo = (topo_statics(nt, pm, pb, num_label_values) if has_ts else None)
-    lv_ids = jnp.arange(num_label_values, dtype=jnp.int32)
+    # the per-wave dense work ahead of the serial scan, named for the
+    # profiler (benchmark/program_trace.py reads the scopes)
+    with jax.named_scope("wave_dense"):
+        masks = static_predicate_masks(nt, pb, is_core, use_pallas,
+                                       pallas_interpret,
+                                       taint_ports)  # [Q-1, P, N]
+        # placeholder rows for the scan-filled predicates (PodTopologySpread,
+        # MatchInterPodAffinity), in DEVICE_PREDICATES order
+        ts_placeholder = jnp.ones((1, P, N), bool)
+        ipa_placeholder = jnp.ones((1, P, N), bool)
+        masks = jnp.concatenate([masks, ts_placeholder, ipa_placeholder,
+                                 extra_mask[None]], axis=0)
+        res_i = enc.PRED_IDX["PodFitsResources"]
+        ipa_i = enc.PRED_IDX["MatchInterPodAffinity"]
+        ts_i = enc.PRED_IDX["PodTopologySpread"]
+        static_nonres = jnp.all(masks.at[res_i].set(True), axis=0)  # [P, N]
+        alloc2 = nt.alloc[:, :2]
+        ipa = (incoming_statics(nt, pm, tt, pb, num_label_values,
+                                weights.hard_pod_affinity)
+               if has_ipa else None)
+        topo = (topo_statics(nt, pm, pb, num_label_values) if has_ts else None)
+        lv_ids = jnp.arange(num_label_values, dtype=jnp.int32)
 
-    w = weights
-    # the weighted-sum multipliers: the traced weight_vec when the live
-    # profile machinery supplies one, the static weights folded to a
-    # trace-time constant otherwise — wv[s] is an f32 scalar either way,
-    # so the arithmetic (and the twin's mirror of it) is identical
-    wv = (weight_vec if weight_vec is not None
-          else jnp.asarray(stack_weights(w)))
-    # raw planes also feed the decomposition: under collect_scores they
-    # are computed even at weight 0 (a 0-weight priority still explains
-    # the decision it did not influence — zeroed planes would fabricate
-    # flat 0 / MAX_PRIORITY rows in /debug/score and the ledger)
-    aff_raw = (node_affinity_raw(nt, pb)
-               if w.node_affinity or collect_scores else None)
-    taint_raw = (taint_intolerable_raw(nt, pb)
-                 if w.taint_toleration or collect_scores else None)
-    spread_cnt = (spread_counts(pm, pb, N)
-                  if w.selector_spread or collect_scores
-                  else jnp.zeros(static_nonres.shape, jnp.int32))
-    static_score = jnp.zeros(static_nonres.shape, jnp.float32)
-    if w.image_locality:
-        static_score = static_score + wv[W_IMAGE] * image_locality(nt, pb)
-    if w.prefer_avoid:
-        static_score = static_score + wv[W_AVOID] * prefer_avoid(nt, pb)
-    if extra_scores is not None:
-        static_score += extra_scores
-    P = pb.req.shape[0]
-    if aff_raw is None:
-        aff_raw = jnp.zeros((P, N), jnp.float32)
-    if taint_raw is None:
-        taint_raw = jnp.zeros((P, N), jnp.float32)
-    if collect_scores:
-        # RAW per-priority planes for the decomposition, computed
-        # regardless of weights (a 0-weight priority still explains the
-        # decision it did not influence); never folded into the total
-        avoid_full = prefer_avoid(nt, pb)
-        img_full = image_locality(nt, pb)
-        extra_full = (extra_scores if extra_scores is not None
-                      else jnp.zeros((P, N), jnp.float32))
+        w = weights
+        # the weighted-sum multipliers: the traced weight_vec when the live
+        # profile machinery supplies one, the static weights folded to a
+        # trace-time constant otherwise — wv[s] is an f32 scalar either way,
+        # so the arithmetic (and the twin's mirror of it) is identical
+        wv = (weight_vec if weight_vec is not None
+              else jnp.asarray(stack_weights(w)))
+        # raw planes also feed the decomposition: under collect_scores they
+        # are computed even at weight 0 (a 0-weight priority still explains
+        # the decision it did not influence — zeroed planes would fabricate
+        # flat 0 / MAX_PRIORITY rows in /debug/score and the ledger)
+        aff_raw = (node_affinity_raw(nt, pb)
+                   if w.node_affinity or collect_scores else None)
+        taint_raw = (taint_intolerable_raw(nt, pb)
+                     if w.taint_toleration or collect_scores else None)
+        spread_cnt = (spread_counts(pm, pb, N)
+                      if w.selector_spread or collect_scores
+                      else jnp.zeros(static_nonres.shape, jnp.int32))
+        static_score = jnp.zeros(static_nonres.shape, jnp.float32)
+        if w.image_locality:
+            static_score = static_score + wv[W_IMAGE] * image_locality(nt, pb)
+        if w.prefer_avoid:
+            static_score = static_score + wv[W_AVOID] * prefer_avoid(nt, pb)
+        if extra_scores is not None:
+            static_score += extra_scores
+        P = pb.req.shape[0]
+        if aff_raw is None:
+            aff_raw = jnp.zeros((P, N), jnp.float32)
+        if taint_raw is None:
+            taint_raw = jnp.zeros((P, N), jnp.float32)
+        if collect_scores:
+            # RAW per-priority planes for the decomposition, computed
+            # regardless of weights (a 0-weight priority still explains the
+            # decision it did not influence); never folded into the total
+            avoid_full = prefer_avoid(nt, pb)
+            img_full = image_locality(nt, pb)
+            extra_full = (extra_scores if extra_scores is not None
+                          else jnp.zeros((P, N), jnp.float32))
 
     usage0 = usage_in if usage_in is not None else (
         nt.requested, nt.nonzero, nt.pod_count)
@@ -629,8 +632,9 @@ def _wave_body(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
                    topo.counts, topo.present, topo.wm, topo.selfm)
     if collect_scores:
         xs = xs + (avoid_full, img_full, extra_full)
-    (req_end, nz_end, cnt_end, rr_end, _), outs = \
-        lax.scan(step, carry0, xs)
+    with jax.named_scope("pod_scan"):
+        (req_end, nz_end, cnt_end, rr_end, _), outs = \
+            lax.scan(step, carry0, xs)
     chosen, best, dyn_fits, feas_cnt, ipa_masks = outs[:5]
     rest = outs[5:]
     ts_masks = None
@@ -821,7 +825,9 @@ def _schedule_round(nt: enc.NodeTensors, pm: enc.PodMatrix,
                                   usage_in=usage_c, taint_ports=tp,
                                   collect_scores=collect_scores,
                                   weight_vec=weight_vec, has_ts=has_ts)
-        pm_o, tt_o = _stage_placements(pm_c, tt_c, res.chosen, rows, trows)
+        with jax.named_scope("stage_placements"):
+            pm_o, tt_o = _stage_placements(pm_c, tt_c, res.chosen, rows,
+                                           trows)
         out = (res.chosen, res.fail_counts)
         if collect_scores:
             out = out + tuple(res.deco)
@@ -833,17 +839,18 @@ def _schedule_round(nt: enc.NodeTensors, pm: enc.PodMatrix,
         # executes one branch): without this, a padded ipa wave still
         # pays the full O(P*M) precompute — 31 pad waves in a 1-wave
         # warm round cost ~25s of device time for nothing
-        out = (jnp.full((P,), -1, jnp.int32),
-               jnp.zeros((Q, P), jnp.int32))
-        if collect_scores:
-            # pad-wave deco: top_vals at -1 read as "infeasible" so the
-            # host consumer skips them without a special case
-            out = out + (jnp.zeros((P, S), jnp.float32),
-                         jnp.zeros((P, KK), jnp.int32),
-                         jnp.full((P, KK), -1.0, jnp.float32),
-                         jnp.zeros((P, S, KK), jnp.float32))
-        # pad waves schedule nothing: their sentinel rows are clean
-        out = out + (jnp.ones((P,), bool),)
+        with jax.named_scope("pad_wave"):
+            out = (jnp.full((P,), -1, jnp.int32),
+                   jnp.zeros((Q, P), jnp.int32))
+            if collect_scores:
+                # pad-wave deco: top_vals at -1 read as "infeasible" so the
+                # host consumer skips them without a special case
+                out = out + (jnp.zeros((P, S), jnp.float32),
+                             jnp.zeros((P, KK), jnp.int32),
+                             jnp.full((P, KK), -1.0, jnp.float32),
+                             jnp.zeros((P, S, KK), jnp.float32))
+            # pad waves schedule nothing: their sentinel rows are clean
+            out = out + (jnp.ones((P,), bool),)
         return carry, out
 
     active = jnp.any(pbs.valid, axis=1)  # [W]
@@ -856,23 +863,24 @@ def _schedule_round(nt: enc.NodeTensors, pm: enc.PodMatrix,
         # i32 tiles (guide: ~16MB VMEM/core; 256x512x4B = 512KB/tile),
         # so larger flat batches risk VMEM exhaustion for zero gain
         # (the launches all live inside this one compiled program)
-        waves_per_chunk = max(1, 256 // P)
-        t_parts, p_parts = [], []
-        for s in range(0, W, waves_per_chunk):
-            e = min(W, s + waves_per_chunk)
-            flat = pbs._replace(
-                req=pbs.req[s:e].reshape((e - s) * P, -1),
-                tol_key=pbs.tol_key[s:e].reshape((e - s) * P, -1),
-                tol_val=pbs.tol_val[s:e].reshape((e - s) * P, -1),
-                tol_op=pbs.tol_op[s:e].reshape((e - s) * P, -1),
-                tol_effect=pbs.tol_effect[s:e].reshape((e - s) * P, -1),
-                ports=pbs.ports[s:e].reshape((e - s) * P, -1))
-            t, po = taint_ports_masks(nt, flat,
-                                      interpret=pallas_interpret)
-            t_parts.append(t.reshape(e - s, P, N))
-            p_parts.append(po.reshape(e - s, P, N))
-        taints_all = jnp.concatenate(t_parts, axis=0)
-        ports_all = jnp.concatenate(p_parts, axis=0)
+        with jax.named_scope("taint_ports"):
+            waves_per_chunk = max(1, 256 // P)
+            t_parts, p_parts = [], []
+            for s in range(0, W, waves_per_chunk):
+                e = min(W, s + waves_per_chunk)
+                flat = pbs._replace(
+                    req=pbs.req[s:e].reshape((e - s) * P, -1),
+                    tol_key=pbs.tol_key[s:e].reshape((e - s) * P, -1),
+                    tol_val=pbs.tol_val[s:e].reshape((e - s) * P, -1),
+                    tol_op=pbs.tol_op[s:e].reshape((e - s) * P, -1),
+                    tol_effect=pbs.tol_effect[s:e].reshape((e - s) * P, -1),
+                    ports=pbs.ports[s:e].reshape((e - s) * P, -1))
+                t, po = taint_ports_masks(nt, flat,
+                                          interpret=pallas_interpret)
+                t_parts.append(t.reshape(e - s, P, N))
+                p_parts.append(po.reshape(e - s, P, N))
+            taints_all = jnp.concatenate(t_parts, axis=0)
+            ports_all = jnp.concatenate(p_parts, axis=0)
 
         def wave(carry, x):
             pb, rows, trows, act, ta, po = x

@@ -46,7 +46,7 @@ from ..state.featurize import PodFeaturizeError, PodFeaturizer
 from ..state.scrubber import SnapshotScrubber
 from ..state.snapshot import Snapshot
 from ..utils import (Metrics, PodBackoff, Trace, bounded_label, faultpoints,
-                     tracing)
+                     profiling, tracing)
 from ..utils.watchdog import DispatchTimeout
 from ..utils.feature_gates import FeatureGates
 from . import breaker as breaker_mod
@@ -84,6 +84,26 @@ PIPELINE_MAX_WAVES_IPA = 64
 # exact host validation (selectVictimsOnNode) per failed pod
 PREEMPT_LEVELS = 8
 PREEMPT_HOST_CANDIDATES = 8
+# the steps each Trace takes, in order: each interval between them is a
+# profiler span named "<phase>/<step>" (utils/trace.py)
+PIPELINE_STEPS = ("featurized+staged", "uploaded", "dispatched", "executed",
+                  "fetched", "committed")
+WAVE_STEPS = ("featurized", "device wave", "committed")
+HOST_WAVE_STEPS = ("featurized", "host wave", "committed")
+PREEMPT_STEPS = ("featurized+uploaded", "dispatched", "fetched",
+                 "validated+performed")
+PREEMPT_HOST_STEPS = ("host what-if", "fetched", "validated+performed")
+# the parts of _commit timed per pipeline round while the step profiler
+# is on, recorded as steps of the "commit" phase
+COMMIT_PARTS = ("recheck", "assume", "bind")
+
+
+def _accrue(parts: List[float], i: int, since: float) -> float:
+    """Add the perf-counter seconds since `since` to parts[i]; returns
+    now."""
+    now = time.perf_counter()
+    parts[i] += now - since
+    return now
 
 
 def pipeline_bucket(n_waves: int, lo: int = 4,
@@ -482,6 +502,9 @@ class Scheduler:
         self._inflight_mu = threading.Lock()
         self._inflight: set = set()
         self.bind_overlap_hwm = 0  # high-water mark of concurrent binds
+        # per-part seconds of the pipeline round's commits, while the
+        # step profiler is on (see _commit); None otherwise
+        self._commit_parts: Optional[List[float]] = None
         # live weight profiles + the shadow-scoring observatory
         # (sched/weights.py): the production weight vector is served
         # from here as a TRACED array (hot-swap/rollback between rounds,
@@ -1578,7 +1601,8 @@ class Scheduler:
 
         from ..ops.kernel import schedule_round
 
-        trace = Trace(f"pipeline of {len(pods)}", clock=self.clock)
+        trace = Trace(f"pipeline of {len(pods)}", clock=self.clock,
+                      steps=PIPELINE_STEPS)
         start = self.clock()
         # the ADAPTIVE cap, not wave_size: host-stage overruns under
         # wave_deadline_s shrink it (see _account_host_overrun); they
@@ -1730,6 +1754,7 @@ class Scheduler:
         wbucket = pipeline_bucket(nw, hi=max_waves)
         pbs_stacked, pm_rows, term_rows = assemble_round(
             pbs, waves, pm_rows_all, term_rows_all, wbucket, tpp)
+        trace.annotate(pods=len(pods), waves=nw, bucket=wbucket)
         if self._active_mesh is not None:
             # pod batches / staged row ids / the rr carry replicate over
             # the mesh; the node tensors (and the usage carry derived
@@ -1896,29 +1921,43 @@ class Scheduler:
         placed = 0
         committed: set = set()
         retry: List[api.Pod] = []
-        for wi, wv in enumerate(waves):
-            for i, pod in enumerate(wv):
-                self.metrics.schedule_attempts.inc()
-                node_idx = int(chosen_all[wi, i])
-                if node_idx >= 0:
-                    node_name = self.snapshot.node_names[node_idx]
-                    if self._commit(pod, node_name):
-                        placed += 1
-                        committed.add(pod.uid)
-                        continue
-                # device placement rejected by the exact recheck, or the
-                # pod failed on device: batched device preemption handles
-                # resource-starved failures below; everything else goes
-                # back through the per-wave path for exact attribution
-                self.snapshot.unstage(pod)
-                retry.append(pod)
+        prof = profiling.active()
+        parts = [0.0] * len(COMMIT_PARTS) if prof is not None else None
+        self._commit_parts = parts
+        try:
+            for wi, wv in enumerate(waves):
+                for i, pod in enumerate(wv):
+                    self.metrics.schedule_attempts.inc()
+                    node_idx = int(chosen_all[wi, i])
+                    if node_idx >= 0:
+                        node_name = self.snapshot.node_names[node_idx]
+                        if self._commit(pod, node_name):
+                            placed += 1
+                            committed.add(pod.uid)
+                            continue
+                    # device placement rejected by the exact recheck, or
+                    # the pod failed on device: batched device preemption
+                    # handles resource-starved failures below; everything
+                    # else goes back through the per-wave path for exact
+                    # attribution
+                    self.snapshot.unstage(pod)
+                    retry.append(pod)
+        finally:
+            self._commit_parts = None
         if rt is not None:
             rt.mark("commit", placed=placed)
         handled = self._pipeline_preempt(retry) if retry else set()
         for pod in retry:
             if pod.uid not in handled:
                 self.queue.add_if_not_present(pod)
-        trace.step("committed")
+        commit_meta = {}
+        if parts is not None:
+            # recorded before the round's last step, so a window the step
+            # profiler's hook closes at that step holds them
+            for part, s in zip(COMMIT_PARTS, parts):
+                prof.record_step("commit", part, s)
+                commit_meta[part + "_s"] = s
+        trace.step("committed", **commit_meta)
         self.metrics.e2e_scheduling_latency.observe(self.clock() - start)
         self.metrics.waves_total.labels(path="device").inc(len(waves))
         if rt is not None:
@@ -2008,7 +2047,8 @@ class Scheduler:
         from ..ops.preempt import PreemptStats
 
         t0 = self.clock()
-        trace = Trace(f"preempt chunk of {len(cands)}", clock=self.clock)
+        trace = Trace(f"preempt chunk of {len(cands)}", clock=self.clock,
+                      steps=PREEMPT_HOST_STEPS if host else PREEMPT_STEPS)
         pb, cands = self._featurize_guarded(cands)
         if not cands:
             return set()
@@ -2291,7 +2331,8 @@ class Scheduler:
 
         if not pods:
             return 0
-        trace = Trace(f"host wave of {len(pods)}", clock=self.clock)
+        trace = Trace(f"host wave of {len(pods)}", clock=self.clock,
+                      steps=HOST_WAVE_STEPS)
         start = self.clock()
         for _p in pods:
             self.metrics.schedule_attempts.inc()
@@ -3044,7 +3085,8 @@ class Scheduler:
                 if golden:
                     tracing.event("golden_gap", **golden)
                 return placed_host
-        trace = Trace(f"wave of {len(pods)}", clock=self.clock)
+        trace = Trace(f"wave of {len(pods)}", clock=self.clock,
+                      steps=WAVE_STEPS)
         start = self.clock()
         # ONE weight view per round (see _run_pipeline)
         gating, wvec, wver = self._weights_kw()
@@ -3910,9 +3952,21 @@ class Scheduler:
 
         With the VolumeScheduling gate on, the pod's unbound PVCs are
         bound to node-compatible PVs first (scheduler.go:268
-        assumeAndBindVolumes); a later bind failure rolls them back."""
+        assumeAndBindVolumes); a later bind failure rolls them back.
+
+        While a pipeline round sets _commit_parts, the perf-counter
+        seconds of the recheck, the assume (volumes, cache and snapshot)
+        and the bind (the inline bind with whatever the store runs
+        inline, or the hand-off to the bind pool) are added to its
+        entries, in COMMIT_PARTS order."""
+        parts = self._commit_parts
+        timed = parts is not None
+        t = time.perf_counter() if timed else 0.0
         ni = self.cache.node_infos.get(node_name)
-        if ni is None or not ni.fits_exactly(pod):
+        fits = ni is not None and ni.fits_exactly(pod)
+        if timed:
+            t = _accrue(parts, 0, t)
+        if not fits:
             return False
         vol_rollback = None
         if (self.features.enabled("VolumeScheduling")
@@ -3920,21 +3974,29 @@ class Scheduler:
             ok, vol_rollback = self.volume_binder.bind_pod_volumes(
                 pod, ni.node)
             if not ok:
+                if timed:
+                    _accrue(parts, 1, t)
                 return False
         bound = api.with_node_name(pod, node_name)
         self.cache.assume_pod(bound)
         self.snapshot.refresh_node_resources(self.cache.node_infos[node_name])
         self.snapshot.add_pod(bound)
+        if timed:
+            t = _accrue(parts, 1, t)
         if self._bind_pool is None:
-            return self._bind_and_finish(pod, bound, node_name, vol_rollback)
-        fut = self._bind_pool.submit(self._bind_and_finish, pod, bound,
-                                     node_name, vol_rollback)
-        with self._inflight_mu:
-            self._inflight.add(fut)
-            self.bind_overlap_hwm = max(self.bind_overlap_hwm,
-                                        len(self._inflight))
-        fut.add_done_callback(self._bind_done)
-        return True
+            ok = self._bind_and_finish(pod, bound, node_name, vol_rollback)
+        else:
+            fut = self._bind_pool.submit(self._bind_and_finish, pod, bound,
+                                         node_name, vol_rollback)
+            with self._inflight_mu:
+                self._inflight.add(fut)
+                self.bind_overlap_hwm = max(self.bind_overlap_hwm,
+                                            len(self._inflight))
+            fut.add_done_callback(self._bind_done)
+            ok = True
+        if timed:
+            _accrue(parts, 2, t)
+        return ok
 
     def _bind_done(self, fut):
         with self._inflight_mu:
