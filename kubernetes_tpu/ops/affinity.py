@@ -18,10 +18,14 @@ Dense shape of the problem:
   * The incoming pod's required terms collapse to one combined AND
     program (metadata semantics match ALL term properties at once) with
     a single shared topology key; satisfaction is anchored through the
-    label-value vocabulary: segment-reduce matching pods by the domain
-    value of their node ([P, LV]), then gather at each node's domain
-    value ([P, N]). Pods whose required terms span >1 topology key take
-    the exact host path (plugins/golden.py) instead.
+    node axis, then the label-value vocabulary. A pod's domain is a
+    function of its node, so matching pods are first counted per node
+    by one segment-sum keyed by each pod's node, shared by every program
+    ([U, M] -> [U, N]); those counts are reduced by each node's domain
+    value ([U, LV]), then gathered at each node's domain value
+    ([U, N]). The sums are integer-valued f32, exact in any order. Pods
+    whose required terms span >1 topology key take the exact host path
+    (plugins/golden.py) instead.
   * Wave-internal visibility (a pod must see placements made earlier in
     the same wave, like the reference's one-at-a-time assume) is handled
     in the commit scan in ops/kernel.py using [P, P] cross-match
@@ -113,16 +117,27 @@ class IncomingStatics(NamedTuple):
     wm_anti: jnp.ndarray  # bool [P, P] wave pod j matches pod i's anti props
 
 
-def _anchored_hit(match, dom_m, num_segments, count=False):
-    """match: bool [P, M]; dom_m: i32 [P, M] domain value of each matching
-    pod's node. Segment-reduce over the label-value vocab:
-    returns [P, LV] (bool any, or f32 counts)."""
-    contrib = (match & (dom_m > 0)).astype(jnp.float32)
+def node_counts(match, node, num_nodes):
+    """f32 [B, N] — matching pods per node. match: bool [B, M]; node:
+    i32 [M] each pod's node. Every row b shares the index, so this is
+    one segment-sum of [M, B] rows: M row updates B lanes wide, not B·M
+    scalar ones. A freed pod-matrix row keeps its stale node, so `match`
+    must carry the row's validity."""
+    rows = match.T.astype(jnp.float32)
+    return jax.ops.segment_sum(rows, node, num_segments=num_nodes).T
+
+
+def _anchored_hit(node_cnt, node_dom, num_segments, count=False):
+    """node_cnt: [B, N] matching pods (or bool presence) per node;
+    node_dom: i32 [B, N] each node's domain value under row b's topology
+    key, 0 = key absent. Segment-reduce the nodes over the label-value
+    vocab: returns [B, LV] (bool any, or f32 counts)."""
+    contrib = jnp.where(node_dom > 0, node_cnt.astype(jnp.float32), 0.0)
 
     def seg(row, dom):
         return jax.ops.segment_sum(row, dom, num_segments=num_segments)
 
-    hit = jax.vmap(seg)(contrib, dom_m)
+    hit = jax.vmap(seg)(contrib, node_dom)
     return hit if count else hit > 0.5
 
 
@@ -142,9 +157,19 @@ def incoming_statics(nt: NodeTensors, pm: PodMatrix, tt: TermTable,
     u_sel = _eval_programs(pm.labels, pb.iu_key, pb.iu_op, pb.iu_vals)  # [U, M]
     u_m = u_sel & ns_match(pb.iu_ns, pm.ns) & pm.valid[None, :]
     node_dom_u = node_domains(nt, pb.iu_tk)  # [U, N]
-    dom_m_u = jnp.take_along_axis(
-        node_dom_u, jnp.broadcast_to(pm.node[None, :], u_m.shape), axis=1)
-    hit_u = _anchored_hit(u_m, dom_m_u, num_label_values)  # [U, LV]
+    # incoming pods' preferred terms, the same way (unique table pb.pu_*)
+    pu_sel = _eval_programs(pm.labels, pb.pu_key, pb.pu_op, pb.pu_vals)
+    pu_m = pu_sel & ns_match(pb.pu_ns, pm.ns) & pm.valid[None, :]  # [UP, M]
+    dom_pu = node_domains(nt, pb.pu_tk)  # [UP, N]
+    U = u_m.shape[0]
+    with jax.named_scope("affinity_anchor"):
+        # both tables' matching pods per node in one shared-index scatter
+        cnt_n = node_counts(jnp.concatenate([u_m, pu_m]), pm.node,
+                            nt.valid.shape[0])  # [U + UP, N]
+        hit_u = _anchored_hit(cnt_n[:U], node_dom_u,
+                              num_label_values)  # [U, LV]
+        cnt_u = _anchored_hit(cnt_n[U:], dom_pu, num_label_values,
+                              count=True)  # [UP, LV]
     # "a matching pod exists in node n's domain" per unique program
     ok_u = jnp.take_along_axis(hit_u, node_dom_u, axis=1) & (node_dom_u > 0)
     any_u = jnp.any(u_m, axis=1)  # [U]
@@ -164,14 +189,8 @@ def incoming_statics(nt: NodeTensors, pm: PodMatrix, tt: TermTable,
         [jnp.full_like(tt.weight, hard_weight), tt.weight, -tt.weight],
         default=jnp.zeros_like(tt.weight))
     counts = (em.astype(jnp.float32) * we[None, :]) @ sd.astype(jnp.float32)
-    # incoming pods' preferred terms: unique-table evaluation, then a
-    # per-slot gather + weight (weights stay per-pod in pa_w)
-    pu_sel = _eval_programs(pm.labels, pb.pu_key, pb.pu_op, pb.pu_vals)
-    pu_m = pu_sel & ns_match(pb.pu_ns, pm.ns) & pm.valid[None, :]  # [UP, M]
-    dom_pu = node_domains(nt, pb.pu_tk)  # [UP, N]
-    dom_m_pu = jnp.take_along_axis(
-        dom_pu, jnp.broadcast_to(pm.node[None, :], pu_m.shape), axis=1)
-    cnt_u = _anchored_hit(pu_m, dom_m_pu, num_label_values, count=True)
+    # incoming pods' preferred terms: per-slot gather of the unique
+    # table's counts + weight (weights stay per-pod in pa_w)
     cnt_node_u = (jnp.take_along_axis(cnt_u, dom_pu, axis=1)
                   * (dom_pu > 0))  # [UP, N]
     PA = pb.pa_w.shape[1]

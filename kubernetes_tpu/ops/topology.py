@@ -13,10 +13,11 @@ Dense shape of the problem:
     per-constraint rows (state/featurize.py): a topology-key column id,
     maxSkew, a hard/soft flag and an AND selector program over POD
     labels. Resident matching-pod counts per topology-domain VALUE are
-    one batched segment-sum over the pod matrix anchored through the
-    label-value vocabulary — the exact shape of ops/affinity.py's
-    `_anchored_hit` (and the zone tally in ops/zonehealth.py,
-    generalized from the fixed zone column to arbitrary label keys).
+    anchored as in ops/affinity.py: matching pods counted per node by
+    one segment-sum keyed by each pod's node (`node_counts`), then the
+    nodes segment-reduced by their domain value (`_anchored_hit`; the
+    zone tally in ops/zonehealth.py, generalized from the fixed zone
+    column to arbitrary label keys).
   * Per-node skew is then a gather at each node's domain value; global
     min/max match counts reduce over the domain values PRESENT among
     valid nodes (upstream's "global minimum matchNum"; domains are
@@ -48,9 +49,10 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
-from .affinity import _anchored_hit, _eval_programs, node_domains
+from .affinity import _anchored_hit, _eval_programs, node_counts, node_domains
 from .encoding import NodeTensors, PodBatch, PodMatrix
 
 
@@ -72,9 +74,9 @@ def topo_statics(nt: NodeTensors, pm: PodMatrix, pb: PodBatch,
     match = selector(existing pod labels) & same-namespace & live, per
     constraint row (upstream counts only the constraint owner's
     namespace; a nil selector was featurized as OP_FALSE and matches
-    nothing). Counts segment-reduce the matches by the domain value of
-    each pod's node; `present` segment-reduces valid nodes themselves so
-    empty domains still participate in the min (upstream enumerates
+    nothing). Counts reduce the matches per node, then the nodes by
+    their domain value; `present` segment-reduces valid nodes themselves
+    so empty domains still participate in the min (upstream enumerates
     domains from the node list, not the pod list)."""
     P, TS = pb.ts_tk.shape
     N = nt.labels.shape[0]
@@ -87,13 +89,12 @@ def topo_statics(nt: NodeTensors, pm: PodMatrix, pb: PodBatch,
     same_ns = (pm.ns[None, None, :] == pb.ns_id[:, None, None])
     match = sel & same_ns & (pm.valid & pm.alive)[None, None, :] & live
     M = pm.labels.shape[0]
-    dom_m = jnp.take_along_axis(
-        dom_f, jnp.broadcast_to(pm.node[None, :], (P * TS, M)), axis=1)
-    counts = _anchored_hit(match.reshape(P * TS, M), dom_m,
-                           num_label_values, count=True)
-    present = _anchored_hit(
-        jnp.broadcast_to(nt.valid[None, :], (P * TS, N)), dom_f,
-        num_label_values)
+    with jax.named_scope("affinity_anchor"):
+        cnt_n = node_counts(match.reshape(P * TS, M), pm.node, N)
+        counts = _anchored_hit(cnt_n, dom_f, num_label_values, count=True)
+        present = _anchored_hit(
+            jnp.broadcast_to(nt.valid[None, :], (P * TS, N)), dom_f,
+            num_label_values)
 
     wsel = _eval_programs(pb.pl_val, pb.ts_key, pb.ts_op, pb.ts_vals)  # [P, TS, P]
     wave_ns = (pb.ns_id[None, None, :] == pb.ns_id[:, None, None])

@@ -486,6 +486,99 @@ class TestInterPodAffinityTwin:
         cache, snap = build(nodes, existing)
         return rng, snap, pods
 
+    @staticmethod
+    def _anchor_world(seed, n_nodes=12, n_existing=40, n_pods=16):
+        """World for the node-first anchoring of the statics: matching
+        existing pods stacked several to a node, deleted pods whose freed
+        pod-matrix rows keep their stale node, nodes without the zone
+        key, and required and preferred terms on both the hostname and
+        the zone key."""
+        import random
+
+        from kubernetes_tpu.api.labels import LabelSelector
+        from test_parity import build
+
+        rng = random.Random(seed)
+        nodes = []
+        for i in range(n_nodes):
+            labels = {api.LABEL_HOSTNAME: f"n{i}"}
+            if rng.random() < 0.7:
+                labels[api.LABEL_ZONE] = f"z{rng.randint(0, 2)}"
+            nodes.append(make_node(f"n{i}", cpu="32", memory="64Gi",
+                                   labels=labels))
+        # a third of the nodes hold nearly all the existing pods
+        hot = [f"n{i}" for i in rng.sample(range(n_nodes), n_nodes // 3)]
+        existing = [make_pod(
+            f"ex-{i}", cpu="100m", memory="64Mi",
+            labels={"grp": f"g{rng.randint(0, 2)}", "app": "web"},
+            node_name=(rng.choice(hot) if rng.random() < 0.8
+                       else f"n{rng.randrange(n_nodes)}"))
+            for i in range(n_existing)]
+        cache, snap = build(nodes, existing)
+        for p in rng.sample(existing, n_existing // 3):
+            cache.remove_pod(p)
+            snap.remove_pod(p)
+
+        def term(key):
+            return api.PodAffinityTerm(
+                label_selector=LabelSelector(
+                    match_labels={"grp": f"g{rng.randint(0, 2)}"}),
+                topology_key=key)
+
+        keys = (api.LABEL_HOSTNAME, api.LABEL_ZONE)
+        pods = []
+        for i in range(n_pods):
+            key = keys[i % 2]
+            kind = rng.randrange(4)
+            if kind == 0:
+                aff = api.Affinity(pod_affinity=api.PodAffinity(
+                    required=[term(key)]))
+            elif kind == 1:
+                aff = api.Affinity(pod_anti_affinity=api.PodAntiAffinity(
+                    required=[term(key)]))
+            elif kind == 2:
+                aff = api.Affinity(pod_affinity=api.PodAffinity(
+                    preferred=[api.WeightedPodAffinityTerm(
+                        weight=rng.randint(1, 100),
+                        pod_affinity_term=term(key))]))
+            else:
+                aff = api.Affinity(pod_anti_affinity=api.PodAntiAffinity(
+                    preferred=[api.WeightedPodAffinityTerm(
+                        weight=rng.randint(1, 100),
+                        pod_affinity_term=term(key))]))
+            pods.append(make_pod(
+                f"p{i}", cpu="100m", memory="64Mi",
+                labels={"grp": f"g{i % 3}", "app": "web"}, affinity=aff))
+        return snap, pods
+
+    @pytest.mark.parametrize("seed", range(3))
+    def test_incoming_statics_bitwise_parity(self, seed):
+        """Every IncomingStatics plane of the device's node-first
+        anchoring equals the twin's per-pod domain reduction bit for bit,
+        on a world where node counts exceed 1, freed rows keep a stale
+        node and some nodes lack the zone key."""
+        from kubernetes_tpu.ops.affinity import incoming_statics
+        from kubernetes_tpu.state.featurize import PodFeaturizer
+
+        snap, pods = self._anchor_world(seed)
+        pb = PodFeaturizer(snap, group_selectors=lambda p: []).featurize(pods)
+        nth, pmh, tth = snap.host_tensors()
+        live = np.asarray(pmh.valid)
+        assert np.any(~live & (np.arange(live.size) < snap._next_slot))
+        assert np.bincount(np.asarray(pmh.node)[live]).max() > 1
+        assert np.any(np.asarray(nth.valid)
+                      & (np.asarray(nth.labels)[
+                          :, snap.label_key_col(api.LABEL_ZONE)] == 0))
+        lv, hw = snap.num_label_values, 1.0
+        nt, pm, tt = snap.to_device()
+        dev = incoming_statics(nt, pm, tt, pb, lv, hw)
+        host = hostwave.incoming_statics_host(nth, pmh, tth, pb, lv, hw)
+        assert np.any(host.ok_aff) and np.any(host.counts != 0)
+        for f in dev._fields:
+            np.testing.assert_array_equal(np.asarray(getattr(dev, f)),
+                                          np.asarray(getattr(host, f)),
+                                          err_msg=f)
+
     @pytest.mark.parametrize("seed", range(6))
     def test_ipa_wave_bitwise_parity(self, seed):
         """Device kernel == numpy twin on affinity-rich worlds: chosen,

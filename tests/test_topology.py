@@ -102,14 +102,56 @@ def topo_world(seed, n_nodes=8, n_existing=6, n_pending=10):
 # parity: device == twin, bit for bit
 
 
+def anchor_world(seed, n_nodes=12, n_existing=40, n_pending=10):
+    """World for the node-first anchoring of the spread counts: matching
+    pods stacked several to a node, deleted pods whose freed pod-matrix
+    rows keep their stale node, nodes without the zone key, and
+    constraints on both the hostname and the zone key."""
+    rng = np.random.RandomState(seed)
+    store = ObjectStore()
+    sched = Scheduler(store, wave_size=16)
+    for i in range(n_nodes):
+        labels = {api.LABEL_HOSTNAME: f"n{i}"}
+        if rng.rand() < 0.7:
+            labels[api.LABEL_ZONE] = f"z{rng.randint(3)}"
+        store.create("nodes", make_node(f"n{i}", cpu="32", memory="64Gi",
+                                        labels=labels))
+    hot = rng.choice(n_nodes, n_nodes // 3, replace=False)
+    existing = [make_pod(
+        f"ex-{i}", cpu="100m", labels={"app": rng.choice(["a", "b"])},
+        node_name=f"n{rng.choice(hot) if rng.rand() < 0.8 else i % n_nodes}")
+        for i in range(n_existing)]
+    for p in existing:
+        store.create("pods", p)
+    for i in rng.choice(n_existing, n_existing // 3, replace=False):
+        store.delete("pods", existing[i].namespace, existing[i].name)
+    pending = []
+    for i in range(n_pending):
+        app = rng.choice(["a", "b"])
+        p = make_pod(f"pend-{i}", cpu="100m", labels={"app": app})
+        p.spec.topology_spread_constraints = [
+            _spread(key=api.LABEL_HOSTNAME, max_skew=2, match={"app": app}),
+            _spread(when=api.SCHEDULE_ANYWAY, match={"app": app})]
+        pending.append(p)
+    snap = sched.snapshot
+    valid = snap.ep_valid[:snap._next_slot]
+    assert not valid.all(), "deleted pods must leave freed rows"
+    assert np.bincount(snap.ep_node[:snap._next_slot][valid]).max() > 1
+    zone = snap.labels[:, snap.label_key_col(api.LABEL_ZONE)]
+    assert np.any(snap.valid & (zone == 0)), "some node must lack the zone"
+    return store, sched, pending
+
+
 class TestStaticsParity:
-    @pytest.mark.parametrize("seed", range(3))
-    def test_topo_statics_matches_host(self, seed):
+    @pytest.mark.parametrize("seed,world", [
+        *(pytest.param(s, topo_world, id=str(s)) for s in range(3)),
+        pytest.param(7, anchor_world, id="anchor-7")])
+    def test_topo_statics_matches_host(self, seed, world):
         """The wave-start spread statics — per-pod node domains, resident
         counts per domain value, domain presence, wave match matrix, self
         matches — bitwise identical between topo_statics (device) and
         topo_statics_host (twin)."""
-        store, sched, pending = topo_world(seed)
+        store, sched, pending = world(seed)
         pb = sched.featurizer.featurize(pending)
         lv = sched.snapshot.num_label_values
         nt_d, pm_d, _ = sched.snapshot.to_device()
