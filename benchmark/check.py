@@ -1,9 +1,11 @@
 """The comparison that decides `correct`.
 
 What the timed path produced is read back once the window has closed:
-the store's own record of every bind and of every bound pod deleted
-(completions), in the order the store applied them, and at the end the
-node the store holds for each pod, which has to agree with that record.
+the store's own record of every bind, of every bound pod deleted (a
+completion the harness made, or an eviction the scheduler made) and of
+every nomination, in the order the store applied them, and at the end
+the node the store holds for each pod, which has to agree with the
+binds and deletions of that record.
 The configuration's plain reference (references/<name>.py, which
 imports nothing of the program) replays the record from the empty
 cluster. Numbers compared, each against the
@@ -13,8 +15,8 @@ limit the cell file gives:
   (resources and pod count, required node affinity, hostname
   anti-affinity), a pod bound twice, or a pod the store does not hold
   where its record puts it. Exact: limit 0.
-- lost: pods created that are neither bound nor pending in the
-  scheduler's queue. Exact: limit 0.
+- lost: pods created that are neither bound, nor pending in the
+  scheduler's queue, nor evicted, nor completed. Exact: limit 0.
 - unbound: pods due in an open-loop window that never bound, after a
   grace period past the window. Exact: limit 0.
 - fallbacks: ways the run left the device path (probes.py). Limit 0.
@@ -35,6 +37,8 @@ from pathlib import Path
 import numpy as np
 
 BENCH = Path(__file__).resolve().parent
+# the event log's op codes (run.py EventLog)
+BIND, COMPLETE, EVICT, NOMINATE = 1, -1, -2, 2
 
 
 def reference(name: str):
@@ -59,23 +63,26 @@ def store_nodes(store, n_pods: int) -> np.ndarray:
 def compare(cfg, plan, log, store_node, eligible, sample_n: int,
             seed: int, counts: dict, limits: dict):
     """log: the run's event log (arrays op/pod/node/round/pos in the
-    order the store applied them; pos -1 for the resident pods bound at
-    set-up). store_node: what the store holds at the end. eligible: bool
+    order the store applied them, op one of run.py's BIND, COMPLETE,
+    EVICT, NOMINATE; pos -1 for the pods bound at set-up or created
+    bound). store_node: what the store holds at the end. eligible: bool
     per event, the binds the sample is drawn from. counts: the exact
-    counts the run measured (lost, unbound, fallbacks). Returns
-    (correct, checks, info)."""
+    counts the run measured (lost, unbound, fallbacks). The reference
+    gets every event, evictions and nominations too. Returns (correct,
+    checks, info)."""
     ref = reference(cfg["reference"])
     cl = ref.Cluster.from_config(cfg)
     op, pod, node = log["op"], log["pod"], log["node"]
     # the store at the end against the log: each pod on the node of its
-    # last bind, or nowhere once deleted
+    # last bind, or nowhere once deleted; a nomination places nothing
     final = np.full(len(store_node), -1, np.int64)
-    if len(pod):
-        u, first = np.unique(pod[::-1], return_index=True)
-        last = len(pod) - 1 - first  # each pod's last event
-        final[u] = np.where(op[last] > 0, node[last], -1)
+    placing = np.flatnonzero(op != NOMINATE)
+    if len(placing):
+        u, first = np.unique(pod[placing][::-1], return_index=True)
+        last = placing[len(placing) - 1 - first]  # each pod's last event
+        final[u] = np.where(op[last] == BIND, node[last], -1)
     mismatch = int(np.sum(final != store_node))
-    made = (op > 0) & (log["pos"] >= 0)
+    made = (op == BIND) & (log["pos"] >= 0)
     rng = np.random.default_rng([seed, 3])
     idx = np.flatnonzero(eligible)
     pick = rng.choice(idx, size=min(sample_n, len(idx)), replace=False)
@@ -91,3 +98,15 @@ def compare(cfg, plan, log, store_node, eligible, sample_n: int,
     info = {"checked": res["checked"], "not_best": res["not_best"],
             "gap_max": res["gap_max"], "store_mismatch": mismatch}
     return correct, checks, info
+
+
+def resident_violations(cfg, plan, nodes: np.ndarray) -> int:
+    """Filters the running pods break where they are put at set-up, by
+    the configuration's reference: each bound in turn, none sampled."""
+    ref = reference(cfg["reference"])
+    n = len(nodes)
+    op = np.full(n, BIND, np.int64)
+    none = np.zeros(n, bool)
+    return ref.replay(ref.Cluster.from_config(cfg), plan, op,
+                      np.arange(n), np.asarray(nodes, np.int64), none,
+                      none)["violations"]

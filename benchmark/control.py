@@ -41,7 +41,7 @@ def reading(cfg, work, n_pods: int, seed: int, variant: str,
     ref = check.reference(cfg["reference"])
     cl = ref.Cluster.from_config(cfg)
     n_res = cfg["resident"]
-    plan = loadgen.plan_pods(cfg, n_res + n_pods, seed)
+    plan = loadgen.plan_pods(cfg, n_res + n_pods, seed, n_res)
     res_nodes = loadgen.resident_nodes(cfg, plan, n_res, seed)
     t = time.perf_counter()
     op, pod, node, made = ref.greedy(

@@ -27,13 +27,19 @@ Semantics (algorithmprovider/defaults/defaults.go, 1.11):
   program breaks ties the same way (its round-robin counter), so a
   comparison can be exact.
 
-`replay` walks the event log of a run (binds, and the deletions of pods
-that completed), checks every bind against the filters in the state it
-lands in, and for a sample of binds computes, from the state the pod
-was placed into, the node the reference chooses, the best score of any
-feasible node and the score of the node it got. `greedy` is the same
-reference put in the scheduler's place; with first_tie it is the
-control.
+- Kinds: a pod requests what its kind requests (the configuration's
+  `kinds`, else `pod_requests` for every pod). Priority is read by kind
+  too (`Cluster.kind_prio`, 0 where unset, as 1.11's GetPodPriority);
+  nothing here depends on it: the filters and scores above do not read
+  it, and a replay of a run that preempts is a later reference's.
+
+`replay` walks the event log of a run (binds, the deletions of pods that
+completed or were evicted, nominations), checks every bind against the
+filters in the state it lands in, and for a sample of binds computes,
+from the state the pod was placed into, the node the reference chooses,
+the best score of any feasible node and the score of the node it got.
+`greedy` is the same reference put in the scheduler's place; with
+first_tie it is the control.
 """
 
 from __future__ import annotations
@@ -44,6 +50,10 @@ from dataclasses import dataclass
 import numpy as np
 
 MAX = 10  # schedulerapi.MaxPriority
+# the event log's op codes (benchmark/run.py EventLog)
+BIND, COMPLETE, EVICT, NOMINATE = 1, -1, -2, 2
+# kinds of a configuration that declares none: one per shape
+DEFAULT_KINDS = 3
 _UNITS = {"": 1, "m": 1e-3, "k": 1e3, "M": 1e6, "G": 1e9,
           "Ki": 2 ** 10, "Mi": 2 ** 20, "Gi": 2 ** 30, "Ti": 2 ** 40}
 
@@ -62,42 +72,44 @@ def quantity(q) -> float:
 class Cluster:
     """Node and pod facts of a configuration, from the benchmark's own
     generation: cpu in millicores, memory in bytes, both integers as Go
-    holds them."""
+    holds them; pod requests and priority by kind (the plan's index)."""
 
     alloc_cpu: int
     alloc_mem: int
     alloc_pods: int
     aff_label: np.ndarray  # [N] the aff-<k> label a node carries, -1 none
-    pod_cpu: int
-    pod_mem: int
+    kind_cpu: np.ndarray  # [K] int64 cpu request of each kind
+    kind_mem: np.ndarray  # [K] int64 memory request of each kind
+    kind_prio: np.ndarray  # [K] int64 priority of each kind
     groups: int
 
     @classmethod
     def from_config(cls, cfg):
         n = cfg["nodes"]
         a = cfg["node_allocatable"]
-        r = cfg["pod_requests"]
+        kinds = cfg.get("kinds") or [{}] * DEFAULT_KINDS
+        reqs = [k["requests"] if "requests" in k else cfg["pod_requests"]
+                for k in kinds]
         return cls(
             alloc_cpu=round(quantity(a["cpu"]) * 1000),
             alloc_mem=round(quantity(a["memory"])),
             alloc_pods=round(quantity(a["pods"])),
             aff_label=(np.arange(n) % cfg["affinity_labels"]
                        if cfg["affinity_labels"] else np.full(n, -1)),
-            pod_cpu=round(quantity(r["cpu"]) * 1000),
-            pod_mem=round(quantity(r["memory"])),
+            kind_cpu=np.asarray([round(quantity(r["cpu"]) * 1000)
+                                 for r in reqs], np.int64),
+            kind_mem=np.asarray([round(quantity(r["memory"])) for r in reqs],
+                                np.int64),
+            kind_prio=np.asarray([k.get("priority") or 0 for k in kinds],
+                                 np.int64),
             groups=max(cfg["anti_groups"], 1))
 
-    def pods_fit(self) -> int:
-        """Pods of the configured request one node holds."""
-        return int(min(self.alloc_pods, self.alloc_cpu // self.pod_cpu,
-                       self.alloc_mem // self.pod_mem))
 
-
-def scores(cl: Cluster, cnt) -> np.ndarray:
-    """LeastRequested + BalancedResourceAllocation of a pod onto nodes
-    already holding `cnt` pods, as 1.11 computes them."""
-    cpu = (cnt + 1) * cl.pod_cpu  # int64: requested, the pod included
-    mem = (cnt + 1) * cl.pod_mem
+def scores(cl: Cluster, st: "State", kind: int) -> np.ndarray:
+    """LeastRequested + BalancedResourceAllocation of a pod of `kind`
+    onto every node in state `st`, as 1.11 computes them."""
+    cpu = st.cpu + cl.kind_cpu[kind]  # int64: requested, the pod included
+    mem = st.mem + cl.kind_mem[kind]
 
     def least(req, cap):
         return np.where(req > cap, 0, (cap - req) * MAX // cap)
@@ -110,35 +122,58 @@ def scores(cl: Cluster, cnt) -> np.ndarray:
     return lr + ba
 
 
-def feasible_nodes(cl: Cluster, cnt, anti, aff: int, group: int):
-    ok = ((cnt + 1) * cl.pod_cpu <= cl.alloc_cpu) \
-        & ((cnt + 1) * cl.pod_mem <= cl.alloc_mem) \
-        & (cnt + 1 <= cl.alloc_pods)
+def feasible_nodes(cl: Cluster, st: "State", kind: int, aff: int,
+                   group: int):
+    ok = (st.cpu + cl.kind_cpu[kind] <= cl.alloc_cpu) \
+        & (st.mem + cl.kind_mem[kind] <= cl.alloc_mem) \
+        & (st.cnt + 1 <= cl.alloc_pods)
     if aff >= 0:
         ok &= cl.aff_label == aff
     if group >= 0:
-        ok &= anti[group] == 0
+        ok &= st.anti[group] == 0
     return ok
 
 
 class State:
+    """Per node: pods, cpu and memory requested, pods of each group."""
+
     def __init__(self, cl: Cluster):
         n = len(cl.aff_label)
+        self.cl = cl
         self.cnt = np.zeros(n, np.int64)
+        self.cpu = np.zeros(n, np.int64)
+        self.mem = np.zeros(n, np.int64)
         self.anti = np.zeros((cl.groups, n), np.int64)
 
-    def place(self, node, group, d=1):
+    def place(self, node, kind, group, d=1):
         self.cnt[node] += d
+        self.cpu[node] += d * self.cl.kind_cpu[kind]
+        self.mem[node] += d * self.cl.kind_mem[kind]
         if group >= 0:
             self.anti[group, node] += d
 
+    def over(self, node) -> bool:
+        """The node holds more than it allows."""
+        cl = self.cl
+        return bool(self.cnt[node] > cl.alloc_pods
+                    or self.cpu[node] > cl.alloc_cpu
+                    or self.mem[node] > cl.alloc_mem)
 
-def choose(cl: Cluster, st: State, aff: int, group: int, rr: int,
+    def copy(self) -> "State":
+        out = State.__new__(State)
+        out.cl = self.cl
+        out.cnt, out.cpu, out.mem = (self.cnt.copy(), self.cpu.copy(),
+                                     self.mem.copy())
+        out.anti = self.anti.copy()
+        return out
+
+
+def choose(cl: Cluster, st: State, kind: int, aff: int, group: int, rr: int,
            first_tie: bool = False):
     """The node 1.11 chooses for a pod in state `st` (-1: none fits),
     the feasible mask and the scores."""
-    ok = feasible_nodes(cl, st.cnt, st.anti, aff, group)
-    tot = scores(cl, st.cnt)
+    ok = feasible_nodes(cl, st, kind, aff, group)
+    tot = scores(cl, st, kind)
     if not ok.any():
         return -1, ok, tot
     ties = np.flatnonzero(ok & (tot == tot[ok].max()))
@@ -146,11 +181,13 @@ def choose(cl: Cluster, st: State, aff: int, group: int, rr: int,
 
 
 def replay(cl: Cluster, plan, op, pod, node, made, sample) -> dict:
-    """The event log of a run, in the order the store applied it: op +1
-    a bind (pod, node), -1 the deletion of a bound pod; pod is the plan
-    index; made marks the binds the scheduler made (not the running pods
-    bound at set-up). sample: bool per event, the binds compared with
-    the reference's own choice, where lastNodeIndex counts the binds the
+    """The event log of a run, in the order the store applied it: op
+    BIND a bind (pod, node), COMPLETE or EVICT the deletion of a bound
+    pod, NOMINATE a nomination (skipped here: 1.11's binds and scores in
+    these configurations do not read it); pod is the plan index; made
+    marks the binds the scheduler made (not the running pods bound at
+    set-up). sample: bool per event, the binds compared with the
+    reference's own choice, where lastNodeIndex counts the binds the
     scheduler made before. Returns violations (binds that break a
     filter: over capacity, node affinity or anti-affinity; a pod bound
     twice), mismatches (sampled binds not on the reference's node),
@@ -158,20 +195,21 @@ def replay(cl: Cluster, plan, op, pod, node, made, sample) -> dict:
     infeasible node), checked (sampled binds) and gap_max (the widest
     score gap among them)."""
     st = State(cl)
-    fit = cl.pods_fit()
     violations = mismatches = not_best = checked = 0
     gap_max = 0
     bound = set()
     last_node_index = 0
     for j in range(len(pod)):
+        if op[j] == NOMINATE:
+            continue
         p, c = pod[j], node[j]
-        aff, grp = plan.aff[p], plan.group[p]
+        k, aff, grp = plan.kind[p], plan.aff[p], plan.group[p]
         if op[j] < 0:
-            st.place(c, grp, -1)
+            st.place(c, k, grp, -1)
             continue
         if sample[j]:
             checked += 1
-            want, ok, tot = choose(cl, st, aff, grp, last_node_index)
+            want, ok, tot = choose(cl, st, k, aff, grp, last_node_index)
             mismatches += c != want
             if not ok[c]:
                 not_best += 1
@@ -181,8 +219,8 @@ def replay(cl: Cluster, plan, op, pod, node, made, sample) -> dict:
                 not_best += gap > 0
         if made[j]:
             last_node_index += 1
-        st.place(c, grp)
-        violations += ((st.cnt[c] > fit) + (aff >= 0 and cl.aff_label[c] != aff)
+        st.place(c, k, grp)
+        violations += (st.over(c) + (aff >= 0 and cl.aff_label[c] != aff)
                        + (grp >= 0 and st.anti[grp, c] > 1) + (p in bound))
         bound.add(p)
     return {"violations": int(violations), "mismatches": int(mismatches),
@@ -206,25 +244,25 @@ def greedy(cl: Cluster, plan, order, resident=None, keep=None,
     out = []
     live = deque()
     for p, c in zip(*(resident or ((), ()))):
-        st.place(c, plan.group[p])
-        out.append((1, p, c, 0))
+        st.place(c, plan.kind[p], plan.group[p])
+        out.append((BIND, p, c, 0))
         live.append((p, c))
     rr = 0
     seen = st
     for j, p in enumerate(order):
         if batched and j % batch == 0:
-            seen = State(cl)
-            seen.cnt, seen.anti = st.cnt.copy(), st.anti.copy()
-        c, _, _ = choose(cl, seen, plan.aff[p], plan.group[p], rr, first_tie)
+            seen = st.copy()
+        c, _, _ = choose(cl, seen, plan.kind[p], plan.aff[p], plan.group[p],
+                         rr, first_tie)
         if c >= 0:
             rr += 1
-            st.place(c, plan.group[p])
-            out.append((1, p, c, 1))
+            st.place(c, plan.kind[p], plan.group[p])
+            out.append((BIND, p, c, 1))
             live.append((p, c))
         if (j + 1) % batch == 0:
             while keep is not None and len(live) > keep:
                 q, d = live.popleft()
-                st.place(d, plan.group[q], -1)
-                out.append((-1, q, d, 0))
+                st.place(d, plan.kind[q], plan.group[q], -1)
+                out.append((COMPLETE, q, d, 0))
     arr = np.asarray(out, np.int64).reshape(-1, 4)
     return arr[:, 0], arr[:, 1], arr[:, 2], arr[:, 3].astype(bool)

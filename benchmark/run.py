@@ -24,10 +24,20 @@ Traffic loops:
   bound are replaced, so each round starts with the backlog full. The
   window is whole rounds: it opens when schedule_pending() is called
   and closes at the end of the first round to end after --seconds.
+  Where schedule_pending() returns with pods still queued (every one
+  parked in backoff) the loop waits for the first to be due and calls
+  it again; that wait counts in the window.
 - open (paced): a generator thread hands pods over at due times drawn
   from the seed, whether or not the scheduler keeps up; the serve loop
   waits on them, moves them into the store and calls schedule_pending().
   Latency counts from the due time.
+
+At each round's end running pods complete: the oldest until the
+cluster holds `resident` again, or with the traffic's `complete_kinds`
+every bound pod of those kinds. With `refill_evicted` each pod the
+scheduler evicted is then replaced by a pod like it from a pool built at
+set-up, created bound to the node it left once that node holds no
+nomination.
 
 Pods enter the store in the scheduler's own thread: the in-process
 store delivers informer events under its lock, and the scheduler holds
@@ -40,7 +50,7 @@ import time
 T0 = time.perf_counter()
 
 import argparse  # noqa: E402
-from collections import deque  # noqa: E402
+from collections import OrderedDict, deque  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
@@ -63,6 +73,7 @@ import check  # noqa: E402
 import loadgen  # noqa: E402
 import probes  # noqa: E402
 import trace_reduce  # noqa: E402
+from check import BIND, COMPLETE, EVICT, NOMINATE  # noqa: E402
 
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = ROOT / ".bench_trace"
@@ -108,16 +119,22 @@ def reader(name: str, base: Path = BENCH):
 
 
 class EventLog:
-    """Store watcher: every bind as it lands, and every bound pod that
-    is deleted, in the order the store applies them — op (+1 bind, -1
-    delete), the pod's plan index, the node index, the host clock, and
-    for a bind the scheduler made, its round and position in it (the
-    resident pods bound at set-up have position -1)."""
+    """Store watcher: in the order the store applies them, every bind as
+    it lands (BIND), every bound pod deleted (COMPLETE where the harness
+    deleted it, EVICT where the scheduler did) and every nomination of
+    an unbound pod (NOMINATE, node -1 where one is cleared) — op, the
+    pod's plan index, the node index, the host clock, and for a bind the
+    scheduler made, its round and position in it (the pods bound at
+    set-up or created bound have position -1). A nomination holds until
+    the pod binds, is deleted or is nominated again."""
 
     def __init__(self):
         self.rows: List[tuple] = []  # (op, t, pod, node, round, pos)
         self.n_binds = 0  # binds the scheduler made
-        self.live = deque()  # names of the bound pods, oldest first
+        self.live = OrderedDict()  # names of the bound pods, oldest first
+        self.nominated = {}  # plan index -> nominated node name
+        self.evicted = []  # (plan index, node index) not yet refilled
+        self.completing = False  # the harness is deleting pods
         self.cur = 0
         self.k = 0
 
@@ -128,24 +145,47 @@ class EventLog:
     def __call__(self, ev):
         obj = ev.obj
         name = obj.metadata.name
-        if not obj.spec.node_name or not name.startswith("pod-"):
+        if not name.startswith("pod-"):
+            return
+        if not obj.spec.node_name:
+            if ev.type == "MODIFIED":
+                self._nomination(int(name[4:]),
+                                 obj.status.nominated_node_name)
             return
         if ev.type == "MODIFIED" and ev.old is not None \
                 and not ev.old.spec.node_name:
-            op, rnd, pos = 1, self.cur, self.k
+            op, rnd, pos = BIND, self.cur, self.k
             self.k += 1
             self.n_binds += 1
         elif ev.type == "ADDED":
-            op, rnd, pos = 1, 0, -1
+            op, rnd, pos = BIND, 0, -1
         elif ev.type == "DELETED":
-            op, rnd, pos = -1, 0, -1
+            op, rnd, pos = COMPLETE if self.completing else EVICT, 0, -1
         else:
             return
         i = int(name[4:])
-        self.rows.append((op, time.perf_counter(), i,
-                          int(obj.spec.node_name[5:]), rnd, pos))
-        if op > 0:
-            self.live.append(name)
+        node = int(obj.spec.node_name[5:])
+        self.rows.append((op, time.perf_counter(), i, node, rnd, pos))
+        if op == BIND:
+            self.live[name] = None
+        else:
+            self.live.pop(name, None)
+            if op == EVICT:
+                self.evicted.append((i, node))
+        if self.nominated:
+            self.nominated.pop(i, None)
+
+    def _nomination(self, i: int, nom: str):
+        # the scheduler may set the name on the pod it holds before the
+        # store's event, so the log's own record is the one compared
+        if nom == self.nominated.get(i, ""):
+            return
+        if nom:
+            self.nominated[i] = nom
+        else:
+            del self.nominated[i]
+        self.rows.append((NOMINATE, time.perf_counter(), i,
+                          int(nom[5:]) if nom else -1, self.cur, -1))
 
     def arrays(self):
         a = np.asarray(self.rows, np.float64).reshape(-1, 6)
@@ -254,7 +294,7 @@ class Tracer:
 
 
 HOST_SPANS = {"schedule_pending", "serve_wait", "arrivals", "topup",
-              "completions"}
+              "completions", "refills", "backoff_wait"}
 
 
 WARM_PODS = 512  # throwaway pods of the warm-up, more than a wave
@@ -275,8 +315,12 @@ def build_scheduler(cfg, work, log, residents):
     from kubernetes_tpu.state.vocab import bucket_size
 
     held = cfg["resident"] + cfg["backlog"] + WARM_PODS
-    anti = cfg["mix"].get("antiaffinity", 0) / sum(cfg["mix"].values())
-    n_terms = int(math.ceil(anti * held))
+    anti = anti_share(cfg, cfg["mix"])
+    terms = anti * held
+    if "resident_mix" in cfg:
+        terms += (anti_share(cfg, cfg["resident_mix"]) - anti) \
+            * cfg["resident"]
+    n_terms = int(math.ceil(terms))
     caps = Caps(M=bucket_size(held),
                 E=bucket_size(n_terms + 64) if n_terms else 8,
                 LV=bucket_size(cfg["nodes"] + 256, 64))
@@ -298,17 +342,97 @@ def build_scheduler(cfg, work, log, residents):
     return store, sched
 
 
+def anti_share(cfg, mix: dict) -> float:
+    """Share of a mix's pods that carry an anti-affinity term."""
+    shape = {k["name"]: k["shape"] for k in loadgen.kinds(cfg)}
+    return sum(w for k, w in mix.items()
+               if shape[k] == "antiaffinity") / sum(mix.values())
+
+
 def complete(ctx):
-    """The oldest bound pods complete until the cluster holds `resident`
-    again. Runs in the scheduler's thread, between rounds."""
+    """Running pods complete: every bound pod of the traffic's
+    `complete_kinds`, or without them the oldest bound pods until the
+    cluster holds `resident` again. Runs in the scheduler's thread,
+    between rounds."""
     import jax
 
-    live, keep = ctx.log.live, ctx.cfg["resident"]
-    if len(live) <= keep:
-        return
+    log = ctx.log
+    live = log.live
+    if ctx.complete_kinds is not None:
+        kind = ctx.plan.kind
+        done = [n for n in live if kind[int(n[4:])] in ctx.complete_kinds]
+    else:
+        done = None
+        keep = ctx.cfg["resident"]
+        if len(live) <= keep:
+            return
     with jax.profiler.TraceAnnotation("completions"):
-        while len(live) > keep:
-            ctx.store.delete("pods", "default", live.popleft())
+        log.completing = True
+        try:
+            if done is None:
+                while len(live) > keep:
+                    ctx.store.delete("pods", "default",
+                                     live.popitem(last=False)[0])
+            else:
+                for name in done:
+                    ctx.store.delete("pods", "default", name)
+        finally:
+            log.completing = False
+
+
+def refill(ctx):
+    """Each pod the scheduler evicted is replaced by the next pool pod
+    like it (its kind, label and group), created bound to the node it
+    left, once that node holds no nomination. It runs after complete(),
+    so with `complete_kinds` no pod of those kinds is bound then either:
+    a refill never undoes a preemption still pending."""
+    import jax
+
+    log = ctx.log
+    if not log.evicted:
+        return
+    held = set(log.nominated.values())
+    wait = []
+    with jax.profiler.TraceAnnotation("refills"):
+        for i, c in log.evicted:
+            name = loadgen.node_name(c)
+            if name in held:
+                wait.append((i, c))
+                continue
+            free = ctx.refill_free.get(ctx.plan.like(i))
+            if not free:
+                raise BenchError(f"refill pool has no pod like pod-{i}: "
+                                 "raise refill_per_s in the cell file")
+            p = free.popleft()
+            p.spec.node_name = name
+            ctx.store.create("pods", p)
+            ctx.refilled += 1
+    log.evicted = wait
+
+
+def round_end(ctx):
+    complete(ctx)
+    if ctx.refill_free is not None:
+        refill(ctx)
+
+
+def wait_ready(queue) -> bool:
+    """Wait on the scheduler's queue until a pod it holds is due: the
+    earliest backoff deadline passes, or a pod turns active. False where
+    no pod is due at any time (the queue is empty, or holds only pods
+    parked unschedulable until an event). Reads the queue's backoff
+    deadlines under its lock: SchedulingQueue keeps them private."""
+    import jax
+
+    with jax.profiler.TraceAnnotation("backoff_wait"), queue._lock:
+        while True:
+            queue._flush_backoff_locked()
+            if queue._items:
+                return True
+            due = [queue._backoff_until.get(u, 0.0) for u in queue._backoff]
+            if not due:
+                return False
+            queue._lock.wait(max(min(due) - queue.clock(), 0.0))
 
 
 def window_open(win, stats, hook, t0=None):
@@ -366,7 +490,7 @@ def drain(ctx, seconds):
             window_close(win, ctx.stats, hook, r.end)
             sched.enter_dormant()
             return
-        complete(ctx)
+        round_end(ctx)
         top_up()
         if k == 0:
             window_open(win, ctx.stats, hook)
@@ -375,12 +499,17 @@ def drain(ctx, seconds):
 
     top_up()
     hook.on_end = on_end
-    with jax.profiler.TraceAnnotation("schedule_pending"):
-        sched.schedule_pending()
-    if hook.error is not None:
-        raise hook.error
+    while True:
+        with jax.profiler.TraceAnnotation("schedule_pending"):
+            sched.schedule_pending()
+        if hook.error is not None:
+            raise hook.error
+        if "t1" in win or not wait_ready(sched.queue):
+            break
     if "t1" not in win:
-        raise BenchError("the backlog drained before the window closed")
+        raise BenchError("the backlog drained before the window closed: "
+                         f"{sched.queue.pending_count()} pods queued, "
+                         "none due")
 
 
 def paced(ctx, seconds, traffic):
@@ -401,7 +530,7 @@ def paced(ctx, seconds, traffic):
             store.create("pods", p)
         ctx.created += size
         sched.schedule_pending()
-        complete(ctx)
+        round_end(ctx)
     n_warm = ctx.created
     inbox = loadgen.Inbox()
     gen = loadgen.OpenLoop(pods[n_warm:], ctx.due, inbox,
@@ -434,7 +563,7 @@ def paced(ctx, seconds, traffic):
                 ctx.created += len(items)
             with jax.profiler.TraceAnnotation("schedule_pending"):
                 sched.schedule_pending()
-            complete(ctx)
+            round_end(ctx)
             now = time.perf_counter()
             if "t1" not in win and now >= t0 + seconds:
                 window_close(win, ctx.stats, hook, t0 + seconds)
@@ -456,11 +585,53 @@ class Ctx:
     own bookkeeping around it."""
 
     def __init__(self, **kw):
-        self.created = 0
+        self.created = 0  # backlog pods created
+        self.refilled = 0  # evicted pods replaced
         self.traced_binds = None
         self.gen = None
         self.win = {}
         self.__dict__.update(kw)
+
+
+@dataclass
+class RunPlan:
+    """Every pod a run may create, by plan index: the running pods
+    [0, n_res), then the backlog's pool (closed loop) or the warm-up and
+    due pods (open loop) up to n_pods, then the refill pool."""
+
+    plan: loadgen.PodPlan
+    n_res: int
+    n_pods: int
+    res_nodes: np.ndarray  # node of each running pod
+    due: Optional[np.ndarray] = None  # open loop: due times, s
+    n_warm: int = 0  # open loop: pods of the warm-up batches
+
+
+def plan_run(cell: dict, seed: int, seconds: float) -> RunPlan:
+    cfg, work, traffic = cell["config"], cell["work"], cell["traffic"]
+    n_res = cfg["resident"]
+    due, n_warm = None, 0
+    if traffic["loop"] == "closed":
+        n_pods = n_res + (cfg["backlog"] * (1 + work["warm_rounds"])
+                          + int(work["pool_per_s"] * seconds))
+    else:
+        # one Poisson stream; its first warm_s seconds run before the
+        # window opens
+        due = loadgen.poisson_due(traffic["rate"],
+                                  traffic["warm_s"] + seconds, seed)
+        n_warm = sum(work["warm_batches"])
+        n_pods = n_res + n_warm + len(due)
+    plan = loadgen.plan_pods(cfg, n_pods, seed, n_res)
+    if traffic.get("refill_evicted"):
+        # as many as every running pod evicted in each warm-up round,
+        # and refill_per_s for each second of the window
+        n_refill = (n_res * work.get("warm_rounds", 1)
+                    + int(work["refill_per_s"] * seconds))
+        plan = loadgen.PodPlan.concat(
+            [plan, loadgen.refill_plan(plan, n_res, n_refill)])
+    return RunPlan(plan, n_res, n_pods,
+                   loadgen.resident_nodes(cfg, plan, n_res, seed), due,
+                   n_warm)
 
 
 def run(cell: dict, seed: int, seconds: float, trace: bool,
@@ -483,27 +654,34 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
 
     cfg, work, traffic = cell["config"], cell["work"], cell["traffic"]
     closed = traffic["loop"] == "closed"
-    n_res = cfg["resident"]
-    if closed:
-        due = None
-        n_warm = 0
-        n_pods = n_res + (cfg["backlog"] * (1 + work["warm_rounds"])
-                          + int(work["pool_per_s"] * seconds))
-    else:
-        # one Poisson stream; its first warm_s seconds run before the
-        # window opens
-        due = loadgen.poisson_due(traffic["rate"],
-                                  traffic["warm_s"] + seconds, seed)
-        n_warm = sum(work["warm_batches"])
-        n_pods = n_res + n_warm + len(due)
-    plan = loadgen.plan_pods(cfg, n_pods, seed)
+    rp = plan_run(cell, seed, seconds)
+    plan, n_res, n_pods, due = rp.plan, rp.n_res, rp.n_pods, rp.due
+    complete_kinds = traffic.get("complete_kinds")
+    if complete_kinds is not None:
+        names = [k["name"] for k in loadgen.kinds(cfg)]
+        if set(complete_kinds) - set(names):
+            raise BenchError(f"complete_kinds {complete_kinds} names kinds "
+                             f"not among {names}")
+        complete_kinds = {names.index(k) for k in complete_kinds}
     pods = loadgen.build_pods(cfg, plan)
-    for p, n in zip(pods, loadgen.resident_nodes(cfg, plan, n_res, seed)):
+    refill_free = None  # the refill pool's pods by what they are like
+    if traffic.get("refill_evicted"):
+        refill_free = {}
+        for q in range(n_pods, len(plan)):
+            refill_free.setdefault(plan.like(q), deque()).append(pods[q])
+    if cfg.get("resident_placement", "drawn") != "drawn":
+        bad = check.resident_violations(cfg, plan, rp.res_nodes)
+        if bad:
+            raise BenchError(f"running pods placed "
+                             f"{cfg['resident_placement']!r} break {bad} "
+                             "filters")
+    for p, n in zip(pods, rp.res_nodes):
         p.spec.node_name = loadgen.node_name(n)
     log = EventLog()
     store, sched = build_scheduler(cfg, work, log, pods[:n_res])
     ctx = Ctx(cfg=cfg, work=work, store=store, sched=sched, log=log,
-              hook=round_hook(log), pods=pods[n_res:],
+              hook=round_hook(log), pods=pods[n_res:n_pods], plan=plan,
+              complete_kinds=complete_kinds, refill_free=refill_free,
               tracer=Tracer() if trace else None, stats=stats, due=due)
     hook, win = ctx.hook, ctx.win
     if closed:
@@ -527,19 +705,23 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     trace_out = (ctx.tracer.reduce(hook.steps) if ctx.tracer is not None
                  else None)
 
-    n_binds = log.n_binds
     log = log.arrays()
-    store_node = check.store_nodes(store, n_pods)
-    lost = ctx.created - n_binds - pending
+    store_node = check.store_nodes(store, len(plan))
+    # every pod the run created is bound, pending, evicted or completed
+    n_completed = int(np.sum(log["op"] == COMPLETE))
+    n_evicted = int(np.sum(log["op"] == EVICT))
+    n_bound = int(np.sum(log["op"] == BIND)) - n_completed - n_evicted
+    lost = (n_res + ctx.created + ctx.refilled - n_bound - pending
+            - n_evicted - n_completed)
     counts = {"lost": abs(lost), "fallbacks": len(fallbacks)}
-    made = (log["op"] > 0) & (log["pos"] >= 0)  # binds the scheduler made
+    made = (log["op"] == BIND) & (log["pos"] >= 0)  # the scheduler's binds
     if closed:
         eligible = made & (log["t"] > win["t0"]) & (log["t"] <= win["t1"])
         attempted = int(np.sum(eligible)) + counts["lost"]
         failed = counts["lost"]
     else:
         n_pre = int(np.sum(due < traffic["warm_s"]))
-        first = n_res + n_warm + n_pre  # plan index of the first due pod
+        first = n_res + rp.n_warm + n_pre  # plan index of the first due pod
         accepted = ctx.gen.accepted[n_pre:]
         due = due[n_pre:] - traffic["warm_s"]
         eligible = made & (log["pod"] >= first)
@@ -596,7 +778,7 @@ class Observations:
     t0: float  # window open
     t1: float  # window close
     t_end: float  # serve loop done (open loop: after the grace wait)
-    log: dict  # event log arrays: op, t, pod, node, round, pos
+    log: dict  # event log arrays: op, t, pod, node, round, pos (EventLog)
     rounds: list  # Round, every round that ended
     step_delta: dict  # step-profiler seconds accrued inside the window
     trace: Optional[dict] = None  # trace_reduce.read + idle_by_host
@@ -608,7 +790,7 @@ class Observations:
     def window_binds(self) -> int:
         """Binds the scheduler made inside the window."""
         t = self.log["t"]
-        made = (self.log["op"] > 0) & (self.log["pos"] >= 0)
+        made = (self.log["op"] == BIND) & (self.log["pos"] >= 0)
         return int(np.sum(made & (t > self.t0) & (t <= self.t1)))
 
     def bind_latency(self) -> np.ndarray:
@@ -617,7 +799,7 @@ class Observations:
         the run."""
         lat = np.full(len(self.due), self.t_end) - (self.t0 + self.due)
         j = self.log["pod"] - self.due_index0
-        ok = (j >= 0) & (self.log["op"] > 0)
+        ok = (j >= 0) & (self.log["op"] == BIND)
         lat[j[ok]] = self.log["t"][ok] - (self.t0 + self.due[j[ok]])
         return lat
 

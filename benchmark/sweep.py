@@ -52,7 +52,7 @@ def main(argv=None):
         fifth = max(len(lat) // 5, 1)
         t = r.log["t"]
         bound_by_close = np.sum((r.log["pod"] >= r.due_index0)
-                                & (r.log["op"] > 0) & (t <= r.t1))
+                                & (r.log["op"] == run.BIND) & (t <= r.t1))
         p99 = float(np.sort(lat)[int(np.ceil(0.99 * len(lat))) - 1])
         print(json.dumps({
             "rate": rate, "correct": out["correct"],
