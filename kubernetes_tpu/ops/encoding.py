@@ -311,6 +311,28 @@ class PodBatch(NamedTuple):
     pu_tk: np.ndarray  # i32 [UP]
 
 
+# priority of a pad row of Nominations.prio: above every pod priority,
+# so no pod's fit reads the row and no placement drops from it
+NOM_PAD_PRIO = np.iinfo(np.int32).max
+
+
+class Nominations(NamedTuple):
+    """Pods nominated to nodes (status.nominatedNodeName), as a pod's
+    resource fit counts them: 1.11's podFitsOnNode adds to a node every
+    pod nominated there with priority >= the pod's own, other than the
+    pod itself (generic_scheduler.go addNominatedPods). Scores read none.
+
+    Row l of req/count sums the pods nominated to each node whose
+    priority is >= prio[l]; prio holds the nominated pods' distinct
+    priorities, ascending, then NOM_PAD_PRIO pad rows of zeros. A pod
+    that places drops its own nomination from every row it is in."""
+
+    req: np.ndarray  # f32 [L, N, R]
+    count: np.ndarray  # i32 [L, N]
+    prio: np.ndarray  # i32 [L]
+    own: np.ndarray  # i32 [..., P]  node of the pod's own nomination, -1 none
+
+
 # Names + order of the device-evaluated predicates; the stacked mask output
 # of the kernel indexes into this list. Order mirrors the reference's
 # predicatesOrdering (predicates.go:133) restricted to tensorized ones.

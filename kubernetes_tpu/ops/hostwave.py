@@ -600,7 +600,7 @@ def schedule_wave_host(nt, pm, tt, pb, extra_mask, rr_start: int,
                        has_ipa: bool = False, has_ts=None,
                        usage_in=None,
                        collect_scores: bool = False,
-                       weight_vec=None) -> WaveResult:
+                       weight_vec=None, nom=None) -> WaveResult:
     """One batched host wave: masks + scores over (P x N), then the
     sequential greedy commit with usage carry — the numpy statement of
     _wave_body's lax.scan. has_ipa compiles in the inter-pod affinity
@@ -625,6 +625,10 @@ def schedule_wave_host(nt, pm, tt, pb, extra_mask, rr_start: int,
     compute, in the identical f32 op order (degraded mode and the
     shadow exact-mode twin run under the same hot-swapped vector the
     device path uses).
+
+    nom: optional enc.Nominations (own [P]), the kernel's nominated-pod
+    term in its op order; the returned usage then ends with the updated
+    (req, count) rows, for the next chained wave.
     """
     N = nt.valid.shape[0]
     P = pb.req.shape[0]
@@ -713,9 +717,28 @@ def schedule_wave_host(nt, pm, tt, pb, extra_mask, rr_start: int,
     ipa_masks = np.ones((P, N), bool)
     ts_masks = np.ones((P, N), bool)
 
+    if nom is not None:
+        nreq = np.array(nom.req, np.float32, copy=True)
+        ncnt = np.array(nom.count, np.int32, copy=True)
+        nprio = np.asarray(nom.prio, np.int32)
+        node_ids = np.arange(N, dtype=np.int32)
     for i in range(P):
-        fits = resource_fit(nt.alloc, nt.allowed_pods, req_c, cnt_c,
-                            pb.req[i][None, :], is_core)[0]
+        if nom is None:
+            fits = resource_fit(nt.alloc, nt.allowed_pods, req_c, cnt_c,
+                                pb.req[i][None, :], is_core)[0]
+        else:
+            # ops/kernel.py _nominated_use, in its op order
+            lvl = int(np.sum((nprio < pb.prio[i]).astype(np.int32)))
+            mine = node_ids == nom.own[i]
+            row = nreq[min(lvl, len(nprio) - 1)]
+            add_req = (np.where(lvl < len(nprio), row, F(0.0))
+                       - np.where(mine[:, None], pb.req[i][None, :], F(0.0)))
+            add_cnt = (np.where(lvl < len(nprio),
+                                ncnt[min(lvl, len(nprio) - 1)], 0)
+                       - mine.astype(np.int32))
+            fits = resource_fit(nt.alloc, nt.allowed_pods, req_c + add_req,
+                                cnt_c + add_cnt, pb.req[i][None, :],
+                                is_core)[0]
         dyn_fits[i] = fits
         feasible = static_nonres[i] & fits & nt.valid & bool(pb.valid[i])
         if has_ipa:
@@ -892,6 +915,12 @@ def schedule_wave_host(nt, pm, tt, pb, extra_mask, rr_start: int,
             nz_c[c] += pb.nonzero[i]
             cnt_c[c] += 1
             rr += 1
+            if nom is not None and nom.own[i] >= 0:
+                # ops/kernel.py _drop_nominated
+                drop = (nprio <= pb.prio[i])[:, None] & mine[None, :]
+                nreq = nreq - np.where(drop[:, :, None],
+                                       pb.req[i][None, None, :], F(0.0))
+                ncnt = ncnt - drop.astype(np.int32)
             if collect_scores:
                 d_cparts[i] = parts[:, c]
         elif collect_scores:
@@ -921,6 +950,8 @@ def schedule_wave_host(nt, pm, tt, pb, extra_mask, rr_start: int,
     res = WaveResult(chosen=chosen, score=best_s, feasible_count=feas_cnt,
                      fail_counts=fail_counts, masks=masks,
                      rr_end=np.int32(rr), deco=deco, finite=finite)
+    if nom is not None:
+        return res, (req_c, nz_c, cnt_c, nreq, ncnt)
     return res, (req_c, nz_c, cnt_c)
 
 

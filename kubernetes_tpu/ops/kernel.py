@@ -231,7 +231,8 @@ def dispatch_bucket(nt, pm, tt, kw, lead=()) -> tuple:
         int(bool(kw.get("has_ts", False))),
         int(bool(kw.get("use_pallas", False))),
         int(bool(kw.get("collect_scores", False))),
-        int(kw.get("weight_vec") is not None))
+        int(kw.get("weight_vec") is not None),
+        0 if kw.get("nom") is None else int(kw["nom"].prio.shape[0]))
 
 
 def record_dispatch(program: str, bucket_key: tuple, fn):
@@ -308,6 +309,31 @@ def record_dispatch(program: str, bucket_key: tuple, fn):
     return out
 
 
+def _nominated_use(nreq, ncnt, nprio, own, pprio, preq):
+    """What the pods nominated to each node add to one pod's fit: the
+    row of the nominated pods of priority >= the pod's, less the pod's
+    own nomination. Returns (f32 [N, R], i32 [N])."""
+    L = nprio.shape[0]
+    lvl = jnp.sum((nprio < pprio).astype(jnp.int32))
+    on = lvl < L
+    row = jnp.minimum(lvl, L - 1)
+    mine = jnp.arange(nreq.shape[1], dtype=jnp.int32) == own
+    add_req = (jnp.where(on, nreq[row], 0.0)
+               - jnp.where(mine[:, None], preq[None, :], 0.0))
+    add_cnt = jnp.where(on, ncnt[row], 0) - mine.astype(jnp.int32)
+    return add_req, add_cnt
+
+
+def _drop_nominated(nreq, ncnt, nprio, own, pprio, preq, placed):
+    """A pod that placed leaves every nomination row it was in (1.11
+    deletes a nominated pod's nomination when it is assumed)."""
+    mine = jnp.arange(nreq.shape[1], dtype=jnp.int32) == own
+    drop = (nprio <= pprio)[:, None] & mine[None, :] & placed
+    nreq = nreq - jnp.where(drop[:, :, None], preq[None, None, :], 0.0)
+    ncnt = ncnt - drop.astype(jnp.int32)
+    return nreq, ncnt
+
+
 def pallas_default() -> bool:
     """Use the fused Pallas filter kernel? KTPU_PALLAS=1/0 forces;
     'auto' (default) enables it on the TPU backend only."""
@@ -326,12 +352,17 @@ def _wave_body(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
                weights: Weights, num_zones: int, num_label_values: int,
                has_ipa: bool, use_pallas: bool, pallas_interpret: bool,
                usage_in=None, taint_ports=None, collect_scores: bool = False,
-               weight_vec=None, has_ts: bool = False):
+               weight_vec=None, has_ts: bool = False, nom=None):
     """Shared wave computation. usage_in: optional (requested, nonzero,
     pod_count) overriding nt's usage columns — the device-resident carry
     that lets consecutive waves chain without a host roundtrip.
     taint_ports: precomputed (taints_ok, ports_ok) [P, N] from the
     round path's hoisted Pallas pass. Returns (WaveResult, usage_out).
+
+    nom: optional enc.Nominations (own [P]): each pod's resource fit
+    counts the pods nominated to each node, and a nominated pod that
+    places drops out of the rows for the pods after it. usage_out then
+    ends with the updated (req, count) rows. None compiles nothing in.
 
     collect_scores (static): keep the per-priority score stack alive
     through the scan and emit, per pod, the SCORE_STACK contributions of
@@ -424,6 +455,9 @@ def _wave_body(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
     pod_count0 = usage0[2]
 
     def step(carry, x):
+        if nom is not None:
+            carry, (nreq_c, ncnt_c) = carry[:-2], carry[-2:]
+            x, nown = x[:-1], x[-1]
         req_c, nz_c, cnt_c, rr, placed = carry
         if collect_scores:
             x, (avoid_row, img_row, extra_row) = x[:-3], x[-3:]
@@ -437,8 +471,14 @@ def _wave_body(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
              ra_has_i, rn_has_i, ra_self_i) = x
         else:
             (i, preq, pnz, mask_sn, araw, traw, scnt, sscore, pvalid) = x
-        fits = resource_fit(nt.alloc, nt.allowed_pods, req_c, cnt_c,
-                            preq[None, :], is_core)[0]  # [N]
+        if nom is None:
+            fits = resource_fit(nt.alloc, nt.allowed_pods, req_c, cnt_c,
+                                preq[None, :], is_core)[0]  # [N]
+        else:
+            add_req, add_cnt = _nominated_use(nreq_c, ncnt_c, nom.prio,
+                                              nown, pprio, preq)
+            fits = resource_fit(nt.alloc, nt.allowed_pods, req_c + add_req,
+                                cnt_c + add_cnt, preq[None, :], is_core)[0]
         feasible = mask_sn & fits & nt.valid & pvalid
         if has_ipa:
             active = placed >= 0
@@ -610,10 +650,15 @@ def _wave_body(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
             top_vals, top_idx = lax.top_k(sm, kk)
             out = out + (parts[:, safe], top_idx.astype(jnp.int32),
                          top_vals, jnp.take(parts, top_idx, axis=1))
+        if nom is not None:
+            return (req_c, nz_c, cnt_c, rr, placed) + _drop_nominated(
+                nreq_c, ncnt_c, nom.prio, nown, pprio, preq, has), out
         return (req_c, nz_c, cnt_c, rr, placed), out
 
     carry0 = (usage0[0], usage0[1], usage0[2],
               jnp.asarray(rr_start, jnp.int32), jnp.full((P,), -1, jnp.int32))
+    if nom is not None:
+        carry0 = carry0 + (nom.req, nom.count)
     ii = jnp.arange(P, dtype=jnp.int32)
     if has_ipa:
         node_dom_rn_full = ipa.node_dom_rn
@@ -632,9 +677,11 @@ def _wave_body(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
                    topo.counts, topo.present, topo.wm, topo.selfm)
     if collect_scores:
         xs = xs + (avoid_full, img_full, extra_full)
+    if nom is not None:
+        xs = xs + (nom.own,)
     with jax.named_scope("pod_scan"):
-        (req_end, nz_end, cnt_end, rr_end, _), outs = \
-            lax.scan(step, carry0, xs)
+        carry_end, outs = lax.scan(step, carry0, xs)
+    req_end, nz_end, cnt_end, rr_end = carry_end[:4]
     chosen, best, dyn_fits, feas_cnt, ipa_masks = outs[:5]
     rest = outs[5:]
     ts_masks = None
@@ -668,7 +715,15 @@ def _wave_body(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
     res = WaveResult(chosen=chosen, score=best, feasible_count=feas_cnt,
                      fail_counts=fail_counts, masks=masks, rr_end=rr_end,
                      deco=deco, finite=finite)
-    return res, (req_end, nz_end, cnt_end)
+    return res, (req_end, nz_end, cnt_end) + tuple(carry_end[5:])
+
+
+def _drop_no_nominations(kw: dict) -> None:
+    """jit keys a call by its keyword names, so nom=None and no nom would
+    be two cache entries of one program: a warm-up that passes no nom
+    would leave the first round that passes None to compile again."""
+    if "nom" in kw and kw["nom"] is None:
+        del kw["nom"]
 
 
 def schedule_wave(*args, **kw):
@@ -682,6 +737,7 @@ def schedule_wave(*args, **kw):
     # featurized batch (numpy in every real call path) so spread-free
     # waves keep the exact pre-topology program
     kw.setdefault("has_ts", bool(np.any(np.asarray(pb.ts_valid))))
+    _drop_no_nominations(kw)
     bucket = dispatch_bucket(nt, pm, tt, kw, lead=(pb.req.shape[0],))
     return record_dispatch("wave", bucket,
                            lambda: _schedule_wave(*args, **kw))
@@ -698,7 +754,7 @@ def _schedule_wave(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
                    use_pallas: bool = False,
                    pallas_interpret: bool = False,
                    collect_scores: bool = False,
-                   weight_vec=None) -> WaveResult:
+                   weight_vec=None, nom=None) -> WaveResult:
     """extra_mask: bool [P, N] — host-evaluated predicates (NoDiskConflict,
     volume predicates) for the rare pods that need them; all-True rows for
     everyone else. Appended to the mask stack as a final "HostPlugins"
@@ -715,12 +771,15 @@ def _schedule_wave(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
     keeps the program identical to the affinity-free kernel.
 
     weight_vec: optional traced f32 [S] live weight vector (see
-    _wave_body) — the hot-swap path never recompiles on a value change."""
+    _wave_body) — the hot-swap path never recompiles on a value change.
+
+    nom: optional enc.Nominations (own [P]) the pods' fit counts (see
+    _wave_body); None keeps the program without them."""
     res, _ = _wave_body(nt, pm, tt, pb, extra_mask, rr_start, extra_scores,
                         weights, num_zones, num_label_values, has_ipa,
                         use_pallas, pallas_interpret,
                         collect_scores=collect_scores,
-                        weight_vec=weight_vec, has_ts=has_ts)
+                        weight_vec=weight_vec, has_ts=has_ts, nom=nom)
     return res
 
 
@@ -764,6 +823,7 @@ def schedule_round(*args, **kw):
     faultpoints.fire("kernel.round")
     nt, pm, tt, pbs = args[0], args[1], args[2], args[3]
     kw.setdefault("has_ts", bool(np.any(np.asarray(pbs.ts_valid))))
+    _drop_no_nominations(kw)
     bucket = dispatch_bucket(nt, pm, tt, kw,
                              lead=(pbs.req.shape[0], pbs.req.shape[1]))
     return record_dispatch("round", bucket,
@@ -780,7 +840,7 @@ def _schedule_round(nt: enc.NodeTensors, pm: enc.PodMatrix,
                    num_label_values: int = 64, has_ipa: bool = False,
                    has_ts: bool = False,
                    use_pallas: bool = False, pallas_interpret: bool = False,
-                   collect_scores: bool = False, weight_vec=None):
+                   collect_scores: bool = False, weight_vec=None, nom=None):
     """An ENTIRE scheduling round as one program: lax.scan over W waves,
     each wave a full _wave_body pass whose placements are staged into the
     pod matrix / term table carries before the next wave runs.
@@ -806,7 +866,12 @@ def _schedule_round(nt: enc.NodeTensors, pm: enc.PodMatrix,
     deco, finite) — deco a ScoreDeco of [W, P, ...] planes when
     collect_scores, None otherwise (the compiled program is then
     unchanged); finite the [W, P] numeric-integrity sentinel
-    (WaveResult.finite semantics, pad waves all-True)."""
+    (WaveResult.finite semantics, pad waves all-True).
+
+    nom: optional enc.Nominations with own [W, P]: every wave's fit
+    counts the pods nominated to each node, and the rows carry from
+    wave to wave, so a nominated pod placed in one wave no longer counts
+    for the later ones. None compiles nothing in."""
     W = pbs.req.shape[0]
     P = pbs.req.shape[1]
     N = nt.valid.shape[0]
@@ -817,6 +882,11 @@ def _schedule_round(nt: enc.NodeTensors, pm: enc.PodMatrix,
     KK = min(SCORE_TOPK, N)
 
     def live_wave(carry, x):
+        wnom = None
+        if nom is not None:
+            carry, (nreq_c, ncnt_c) = carry[:-2], carry[-2:]
+            x, own = x[:-1], x[-1]
+            wnom = enc.Nominations(nreq_c, ncnt_c, nom.prio, own)
         pm_c, tt_c, usage_c, rr_c = carry
         pb, rows, trows, tp = x
         res, usage_o = _wave_body(nt, pm_c, tt_c, pb, ones, rr_c, None,
@@ -824,7 +894,8 @@ def _schedule_round(nt: enc.NodeTensors, pm: enc.PodMatrix,
                                   has_ipa, False, pallas_interpret,
                                   usage_in=usage_c, taint_ports=tp,
                                   collect_scores=collect_scores,
-                                  weight_vec=weight_vec, has_ts=has_ts)
+                                  weight_vec=weight_vec, has_ts=has_ts,
+                                  nom=wnom)
         with jax.named_scope("stage_placements"):
             pm_o, tt_o = _stage_placements(pm_c, tt_c, res.chosen, rows,
                                            trows)
@@ -832,7 +903,7 @@ def _schedule_round(nt: enc.NodeTensors, pm: enc.PodMatrix,
         if collect_scores:
             out = out + tuple(res.deco)
         out = out + (res.finite,)
-        return (pm_o, tt_o, usage_o, res.rr_end), out
+        return (pm_o, tt_o, usage_o[:3], res.rr_end) + usage_o[3:], out
 
     def padded_wave(carry, x):
         # bucket-padding waves skip the whole body at RUNTIME (lax.cond
@@ -883,21 +954,25 @@ def _schedule_round(nt: enc.NodeTensors, pm: enc.PodMatrix,
             ports_all = jnp.concatenate(p_parts, axis=0)
 
         def wave(carry, x):
-            pb, rows, trows, act, ta, po = x
+            pb, rows, trows, act, ta, po = x[:6]
             return lax.cond(act, live_wave, padded_wave, carry,
-                            (pb, rows, trows, (ta, po)))
+                            (pb, rows, trows, (ta, po)) + x[6:])
 
         xs = (pbs, pm_rows, term_rows, active, taints_all, ports_all)
     else:
         def wave(carry, x):
-            pb, rows, trows, act = x
+            pb, rows, trows, act = x[:4]
             return lax.cond(act, live_wave, padded_wave, carry,
-                            (pb, rows, trows, None))
+                            (pb, rows, trows, None) + x[4:])
 
         xs = (pbs, pm_rows, term_rows, active)
 
     carry0 = (pm, tt, usage, jnp.asarray(rr_start, jnp.int32))
-    (_, _, usage_end, rr_end), outs = lax.scan(wave, carry0, xs)
+    if nom is not None:
+        xs = xs + (nom.own,)
+        carry0 = carry0 + (nom.req, nom.count)
+    carry_end, outs = lax.scan(wave, carry0, xs)
+    usage_end, rr_end = carry_end[2], carry_end[3]
     if collect_scores:
         chosen, fail_counts, cparts, tidx, tvals, tparts, finite = outs
         deco = ScoreDeco(chosen_parts=cparts, top_idx=tidx,

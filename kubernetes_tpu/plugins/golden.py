@@ -512,10 +512,36 @@ ORDERED_PREDICATES: List[Tuple[str, Callable[[api.Pod, NodeInfo], PredicateResul
 
 def pod_fits_on_node(pod: api.Pod, ni: NodeInfo,
                      always_check_all: bool = False,
-                     view: Optional[ClusterView] = None) -> PredicateResult:
-    """Reference: generic_scheduler.go:456 podFitsOnNode inner loop with
+                     view: Optional[ClusterView] = None,
+                     nominated: Sequence[api.Pod] = ()) -> PredicateResult:
+    """Reference: generic_scheduler.go:456 podFitsOnNode with
     short-circuit ordering (:503). view enables MatchInterPodAffinity
-    (last in predicatesOrdering, predicates.go:139)."""
+    (last in predicatesOrdering, predicates.go:139).
+
+    nominated: the pods nominated to this node (the queue's
+    waiting_pods_for_node). Those of priority >= the pod's, other than
+    the pod itself, are added to the node for a first pass
+    (addNominatedPods); where any were, the pod must also fit without
+    them, as 1.11 runs the predicates twice."""
+    prio = api.pod_priority(pod)
+    adds = [p for p in nominated
+            if p.uid != pod.uid and api.pod_priority(p) >= prio]
+    if adds:
+        with_nom = ni.clone()
+        for p in adds:
+            with_nom.add_pod(p)
+        ok, reasons = _fits_once(
+            pod, with_nom, always_check_all,
+            None if view is None else ClusterView(view.node_infos,
+                                                  override=with_nom))
+        if not ok:
+            return ok, reasons
+    return _fits_once(pod, ni, always_check_all, view)
+
+
+def _fits_once(pod: api.Pod, ni: NodeInfo, always_check_all: bool,
+               view: Optional[ClusterView]) -> PredicateResult:
+    """One pass of the ordered predicates over `ni`."""
     reasons: List[str] = []
     for name, pred in ORDERED_PREDICATES:
         ok, r = pred(pod, ni)

@@ -893,6 +893,13 @@ class SchedulingQueue:
         with self._lock:
             return list(self._nominated.get(node_name, {}).values())
 
+    def nominated_pods(self) -> List[Tuple[api.Pod, str]]:
+        """Every (pod, node name) nomination the queue records, in the
+        order they were made per node."""
+        with self._lock:
+            return [(p, name) for name, pods in self._nominated.items()
+                    for p in pods.values()]
+
     # -- introspection ---------------------------------------------------------
 
     def pending_count(self) -> int:

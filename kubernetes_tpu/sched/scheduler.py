@@ -96,6 +96,11 @@ PREEMPT_HOST_STEPS = ("host what-if", "fetched", "validated+performed")
 # the parts of _commit timed per pipeline round while the step profiler
 # is on, recorded as steps of the "commit" phase
 COMMIT_PARTS = ("recheck", "assume", "bind")
+# the parts of a preempt chunk's host loop (its validated+performed
+# step) timed the same way, as steps of the "preempt" phase: ordering
+# the device candidates, selectVictimsOnNode over them, and the
+# nomination and eviction writes
+PREEMPT_PARTS = ("rank", "validate", "perform")
 
 
 def _accrue(parts: List[float], i: int, since: float) -> float:
@@ -727,9 +732,30 @@ class Scheduler:
         per-round coverage gap."""
         counts: Dict[str, int] = {}
         for p in pods:
-            r = self.featurizer.golden_reason(p)
+            r = (self.featurizer.golden_reason(p)
+                 if _pod_has_ipa_terms(p) or self.featurizer.needs_host_path(p)
+                 else "nominated")
             counts[r] = counts.get(r, 0) + 1
         return counts
+
+    def _split_golden(self, pods: List[api.Pod]):
+        """(pods for the exact golden path, the rest). Golden takes what
+        the device cannot encode (needs_host_path) and, while the queue
+        holds nominations, what the device's nomination term does not
+        count: it adds a nominated pod's requests to its node, not its
+        inter-pod (anti)affinity terms or labels. So then the pods with
+        such terms go to golden, and every pod where a nominated pod
+        carries them."""
+        nominated = [p for p, _ in self.queue.nominated_pods()]
+        every = any(_pod_has_ipa_terms(p) for p in nominated)
+        host, rest = [], []
+        for p in pods:
+            if (every or self.featurizer.needs_host_path(p)
+                    or (nominated and _pod_has_ipa_terms(p))):
+                host.append(p)
+            else:
+                rest.append(p)
+        return host, rest
 
     # -- observability hooks ---------------------------------------------------
 
@@ -1007,7 +1033,8 @@ class Scheduler:
         return out, shadow
 
     def _shadow_exact_sample(self, wave_pods, pb, chosen_row, rr_start,
-                             has_ipa: bool, gating) -> Optional[Dict]:
+                             has_ipa: bool, gating,
+                             nom=None) -> Optional[Dict]:
         """Opt-in exact shadow mode (shadow_exact_interval > 0): every
         Nth traced round replays its FIRST wave through the numpy host
         twin under each candidate vector — exact candidate placements,
@@ -1039,7 +1066,7 @@ class Scheduler:
                 num_zones=self.snapshot.caps.Z,
                 num_label_values=self.snapshot.num_label_values,
                 has_ipa=has_ipa,
-                weight_vec=vec)
+                weight_vec=vec, nom=nom)
             flips = int(np.sum(np.asarray(res.chosen)[:n] != chosen_dev))
             self.weightbook.record_exact(name, n, flips)
             out[name] = {"pods": n, "flips": flips}
@@ -1465,15 +1492,12 @@ class Scheduler:
                 all_pods = [p for p in all_pods
                             if self.gangs.key(p) is None]
                 placed += self._schedule_gangs(gang_pods)
-            host_path = [p for p in all_pods
-                         if self.featurizer.needs_host_path(p)]
+            host_path, pods = self._split_golden(all_pods)
             # golden-path pods have no ScoreDeco: count them by reason
             # so the round record shows the shadow observatory's
             # coverage gap alongside the shadow divergence itself
             golden = self._golden_reasons(host_path)
             placed += self._schedule_host_batch(host_path)
-            pods = [p for p in all_pods
-                    if not self.featurizer.needs_host_path(p)]
             if not pods:
                 if golden:
                     tracing.event("golden_gap", **golden)
@@ -1724,6 +1748,9 @@ class Scheduler:
             return self._isolate_poison(pods, verdict, self._run_pipeline)
         pm_rows_all, term_rows_all = self.snapshot.stage_pending(pods)
         tpp = term_rows_all.shape[1]
+        nw = len(waves)
+        wbucket = pipeline_bucket(nw, hi=max_waves)
+        nom = self._nominations(waves, pbs[0].req.shape[0], wbucket)
         trace.step("featurized+staged")
         if rt is not None:
             rt.mark("featurize", pods=len(pods))
@@ -1750,8 +1777,6 @@ class Scheduler:
         has_ipa = bool(self.snapshot.has_affinity_terms
                        or any(pb.ra_has.any() or pb.rn_has.any()
                               or (pb.pa_w != 0).any() for pb in pbs))
-        nw = len(waves)
-        wbucket = pipeline_bucket(nw, hi=max_waves)
         pbs_stacked, pm_rows, term_rows = assemble_round(
             pbs, waves, pm_rows_all, term_rows_all, wbucket, tpp)
         trace.annotate(pods=len(pods), waves=nw, bucket=wbucket)
@@ -1768,6 +1793,9 @@ class Scheduler:
             term_rows = replicate(self._active_mesh, term_rows)
             self._rr = replicate(self._active_mesh, self._rr)
             wv = replicate(self._active_mesh, wv)
+            if nom is not None:
+                nom = enc.Nominations(
+                    *replicate(self._active_mesh, tuple(nom)))
         # the Pallas taint/port kernel is HOISTED out of the round's
         # lax.scan (ops/kernel.py schedule_round: one call covering all
         # waves) — under the scan it faults on Mosaic. A pallas round
@@ -1790,7 +1818,7 @@ class Scheduler:
                 num_zones=self.snapshot.caps.Z,
                 num_label_values=self.snapshot.num_label_values,
                 has_ipa=has_ipa, use_pallas=use_p,
-                collect_scores=collect, weight_vec=wv)
+                collect_scores=collect, weight_vec=wv, nom=nom)
             trace.step("dispatched")
             # wait for the round before fetching, so the trace's
             # "executed" and "fetched" steps split device time from
@@ -1914,7 +1942,10 @@ class Scheduler:
         exact_info = None
         if rt is not None and deco_all is not None:
             exact_info = self._shadow_exact_sample(
-                waves[0], pbs[0], chosen_all[0], self._rr, has_ipa, gating)
+                waves[0], pbs[0], chosen_all[0], self._rr, has_ipa, gating,
+                None if nom is None
+                else enc.Nominations(*(np.asarray(a) for a in nom[:3]),
+                                     np.asarray(nom.own)[0]))
         self._rr = rr_end
         # mirror: the round's scan advanced rr once per placement
         self._host_rr += int(np.sum(chosen_all >= 0))
@@ -2023,6 +2054,22 @@ class Scheduler:
                                            claimed, exhausted, host=host)
         return handled
 
+    def _nominations(self, waves: List[List[api.Pod]], P: int,
+                     W: Optional[int] = None) -> Optional[enc.Nominations]:
+        """The queue's nominations as the fit of a round's waves (or, W
+        None, of one wave) counts them (Snapshot.stage_nominations);
+        None when the queue holds none. A nominated pod the cache holds
+        as assumed is left out: its requests already count where it was
+        assumed."""
+        nominated = [(p, name) for p, name in self.queue.nominated_pods()
+                     if not self.cache.is_assumed(p)]
+        if not nominated:
+            return None
+        nom = self.snapshot.stage_nominations(nominated, waves, P, W)
+        if nom is not None:
+            self.metrics.nominated_pods_staged.inc(int(nom.count[0].sum()))
+        return nom
+
     def _preempt_gang_weights(self):
         """Victim-gang disruption weights for the what-if stats: 1 for
         placed members of gangs with no slack above minMember (any
@@ -2116,14 +2163,19 @@ class Scheduler:
         trace.step("fetched")
         pdbs = self._pdbs()
         handled: set = set()
+        prof = profiling.active()
+        parts = [0.0] * len(PREEMPT_PARTS) if prof is not None else None
         # `claimed` = capacity claimed by earlier pods in this batch (the
         # host analog of the reference's nominated-pod accounting in
         # podFitsOnNode's two-pass logic): without it, one freed node
         # would absorb every later candidate's validation and the batch
         # would degenerate to one eviction per round
         for i, pod in enumerate(cands):
+            t = time.perf_counter() if parts is not None else 0.0
             cand_nodes = np.nonzero(ok[i])[0]
             if cand_nodes.size == 0:
+                if parts is not None:
+                    _accrue(parts, 0, t)
                 continue
             self.metrics.total_preemption_attempts.inc()
             # device ranking approximates the reference's tie-breaks to
@@ -2133,6 +2185,8 @@ class Scheduler:
                 cand_nodes.tolist(),
                 key=lambda n: (float(gviol[i, n]), float(pmax[i, n]),
                                float(psum[i, n]), float(victims_n[i, n])))
+            if parts is not None:
+                t = _accrue(parts, 0, t)
             aff = pod.spec.affinity
             with_aff = bool(self.snapshot.has_affinity_terms
                             or (aff is not None
@@ -2189,9 +2243,13 @@ class Scheduler:
                           validated=len(validated),
                           chosen=chosen or "")
             if chosen is None:
+                if parts is not None:
+                    _accrue(parts, 1, t)
                 continue
             victims, nviol = validated[chosen]
             claimed.setdefault(chosen, []).append(pod)
+            if parts is not None:
+                t = _accrue(parts, 1, t)
             if not victims:
                 # an earlier eviction already freed this node: the pod
                 # fits WITHOUT preempting — requeue and let the next
@@ -2203,7 +2261,12 @@ class Scheduler:
             self._park_with_backoff(pod)
             self.pipeline_preemptions += 1
             handled.add(pod.uid)
+            if parts is not None:
+                _accrue(parts, 2, t)
         trace.step("validated+performed")
+        if parts is not None:
+            for part, s in zip(PREEMPT_PARTS, parts):
+                prof.record_step("preempt", part, s)
         trace.log_if_long(0.5)
         self.metrics.preemption_evaluation.observe(self.clock() - t0)
         return handled
@@ -2372,7 +2435,7 @@ class Scheduler:
                 num_label_values=self.snapshot.num_label_values,
                 has_ipa=has_ipa,
                 collect_scores=deco_acc is not None,
-                weight_vec=wvec)
+                weight_vec=wvec, nom=self._nominations([pods], P))
         except Exception as e:
             # a crash on the HOST path follows the data by construction
             # (no runtime to blame): input fault — bisect to the
@@ -3073,13 +3136,13 @@ class Scheduler:
                 # a gang dispatch was just watchdog-abandoned: the
                 # wave must not follow it onto the wedged runtime
                 return placed_gang + self._schedule_degraded(pods)
-        # pods whose required pod-(anti)affinity spans >1 topology key take
-        # the exact host path (ops/affinity.py single-anchor limitation)
-        host_path = [p for p in pods if self.featurizer.needs_host_path(p)]
+        # pods whose required pod-(anti)affinity spans >1 topology key, or
+        # that nominated pods' affinity terms bear on, take the exact host
+        # path (_split_golden)
+        host_path, pods = self._split_golden(pods)
         placed_host = placed_gang
         golden = self._golden_reasons(host_path)
         if host_path:
-            pods = [p for p in pods if not self.featurizer.needs_host_path(p)]
             placed_host += self._schedule_host_batch(host_path)
             if not pods:
                 if golden:
@@ -3169,6 +3232,7 @@ class Scheduler:
         has_ipa = bool(self.snapshot.has_affinity_terms or pb.ra_has.any()
                        or pb.rn_has.any() or (pb.pa_w != 0).any())
         wv = jnp.asarray(wvec)
+        nom = self._nominations([pods], pb.req.shape[0])
         if self._active_mesh is not None:
             from ..parallel.mesh import (mesh_divides, replicate, shard_extra,
                                          shard_inputs)
@@ -3179,6 +3243,8 @@ class Scheduler:
             # mixing commitments in one jit is an error, so re-commit
             self._rr = replicate(mesh, self._rr)
             wv = replicate(mesh, wv)
+            if nom is not None:
+                nom = enc.Nominations(*replicate(mesh, tuple(nom)))
             if mesh_divides(mesh, nt.valid.shape[0], pb.req.shape[0]):
                 # nt/pm/tt are already committed by _to_device; re-putting
                 # to the identical shardings transfers nothing — this
@@ -3194,7 +3260,7 @@ class Scheduler:
                 # a multi-device mesh the partitionable XLA formulation is
                 # the correct hot path (GSPMD can't shard a pallas_call)
                 self._use_pallas = False
-        kw = dict(weights=gating, weight_vec=wv,
+        kw = dict(weights=gating, weight_vec=wv, nom=nom,
                   num_zones=self.snapshot.caps.Z,
                   num_label_values=self.snapshot.num_label_values,
                   has_ipa=bool(has_ipa),
@@ -3464,7 +3530,9 @@ class Scheduler:
         reasons: Dict[str, int] = {}
         failed: Dict[str, List[str]] = {}
         for name, ni in self.cache.node_infos.items():
-            ok, rs = golden.pod_fits_on_node(pod, ni, view=view)
+            ok, rs = golden.pod_fits_on_node(
+                pod, ni, view=view,
+                nominated=self.queue.waiting_pods_for_node(name))
             if ok:
                 for fname, fn in self.profile.host_filters.items():
                     if getattr(fn, "relevant", None) is not None and not fn.relevant(pod):

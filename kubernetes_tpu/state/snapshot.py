@@ -679,6 +679,39 @@ class Snapshot:
             term_rows[i, :len(rows)] = rows
         return pm_rows, term_rows
 
+    def stage_nominations(self, nominated, waves, P: int,
+                          W: Optional[int] = None
+                          ) -> Optional[enc.Nominations]:
+        """The nominations a round's (or, W None, a wave's) fit counts:
+        `nominated` is the queue's record as (pod, node name) pairs,
+        `waves` the pods of the round's waves. Returns enc.Nominations
+        with `own` [W, P] (or [P]) naming each pod's own nominated node,
+        or None when no nomination names a node the snapshot holds."""
+        from .node_info import Resource
+
+        rows = [(self.node_index[name], api.pod_priority(pod), pod)
+                for pod, name in nominated if name in self.node_index]
+        if not rows:
+            return None
+        levels = sorted({prio for _, prio, _ in rows})
+        L = bucket_size(len(levels), 1)
+        prio = np.full((L,), enc.NOM_PAD_PRIO, np.int32)
+        prio[:len(levels)] = levels
+        req = np.zeros((L, self.caps.N, self.caps.R), np.float32)
+        count = np.zeros((L, self.caps.N), np.int32)
+        node_of = {}
+        for idx, p, pod in rows:
+            k = levels.index(p) + 1  # rows 0..k-1 take priorities <= p
+            req[:k, idx] += self._res_vec(
+                Resource.from_map(api.get_resource_request(pod)))
+            count[:k, idx] += 1
+            node_of[pod.uid] = idx
+        own = np.full((len(waves) if W is None else W, P), -1, np.int32)
+        for w, pods in enumerate(waves):
+            for i, pod in enumerate(pods):
+                own[w, i] = node_of.get(pod.uid, -1)
+        return enc.Nominations(req, count, prio, own if W else own[0])
+
     def unstage(self, pod: api.Pod):
         """Free the staged rows of a pod the pipeline did not place."""
         self.remove_pod(pod)

@@ -281,6 +281,9 @@ class Metrics:
         self.binding_latency = Histogram("binding_latency")
         self.pod_preemption_victims = Counter("pod_preemption_victims")
         self.total_preemption_attempts = Counter("total_preemption_attempts")
+        # nominated pods whose requests a device or twin pass counted in
+        # the fit, summed over passes (Scheduler._nominations)
+        self.nominated_pods_staged = Counter("nominated_pods_staged_total")
         self.schedule_attempts = Counter("schedule_attempts_total")
         # gang (coscheduling) series: attempts counts whole-gang placement
         # tries; wait_seconds spans first-member-parked -> gang released

@@ -110,3 +110,14 @@ def _reset_faultpoints():
     faultpoints.reset()
     yield
     faultpoints.reset()
+
+
+@pytest.fixture(autouse=True)
+def _reset_dispatch_watchdog():
+    """A Scheduler with wave_deadline_s > 0 registers its dispatch
+    watchdog process-globally; clear it after the test, so a later
+    test's first compile is not held to that test's deadline."""
+    yield
+    from kubernetes_tpu.ops import kernel
+
+    kernel.set_watchdog(None)
