@@ -56,6 +56,8 @@ from .scores import (
     ScoreDeco,
     floor_div,
     stack_weights,
+    _domain_colocation,
+    _domain_onehot,
     balanced_allocation,
     image_locality,
     least_requested,
@@ -407,6 +409,12 @@ def _wave_body(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
                if has_ipa else None)
         topo = (topo_statics(nt, pm, pb, num_label_values) if has_ts else None)
         lv_ids = jnp.arange(num_label_values, dtype=jnp.int32)
+        # node-in-domain planes for the scan's per-domain sums (rack and
+        # superpod ids intern into the zones vocabulary, so num_zones
+        # bounds all three)
+        zone_oh = _domain_onehot(nt.zone_id, num_zones)
+        rack_oh = _domain_onehot(nt.rack_id, num_zones)
+        superpod_oh = _domain_onehot(nt.superpod_id, num_zones)
 
         w = weights
         # the weighted-sum multipliers: the traced weight_vec when the live
@@ -561,7 +569,7 @@ def _wave_body(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
                    if w.taint_toleration or collect_scores else None)
         if w.taint_toleration:
             total = total + wv[W_TAINT] * taint_n
-        spread_n = (spread_reduce(scnt, feasible, nt.zone_id, num_zones)
+        spread_n = (spread_reduce(scnt, feasible, nt.zone_id, zone_oh)
                     if w.selector_spread or collect_scores else None)
         if w.selector_spread:
             total = total + wv[W_SPREAD] * spread_n
@@ -596,19 +604,15 @@ def _wave_body(nt: enc.NodeTensors, pm: enc.PodMatrix, tt: enc.TermTable,
         compact_n = None
         if w.topology_compactness or collect_scores:
             # gang compactness + heterogeneity steering: count this
-            # wave's placements per rack/superpod (ids intern into the
-            # shared zones vocab — state/snapshot.py — so num_zones
-            # bounds the segment-sums), prefer co-located nodes with a
-            # rack-over-superpod gradient, and bias priority-bearing
-            # (throughput-sensitive) pods toward newer accelerator
-            # generations. All-zero columns make this plane exactly 0.
+            # wave's placements per rack/superpod, prefer co-located
+            # nodes with a rack-over-superpod gradient, and bias
+            # priority-bearing (throughput-sensitive) pods toward newer
+            # accelerator generations. All-zero columns make this plane
+            # exactly 0.
             wave_placed = (cnt_c - pod_count0).astype(jnp.float32)
-            rsum = jax.ops.segment_sum(wave_placed, nt.rack_id,
-                                       num_segments=num_zones)
-            rackc = rsum[nt.rack_id] * (nt.rack_id > 0)
-            ssum = jax.ops.segment_sum(wave_placed, nt.superpod_id,
-                                       num_segments=num_zones)
-            spc = ssum[nt.superpod_id] * (nt.superpod_id > 0)
+            rackc = _domain_colocation(wave_placed, nt.rack_id, rack_oh)
+            spc = _domain_colocation(wave_placed, nt.superpod_id,
+                                    superpod_oh)
             gen = nt.accel_gen.astype(jnp.float32) * (pprio > 0)
             compact_raw = 3.0 * rackc + spc + gen
             compact_n = normalize_reduce(compact_raw, feasible, False)

@@ -208,18 +208,47 @@ def spread_counts(pm: PodMatrix, pb: PodBatch, num_nodes: int) -> jnp.ndarray:
     return jax.vmap(seg)(matched)
 
 
-def spread_reduce(cnt, feasible, zone_id, num_zones: int):
+def _domain_onehot(ids, num_domains: int):
+    """bool [Z, N] — node n lies in domain z (ids < Z: the snapshot's
+    vocabulary bounds them). Built once per wave, ahead of the scan, so
+    the scan's per-domain sums need no scatter and no gather."""
+    return ids[None, :] == jnp.arange(num_domains, dtype=ids.dtype)[:, None]
+
+
+def _domain_sums(v, onehot):
+    """[Z] — per-domain sums of the node values v [N] (segment_sum by
+    compare-and-reduce: a scatter over the node axis runs one update at
+    a time on the TPU)."""
+    # Node-axis sum of integer-valued f32 far below 2^24: exact in any
+    # association, so bit-equal to the twin's bincount.
+    # ktpu: allow[f32-reduction] integer-valued, exact in any order, twin-mirrored
+    return jnp.sum(jnp.where(onehot, v[None, :], 0.0), axis=1)
+
+
+def _domain_values(s, onehot):
+    """[N] — each node's entry of the per-domain values s [Z] (s[ids]
+    without a gather)."""
+    # ktpu: allow[f32-reduction] one term per node, exact, twin-mirrored
+    return jnp.sum(jnp.where(onehot, s[:, None], 0.0), axis=0)
+
+
+def _domain_colocation(v, ids, onehot):
+    """[N] — the sum of v [N] over each node's domain, 0 on nodes with
+    no domain (id 0)."""
+    return _domain_values(_domain_sums(v, onehot), onehot) * (ids > 0)
+
+
+def spread_reduce(cnt, feasible, zone_id, zone_onehot):
     """[N] — reference selector_spreading.go:122 CalculateSpreadPriorityReduce
-    with zoneWeighting = 2/3."""
+    with zoneWeighting = 2/3. zone_onehot: _domain_onehot(zone_id, Z)."""
     cntf = jnp.where(feasible, cnt, 0).astype(jnp.float32)
     max_node = jnp.max(cntf)
-    zc = jax.ops.segment_sum(jnp.where(zone_id > 0, cntf, 0.0), zone_id,
-                             num_segments=num_zones)
-    max_zone = jnp.max(zc.at[0].set(0.0))
+    zc = _domain_sums(jnp.where(zone_id > 0, cntf, 0.0), zone_onehot)
+    max_zone = jnp.max(jnp.where(jnp.arange(zc.shape[0]) > 0, zc, 0.0))
     have_zones = jnp.any(feasible & (zone_id > 0))
     f = jnp.where(max_node > 0, MAX_PRIORITY * (max_node - cntf) / jnp.maximum(max_node, 1.0),
                   MAX_PRIORITY)
-    node_zc = zc[zone_id]
+    node_zc = _domain_values(zc, zone_onehot)
     zscore = jnp.where(max_zone > 0, MAX_PRIORITY * (max_zone - node_zc) / jnp.maximum(max_zone, 1.0),
                        MAX_PRIORITY)
     f = jnp.where(have_zones & (zone_id > 0), f / 3.0 + (2.0 / 3.0) * zscore, f)

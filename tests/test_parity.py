@@ -235,7 +235,8 @@ def test_spread_parity(seed):
         if not feas.any():
             continue
         dev = np.asarray(scores.spread_reduce(
-            jnp.asarray(cnt[pi]), jnp.asarray(feas), nt.zone_id, snap.caps.Z))
+            jnp.asarray(cnt[pi]), jnp.asarray(feas), nt.zone_id,
+            scores._domain_onehot(nt.zone_id, snap.caps.Z)))
         counts = {n.name: int(cnt[pi, i]) for i, n in enumerate(nodes) if feas[i]}
         zones = {n.name: api.get_zone_key(n) for n in nodes}
         gold = golden.selector_spread_reduce(counts, zones)

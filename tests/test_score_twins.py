@@ -213,5 +213,6 @@ class TestArrayKernelTwins:
         cnt = rng.randint(0, 6, 24).astype(np.int32)
         feasible = rng.rand(24) < 0.8
         zone_id = rng.randint(0, 4, 24).astype(np.int32)
-        _eq(scores.spread_reduce(cnt, feasible, zone_id, 4),
+        zone_oh = scores._domain_onehot(np.asarray(zone_id), 4)
+        _eq(scores.spread_reduce(cnt, feasible, zone_id, zone_oh),
             hostwave.spread_reduce(cnt, feasible, zone_id, 4))

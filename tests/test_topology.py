@@ -396,6 +396,157 @@ class TestGangCompactness:
 
 
 # ---------------------------------------------------------------------------
+# the scan's per-domain sums: compare-and-reduce over one-hot planes
+
+
+def domain_world(seed, racks):
+    """Twenty-four nodes, a service selecting `app=a`, running `app=a`
+    pods for the selector spread to count, and a round of pending pods
+    of both apps and of priority 0 and 5 (5 turns on the accel-gen
+    bias). racks=False leaves every zone, rack and superpod id 0, as the
+    benchmark's clusters have them; racks=True spreads the nodes over 3
+    zones and 20 racks under 3 superpods, so the shared zones vocabulary
+    grows past 8."""
+    rng = np.random.RandomState(seed)
+    store = ObjectStore()
+    sched = Scheduler(store, wave_size=8)
+    store.create("services", api.Service(
+        metadata=api.ObjectMeta(name="svc-a", namespace="default"),
+        selector={"app": "a"}))
+    for i in range(24):
+        labels = {api.LABEL_HOSTNAME: f"n{i}"}
+        if racks:
+            rack = int(rng.randint(20))
+            labels.update({api.LABEL_ZONE: f"z{i % 3}",
+                           api.LABEL_RACK: f"r{rack}",
+                           api.LABEL_SUPERPOD: f"sp{rack % 3}",
+                           api.LABEL_ACCEL_GEN: str(rng.randint(1, 4))})
+        store.create("nodes", make_node(f"n{i}", cpu=str(rng.randint(2, 5)),
+                                        memory="16Gi", labels=labels))
+    for i in range(12):
+        store.create("pods", make_pod(
+            f"ex-{i}", cpu="500m", labels={"app": "a"},
+            node_name=f"n{rng.randint(24)}"))
+    pending = [make_pod(f"pend-{i}", cpu=f"{rng.randint(2, 9)}00m",
+                        priority=int(rng.choice([0, 5])),
+                        labels={"app": str(rng.choice(["a", "b"]))})
+               for i in range(16)]
+    return store, sched, pending
+
+
+class TestDomainSums:
+    @pytest.mark.parametrize("seed,racks", [
+        pytest.param(0, False, id="flat-0"),
+        pytest.param(1, False, id="flat-1"),
+        pytest.param(0, True, id="racks-0"),
+        pytest.param(1, True, id="racks-1")])
+    def test_round_matches_twin(self, seed, racks):
+        """The device round (two chained waves) against the numpy twin
+        chained wave by wave: chosen, fail counts, round-robin, usage and
+        the whole score decomposition, bit for bit, with the selector
+        spread's zone sums and the compactness plane's rack and superpod
+        sums in force."""
+        from kubernetes_tpu.ops.kernel import schedule_round
+        from kubernetes_tpu.sched.scheduler import assemble_round
+
+        store, sched, pending = domain_world(seed, racks)
+        snap, feat = sched.snapshot, sched.featurizer
+        waves = [pending[:8], pending[8:]]
+        # the first pass interns the pods' labels, so the second's
+        # shapes are the round's
+        [feat.featurize(wv) for wv in waves]
+        pbs = [feat.featurize(wv) for wv in waves]
+        assert np.any(np.asarray(pbs[0].sg_valid)), "pods must spread"
+        if racks:
+            assert snap.caps.Z > 8
+            assert len(set(np.asarray(snap.node_tensors().rack_id))) > 8
+        else:
+            nt0 = snap.node_tensors()
+            for ids in (nt0.zone_id, nt0.rack_id, nt0.superpod_id):
+                assert not np.any(np.asarray(ids))
+        P = pbs[0].req.shape[0]
+        pm_rows, term_rows = snap.stage_pending(pending)
+        kw = _weights(sched)
+        nt, pm, tt = snap.to_device()
+        stacked, rows, trows = assemble_round(
+            pbs, waves, pm_rows, term_rows, 2, term_rows.shape[1])
+        chosen, fails, usage_d, rr_d, deco, _fin = schedule_round(
+            nt, pm, tt, stacked, (nt.requested, nt.nonzero, nt.pod_count),
+            jnp.asarray(2, jnp.int32), rows, trows, has_ipa=False,
+            collect_scores=True, **kw)
+        nth, pmh, tth = snap.host_tensors()
+        pm_node, pm_valid = pmh.node.copy(), pmh.valid.copy()
+        usage = (nth.requested, nth.nonzero, nth.pod_count)
+        rr = 2
+        for w, pb in enumerate(pbs):
+            pm_w = pmh._replace(node=pm_node.copy(), valid=pm_valid.copy())
+            res, usage = hostwave.schedule_wave_host(
+                nth, pm_w, tth, pb, np.ones((P, nth.valid.shape[0]), bool),
+                rr, usage_in=usage, collect_scores=True, **kw)
+            rr = int(res.rr_end)
+            np.testing.assert_array_equal(np.asarray(chosen[w]), res.chosen)
+            np.testing.assert_array_equal(np.asarray(fails[w]),
+                                          res.fail_counts)
+            for a, b in zip(deco, res.deco):
+                np.testing.assert_array_equal(np.asarray(a[w]), b)
+            placed = (res.chosen >= 0) & (rows[w] >= 0)
+            pm_node[rows[w][placed]] = res.chosen[placed]
+            pm_valid[rows[w][placed]] = True
+            # later pods of the wave see its earlier placements in the
+            # rack and superpod sums
+            assert np.sum(res.chosen >= 0) >= 2
+        assert int(np.asarray(rr_d)) == rr
+        for a, b in zip(usage_d, usage):
+            np.testing.assert_array_equal(np.asarray(a), b)
+        for p in pending:
+            snap.unstage(p)
+
+    @pytest.mark.parametrize("num_domains", [8, 32, 256])
+    def test_domain_sums_equal_segment_sum(self, num_domains):
+        """_domain_sums and _domain_values are segment_sum and the gather
+        of its result, bit for bit, on integer-valued counts (a rack
+        vocabulary of hundreds included)."""
+        import jax
+
+        from kubernetes_tpu.ops.scores import (
+            _domain_onehot, _domain_sums, _domain_values)
+
+        rng = np.random.RandomState(num_domains)
+        ids = jnp.asarray(rng.randint(num_domains, size=500), jnp.int32)
+        v = jnp.asarray(rng.randint(0, 110, size=500), jnp.float32)
+        oh = _domain_onehot(ids, num_domains)
+        want = jax.ops.segment_sum(v, ids, num_segments=num_domains)
+        got = _domain_sums(v, oh)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(_domain_values(got, oh)),
+                                      np.asarray(want[ids]))
+
+    @pytest.mark.parametrize("fn", ["spread_reduce", "_domain_colocation"])
+    def test_domain_sums_lower_without_scatter_or_gather(self, fn):
+        """The per-domain sums of the scan step lower to compares and
+        reductions: a scatter over the node axis runs one update after
+        another on the TPU, and a gather reads back what the one-hot
+        already holds."""
+        import jax
+
+        from kubernetes_tpu.ops import scores
+
+        N, Z = 16, 8
+        ids = jax.ShapeDtypeStruct((N,), jnp.int32)
+        oh = jax.ShapeDtypeStruct((Z, N), jnp.bool_)
+        f32 = jax.ShapeDtypeStruct((N,), jnp.float32)
+        if fn == "spread_reduce":
+            args = (jax.ShapeDtypeStruct((N,), jnp.int32),
+                    jax.ShapeDtypeStruct((N,), jnp.bool_), ids, oh)
+        else:
+            args = (f32, ids, oh)
+        text = jax.jit(getattr(scores, fn)).lower(*args).as_text()
+        assert "reduce" in text
+        assert "scatter" not in text
+        assert "gather" not in text
+
+
+# ---------------------------------------------------------------------------
 # recompile-free weight swaps
 
 
