@@ -3,8 +3,8 @@
 When the device path is unavailable — breaker open (sched/breaker.py),
 device preemption disabled, or an autoscaler what-if while the runtime
 is tripped — the scheduler used to fall back to the per-pod golden loop
-(plugins/golden.py): exact, but three orders of magnitude slower
-(BENCH_r05: 194.8 pods/s device vs 0.8 pods/s host preemption). The
+(plugins/golden.py): exact, but orders of magnitude slower, as it
+scores one pod against one node at a time in Python. The
 paper's thesis is that Filter+Score is ONE batched (pods x nodes)
 mask+score computation; that property survives losing the accelerator.
 This module re-states the device kernels as dense numpy ops over the

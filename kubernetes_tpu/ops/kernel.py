@@ -337,15 +337,7 @@ def _drop_nominated(nreq, ncnt, nprio, own, pprio, preq, placed):
 
 
 def pallas_default() -> bool:
-    """Use the fused Pallas filter kernel? KTPU_PALLAS=1/0 forces;
-    'auto' (default) enables it on the TPU backend only."""
-    import os
-
-    v = os.environ.get("KTPU_PALLAS", "auto")
-    if v in ("0", "false"):
-        return False
-    if v in ("1", "true"):
-        return True
+    """Use the fused Pallas filter kernel? On the TPU backend only."""
     return jax.default_backend() == "tpu"
 
 

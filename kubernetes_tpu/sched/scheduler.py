@@ -36,7 +36,7 @@ import numpy as np
 from ..api import labels as lbl
 from ..api import types as api
 from ..ops import encoding as enc
-from ..ops.kernel import Weights, pallas_default, schedule_wave
+from ..ops.kernel import schedule_wave
 from ..plugins import golden
 from ..plugins.registry import Profile, default_profile
 from ..runtime.informer import SharedInformer
@@ -51,6 +51,8 @@ from ..utils.watchdog import DispatchTimeout
 from ..utils.feature_gates import FeatureGates
 from . import breaker as breaker_mod
 from .breaker import STATE_CODES, DevicePathBreaker, is_capacity_error
+from .dispatch import (CAPACITY, DEGRADE, FAILED, HUNG, INPUT, PARK,
+                       REFORMED, SALVAGE, TRANSIENT, Formulation, Verdict)
 from .equivalence import EquivalenceCache, equivalence_class
 from .errors import (REASON_KEYS, REASONS, FitError, PoisonError,
                      insufficient_resource_reason)
@@ -417,39 +419,15 @@ class Scheduler:
         # device->twin->device transition (breaker recovery, mesh
         # reform salvage) instead of rewinding the counter to 0.
         self._host_rr = 0
-        # None = not yet resolved; resolved on first wave to
-        # pallas_default(), then demoted to False permanently if the fused
-        # pallas kernel fails to compile on this backend (a wave must
-        # always produce a result; the pure-XLA formulation is the
-        # fallback path)
-        self._use_pallas: Optional[bool] = None
-        # what the most recently EXECUTED program actually used —
-        # wave_path() reports this, never a prediction (the round-3
-        # verdict caught the driver bench labeled "pallas" for rounds
-        # that hard-code the XLA formulation)
-        self._last_path: Optional[str] = None
+        # the Pallas/XLA choice of the round, wave and gang programs,
+        # their demotion, and what wave_path() reports (sched/dispatch.py)
+        self.formulation = Formulation(
+            self.metrics,
+            multi_device=mesh is not None and mesh.devices.size > 1)
         # telemetry gauge children exported last traced round
         # ({resource names}, {(zone, resource)}) — pruned when the
         # subject disappears so /metrics never freezes a dead series
         self._tele_exported: Tuple[set, set] = (set(), set())
-        # round-program formulation: None = resolve on first round to
-        # pallas_default(); demoted to False permanently if the hoisted
-        # pallas round fails on this backend (separate from _use_pallas:
-        # the per-wave and round programs fail independently)
-        self._round_pallas: Optional[bool] = None
-        # first-pallas-round self-check pending? The Mosaic lowering is
-        # parity-tested in interpret mode on CPU, but the first REAL
-        # pallas round in each process is additionally compared against
-        # the XLA formulation on-device (warm_pipeline, or the first
-        # _run_pipeline if unwarmed) — a mismatch demotes to XLA rather
-        # than silently degrading placement quality
-        self._round_pallas_checked = False
-        if mesh is not None and mesh.devices.size > 1:
-            # the fused pallas kernels are single-device programs — GSPMD
-            # cannot shard a pallas_call — so under a multi-device mesh
-            # BOTH formulations resolve to partitionable XLA up front
-            self._use_pallas = False
-            self._round_pallas = False
         # the mesh actually used by the last _to_device upload (None when
         # caps.N doesn't divide the nodes axis — inputs ran unsharded)
         self._active_mesh = None
@@ -907,6 +885,46 @@ class Scheduler:
             rec.add_span("gang_wait", now - waited, now, cat="gang",
                          gang=key, waited_s=round(waited, 6))
 
+    def _begin_round(self, kind: str, pods: List[api.Pod], wver: str,
+                     golden: Optional[Dict[str, int]] = None, **meta):
+        """(recorder, round trace) of a traced round, (None, None) when
+        tracing is off. The trace carries each pod's queue_wait span
+        and, where golden-path pods were scheduled beside the round,
+        their count by reason: they have no ScoreDeco, the shadow
+        observatory's coverage gap."""
+        rec = tracing.active()
+        if rec is None:
+            return None, None
+        rt = rec.begin_round(kind, pending=len(pods), **meta,
+                             weights_version=wver)
+        self._trace_queue_waits(rt, pods)
+        if golden:
+            rt.ledger["golden"] = dict(golden)
+        return rec, rt
+
+    def _host_planes(self, pods: List[api.Pod], P: int):
+        """(extra mask, extra scores) of the host plugins and extenders
+        for a batch, or None when a non-ignorable extender is
+        unreachable: then only this attempt fails, and the batch parks
+        for retry on the next cluster event (reference: scheduleOne
+        records the error and MakeDefaultErrorFunc requeues with
+        backoff)."""
+        try:
+            return (self._host_plugin_mask(pods, P),
+                    self._host_score_matrix(pods, P))
+        except ExtenderError:
+            self.metrics.scheduling_errors.labels(stage="extender").inc()
+            for p in pods:
+                self._park_with_backoff(p)
+            return None
+
+    def _has_ipa(self, pbs) -> bool:
+        """The has_ipa static of a program over these batches:
+        inter-pod (anti)affinity already placed, or asked for by a pod."""
+        return bool(self.snapshot.has_affinity_terms
+                    or any(pb.ra_has.any() or pb.rn_has.any()
+                           or (pb.pa_w != 0).any() for pb in pbs))
+
     def _trace_queue_waits(self, rt, pods: List[api.Pod]) -> None:
         """Per-pod queue_wait spans (first enqueue -> popped into this
         round), keyed by UID; added_at survives until bind so reading it
@@ -1251,14 +1269,64 @@ class Scheduler:
         self._active_mesh = mesh
         return self.snapshot.to_device(mesh=mesh)
 
+    def _max_waves(self, pods: List[api.Pod]) -> int:
+        """The round's wave cap: ipa anywhere in the backlog (or already
+        placed) caps it at the ipa-safe count, even for ipa-free leading
+        rounds."""
+        return (PIPELINE_MAX_WAVES_IPA
+                if (self.snapshot.has_affinity_terms
+                    or any(_pod_has_ipa_terms(p) for p in pods))
+                else PIPELINE_MAX_WAVES)
+
+    def _device_inputs(self, pbs, wvec, nom=None, rows=None, wave=None):
+        """(has_ipa, wv, nom, rows, wave): what a device program takes
+        beside the snapshot, for the round, the wave, the gang and the
+        warm-up. Under a mesh the rr carry, the weights, the nominations
+        and `rows` (the round's stacked batch and staged row ids)
+        replicate, and `wave` (nt, pm, tt, pb, extra, extra_scores of the
+        wave and the gang) shards where the mesh divides it."""
+        import jax.numpy as jnp
+
+        has_ipa = self._has_ipa(pbs)
+        if self._rr is None:
+            # re-seed from the host mirror: a twin-salvaged round nulls
+            # _rr after advancing _host_rr, so device resumption keeps
+            # the logical counter continuous (bit-equal tie-breaks)
+            self._rr = jnp.asarray(self._host_rr, jnp.int32)
+        wv = jnp.asarray(wvec)
+        mesh = self._active_mesh
+        if mesh is not None:
+            from ..parallel.mesh import (mesh_divides, replicate,
+                                         shard_extra, shard_inputs)
+
+            # every input carries the same commitment on every path:
+            # shardings are part of the jit cache key, and the rr carry
+            # may still sit on one device from rounds run before the
+            # cluster grew to divide the mesh
+            self._rr = replicate(mesh, self._rr)
+            wv = replicate(mesh, wv)
+            if nom is not None:
+                nom = replicate(mesh, nom)
+            if rows is not None:
+                rows = replicate(mesh, rows)
+            if wave is not None and mesh_divides(
+                    mesh, wave[0].valid.shape[0], wave[3].req.shape[0]):
+                # re-putting nt/pm/tt to their identical shardings
+                # transfers nothing; this shards the pod batch and masks
+                extra_scores = wave[5]
+                wave = shard_inputs(mesh, *wave[:5]) + (
+                    None if extra_scores is None
+                    else shard_extra(mesh, extra_scores),)
+        return has_ipa, wv, nom, rows, wave
+
     def wave_path(self) -> str:
         """Which formulation the most recently executed program actually
         used: 'pallas' or 'xla' on the device path, 'vector' for the
         numpy host twin (degraded waves), or 'unresolved' before any
         wave or round has run. This reports executions, not intent — the
-        device-resident round path and the per-wave path resolve their
-        formulation independently."""
-        return self._last_path or "unresolved"
+        round, wave and gang programs resolve their formulation
+        independently."""
+        return self.formulation.last_path or "unresolved"
 
     # -- the wave cycle --------------------------------------------------------
 
@@ -1267,15 +1335,10 @@ class Scheduler:
         binds so the store state is settled on return. Returns pods
         placed (assumed + bind dispatched).
 
-        EVERY backlog — one pod or thirty thousand — takes the
-        device-resident pipeline first (see _schedule_pipelined): the
-        per-wave loop pays one dispatch and one device->host fetch per
-        wave, the round one of each for all its waves. The
-        round program buckets its wave count down to the backlog
-        (pipeline_bucket), so a sub-wave backlog runs a 4-iteration
-        program with one fetch. Stragglers and failures fall through to
-        the per-wave loop below, which owns failure attribution,
-        extenders, and mesh sharding."""
+        EVERY backlog takes the device-resident round first (see
+        _schedule_pipelined), bucketed to its wave count
+        (pipeline_bucket). Stragglers, extenders and host plugins fall
+        through to the per-wave loop below."""
         placed = 0
         waves = 0
         allow_pipeline = True
@@ -1448,24 +1511,13 @@ class Scheduler:
             chk.check(self)
 
     def _schedule_pipelined(self) -> int:
-        """Device-resident scheduling round: chain every pending wave on
-        device and fetch results ONCE at the end.
-
-        Why: the per-wave loop reads `chosen` back after every wave, a
-        host round trip per wave during which the device waits for the
-        next dispatch. Staging pending pods'
-        PodMatrix/TermTable rows up front (state/snapshot.py
-        stage_pending) and flipping them on device as waves place
-        (ops/kernel.py schedule_wave_resident) keeps inter-wave
-        visibility — resources via the usage carry, spreading via the
-        live pod matrix, inter-pod (anti)affinity via the live term
-        table — without any host roundtrip. The host then replays the
-        fetched placements through the exact int64 recheck + assume +
-        async bind path, identical to the per-wave flow.
-
-        Pods the device can't encode (multi-topology-key required
-        affinity) and pods that fail placement are handed back to the
-        per-wave path, which owns failure attribution and preemption."""
+        """Device-resident scheduling round over the whole backlog: every
+        wave chains on the device and results are fetched ONCE at the
+        end, instead of a host round trip per wave during which the
+        device waits. Staged PodMatrix/TermTable rows (state/snapshot.py
+        stage_pending) flip on the device as waves place, keeping
+        inter-wave visibility; the host then replays the placements
+        through the exact recheck + assume + bind path."""
         self._housekeep()
         all_pods: List[api.Pod] = []
         while True:
@@ -1476,51 +1528,48 @@ class Scheduler:
         if not all_pods:
             return 0
         with self._mu:
-            if not self._device_admitted():
-                # breaker open (or a wedged dispatch outstanding): the
-                # whole backlog takes the host path — degraded but
-                # never stopped
-                return self._schedule_degraded(all_pods)
-            placed = 0
-            # gangs bypass the device-resident round: their placements
-            # must be all-or-nothing per group, which the round's
-            # staged-commit carry can't express — the joint-assignment
-            # kernel (ops/gang.py) owns them. One annotation lookup per
-            # pod; zero extra work when no gang pods exist.
-            gang_pods = [p for p in all_pods if self.gangs.key(p) is not None]
-            if gang_pods:
-                all_pods = [p for p in all_pods
-                            if self.gangs.key(p) is None]
-                placed += self._schedule_gangs(gang_pods)
-            host_path, pods = self._split_golden(all_pods)
-            # golden-path pods have no ScoreDeco: count them by reason
-            # so the round record shows the shadow observatory's
-            # coverage gap alongside the shadow divergence itself
-            golden = self._golden_reasons(host_path)
-            placed += self._schedule_host_batch(host_path)
-            if not pods:
-                if golden:
-                    tracing.event("golden_gap", **golden)
-                return placed
-            # RE-check admission: a gang dispatch above may have been
-            # watchdog-abandoned (breaker now open, wedge outstanding)
-            # — the round must not dispatch at that runtime
-            if not self._device_admitted():
-                # the golden coverage gap travels with the fallback:
-                # it was counted for pods already scheduled above and
-                # must not vanish because the round went degraded
-                return placed + self._schedule_degraded(pods,
-                                                        golden=golden)
-            return placed + self._run_pipeline(pods, golden=golden)
+            return self._route(all_pods, self._run_pipeline)
+
+    def _route(self, pods: List[api.Pod], run) -> int:
+        """Route a batch the way the round and the wave both do: gangs
+        to the joint-assignment path, pods the device can't encode to
+        the golden path, the rest to run(pods, golden). While the
+        breaker is open (or a wedged dispatch is outstanding) the batch
+        takes the host path instead — degraded but never stopped."""
+        if not self._device_admitted():
+            return self._schedule_degraded(pods)
+        # gangs bypass the round and the wave: their placements must be
+        # all-or-nothing per group, which the staged-commit carry can't
+        # express — the joint-assignment kernel (ops/gang.py) owns them
+        placed, pods = self._gangs_first(pods, self._schedule_one_gang)
+        # pods whose required pod-(anti)affinity spans >1 topology key,
+        # or that nominated pods' affinity terms bear on, take the exact
+        # host path (_split_golden); with no ScoreDeco they are counted
+        # by reason, so the round record shows the shadow observatory's
+        # coverage gap alongside the shadow divergence itself
+        host_path, pods = self._split_golden(pods)
+        golden = self._golden_reasons(host_path)
+        placed += self._schedule_host_batch(host_path)
+        if not pods:
+            if golden:
+                tracing.event("golden_gap", **golden)
+            return placed
+        # RE-check admission: a gang dispatch above may have been
+        # watchdog-abandoned (breaker now open, wedge outstanding), and
+        # nothing may follow it onto that runtime; the golden coverage
+        # gap travels with the fallback
+        if not self._device_admitted():
+            return placed + self._schedule_degraded(pods, golden=golden)
+        return placed + run(pods, golden)
 
     def warm_pipeline(self, pods: List[api.Pod],
                       n_waves: Optional[int] = None) -> None:
         """Compile and run the round program for this cluster's shapes
         on `pods`, committing nothing. n_waves selects the wave-count
         bucket to compile (default: one bucket covering len(pods)/wave).
-        The pods are left unscheduled; staged rows are released."""
-        import jax.numpy as jnp
-
+        The pods are left unscheduled; staged rows are released. A
+        failure other than a Pallas demotion is raised, not salvaged:
+        the warm-up places nothing, so it has nothing to salvage."""
         from ..ops.kernel import schedule_round
 
         with self._mu:
@@ -1536,40 +1585,15 @@ class Scheduler:
                 return
             pm_rows, term_rows = self.snapshot.stage_pending(pods)
             pb = self.featurizer.featurize(pods)
-            P = pb.req.shape[0]
             nt, pm, tt = self._to_device()
             usage = (nt.requested, nt.nonzero, nt.pod_count)
-            if self._use_pallas is None:
-                self._use_pallas = pallas_default()
-            has_ipa = bool(self.snapshot.has_affinity_terms
-                           or pb.ra_has.any() or pb.rn_has.any()
-                           or (pb.pa_w != 0).any())
-            wbucket = pipeline_bucket(
-                n_waves if n_waves is not None else 1,
-                hi=PIPELINE_MAX_WAVES_IPA if has_ipa else PIPELINE_MAX_WAVES)
-            tpp = term_rows.shape[1]
-            pbs_stacked, rows, trows = assemble_round(
-                [pb], [pods], pm_rows, term_rows, wbucket, tpp)
-            rr0 = jnp.asarray(0, jnp.int32)
             gating, wvec, _wver = self._weights_kw()
-            wv = jnp.asarray(wvec)
-            if self._active_mesh is not None:
-                from ..parallel.mesh import replicate
-
-                pbs_stacked = enc.PodBatch(
-                    *replicate(self._active_mesh, tuple(pbs_stacked)))
-                rows = replicate(self._active_mesh, rows)
-                trows = replicate(self._active_mesh, trows)
-                # the rr scalar must carry the same commitment as the
-                # measured rounds' (_run_pipeline replicates self._rr):
-                # shardings are part of the jit cache key, so an
-                # uncommitted rr here would warm a program the first
-                # measured round can never hit — recompiling inside the
-                # window this warm-up exists to protect
-                rr0 = replicate(self._active_mesh, rr0)
-                wv = replicate(self._active_mesh, wv)
-            if self._round_pallas is None:
-                self._round_pallas = pallas_default()
+            wbucket = pipeline_bucket(n_waves if n_waves is not None else 1,
+                                      hi=self._max_waves(pods))
+            has_ipa, wv, _nom, rows, _ = self._device_inputs(
+                [pb], wvec, rows=assemble_round(
+                    [pb], [pods], pm_rows, term_rows, wbucket,
+                    term_rows.shape[1]))
             # compile the SAME collect_scores variant the measured
             # rounds will dispatch: with tracing on they run the
             # decomposition-carrying program, and warming the other one
@@ -1579,41 +1603,22 @@ class Scheduler:
 
             def _warm(use_p: bool):
                 out = schedule_round(
-                    nt, pm, tt, pbs_stacked, usage,
-                    rr0, rows, trows,
+                    nt, pm, tt, rows[0], usage, self._rr, rows[1], rows[2],
                     weights=gating,
                     num_zones=self.snapshot.caps.Z,
                     num_label_values=self.snapshot.num_label_values,
                     has_ipa=has_ipa, use_pallas=use_p,
                     collect_scores=collect, weight_vec=wv)
-                # fetch the placements: the first-pallas-round
-                # self-check below compares them, and an execution
-                # fault surfaces inside the warm-up instead of in the
-                # first real round
+                # fetch the placements: the first Pallas round's
+                # cross-check compares them, and an execution fault
+                # surfaces here instead of in the first real round
                 return np.asarray(out[0])
 
             try:
-                try:
-                    got = _warm(self._round_pallas)
-                    if self._round_pallas and not self._round_pallas_checked:
-                        # on-device cross-check against the XLA
-                        # formulation (compile cost lands in the warm-up
-                        # window, never in a measured run)
-                        want = _warm(False)
-                        if not np.array_equal(got, want):
-                            self._pallas_demoted(
-                                "round", "MISMATCHES the XLA formulation "
-                                "on this backend (warm-up self-check)")
-                            self._round_pallas = False
-                        self._round_pallas_checked = True
-                except Exception:
-                    # a faulting pallas warm must demote the round path
-                    # HERE so the measured run compiles the same (XLA)
-                    # program the warm fell back to
-                    if not self._round_pallas:
-                        raise
-                    self._round_pallas = False
-                    _warm(False)
+                # a Pallas fault or mismatch demotes the round HERE, so
+                # the measured run compiles the same program the warm-up
+                # ran (the cross-check's compile lands in this window)
+                self.formulation.run("round", _warm, same=np.array_equal)
             finally:
                 for p in pods:
                     self.snapshot.unstage(p)
@@ -1621,7 +1626,6 @@ class Scheduler:
     def _run_pipeline(self, pods: List[api.Pod],
                       golden: Optional[Dict[str, int]] = None) -> int:
         import jax
-        import jax.numpy as jnp
 
         from ..ops.kernel import schedule_round
 
@@ -1632,12 +1636,7 @@ class Scheduler:
         # wave_deadline_s shrink it (see _account_host_overrun); they
         # are the same number whenever no deadline is configured
         W = self._wave_cap
-        # ipa anywhere in the backlog (or already placed) caps the round
-        # at the ipa-safe wave count, even for ipa-free leading rounds
-        max_waves = (PIPELINE_MAX_WAVES_IPA
-                     if (self.snapshot.has_affinity_terms
-                         or any(_pod_has_ipa_terms(p) for p in pods))
-                     else PIPELINE_MAX_WAVES)
+        max_waves = self._max_waves(pods)
         waves = [pods[i:i + W] for i in range(0, len(pods), W)]
         if len(waves) > max_waves:
             # bound the round (fixed program size); the leftover goes back
@@ -1653,17 +1652,8 @@ class Scheduler:
         # ONE weight view per round: dispatch, decision recording, and
         # the ledger's weights_version all come from this triple
         gating, wvec, wver = self._weights_kw()
-        rec = tracing.active()
-        rt = None
-        if rec is not None:
-            rt = rec.begin_round("pipeline", pending=len(pods),
-                                 waves=len(waves), weights_version=wver)
-            self._trace_queue_waits(rt, pods)
-            if golden:
-                # golden-path pods scheduled alongside this round have
-                # no ScoreDeco — the shadow observatory's coverage gap,
-                # ledgered per round (carried PR 9 follow-up)
-                rt.ledger["golden"] = golden
+        rec, rt = self._begin_round("pipeline", pods, wver, golden,
+                                    waves=len(waves))
         # pass 1: grow every vocab/cap to its final size so pass 2 emits
         # uniform shapes (one compiled program, not one per growth step).
         # When nothing grew — the steady state once caps are pre-sized —
@@ -1678,16 +1668,15 @@ class Scheduler:
             try:
                 sig0 = (self.featurizer.vocabs.version(),
                         dataclasses.astuple(self.snapshot.caps))
-                pass1 = [self.featurizer.featurize(wv) for wv in waves]
+                pbs = [self.featurizer.featurize(wv) for wv in waves]
                 if (self.featurizer.vocabs.version(),
                         dataclasses.astuple(self.snapshot.caps)) != sig0:
-                    pass1 = [self.featurizer.featurize(wv) for wv in waves]
+                    pbs = [self.featurizer.featurize(wv) for wv in waves]
                 break
             except PodFeaturizeError as e:
                 pods = self._convict_featurize_victim(e, pods)
                 if not pods:
-                    if rt is not None:
-                        rec.end_round(rt, outcome="input_fault")
+                    self._ledger(rt, rec, outcome="input_fault")
                     return 0
                 waves = [pods[i:i + W] for i in range(0, len(pods), W)]
             except Exception as e:
@@ -1697,14 +1686,10 @@ class Scheduler:
                 # boundary: compact and retry rather than crash the
                 # scheduling loop or convict the pod that happened to
                 # be featurizing when memory ran out
-                if not is_capacity_error(e):
-                    raise
-                return self._capacity_fault(pods, e, rt, rec,
-                                            self._run_pipeline)
-        pbs = []
+                return self._salvage("round", pods, self._classify(
+                    pods, e, "featurize"), rt, rec)
         try:
-            for wv, pb_w in zip(waves, pass1):
-                pbs.append(pb_w)
+            for wv, pb_w in zip(waves, pbs):
                 P = pb_w.req.shape[0]
                 extra = self._host_plugin_mask(wv, P)
                 if (not extra.all()
@@ -1733,19 +1718,8 @@ class Scheduler:
             for wv_pods, pb_w in zip(waves, pbs):
                 self._wave_poison_seam(wv_pods, pb_w)
         except Exception as e:
-            verdict = self._input_fault_verdict(pods, e)
-            if rt is not None:
-                rec.end_round(rt, outcome=("input_fault"
-                                           if verdict is not None
-                                           else "device_failure"),
-                              error=type(e).__name__)
-            if verdict is None:
-                # transient (a times-bounded fault drained): requeue for
-                # a clean retry
-                for p in pods:
-                    self.queue.add_if_not_present(p)
-                return 0
-            return self._isolate_poison(pods, verdict, self._run_pipeline)
+            return self._salvage("round", pods,
+                                 self._classify(pods, e, "seam"), rt, rec)
         pm_rows_all, term_rows_all = self.snapshot.stage_pending(pods)
         tpp = term_rows_all.shape[1]
         nw = len(waves)
@@ -1766,51 +1740,19 @@ class Scheduler:
         # degrade the wave size BEFORE they degrade latency
         self._account_host_overrun(self.clock() - start)
         usage = (nt.requested, nt.nonzero, nt.pod_count)
-        if self._rr is None:
-            # re-seed from the host mirror: a twin-salvaged round nulls
-            # _rr after advancing _host_rr, so device resumption keeps
-            # the logical counter continuous (bit-equal tie-breaks)
-            self._rr = jnp.asarray(self._host_rr, jnp.int32)
-        wv = jnp.asarray(wvec)
-        if self._use_pallas is None:
-            self._use_pallas = pallas_default()
-        has_ipa = bool(self.snapshot.has_affinity_terms
-                       or any(pb.ra_has.any() or pb.rn_has.any()
-                              or (pb.pa_w != 0).any() for pb in pbs))
-        pbs_stacked, pm_rows, term_rows = assemble_round(
-            pbs, waves, pm_rows_all, term_rows_all, wbucket, tpp)
+        has_ipa, wv, nom, (pbs_stacked, pm_rows, term_rows), _ = \
+            self._device_inputs(pbs, wvec, nom=nom, rows=assemble_round(
+                pbs, waves, pm_rows_all, term_rows_all, wbucket, tpp))
         trace.annotate(pods=len(pods), waves=nw, bucket=wbucket)
-        if self._active_mesh is not None:
-            # pod batches / staged row ids / the rr carry replicate over
-            # the mesh; the node tensors (and the usage carry derived
-            # from them) are already committed node-sharded, so GSPMD
-            # partitions the whole round along N with no program change
-            from ..parallel.mesh import replicate
-
-            pbs_stacked = enc.PodBatch(
-                *replicate(self._active_mesh, tuple(pbs_stacked)))
-            pm_rows = replicate(self._active_mesh, pm_rows)
-            term_rows = replicate(self._active_mesh, term_rows)
-            self._rr = replicate(self._active_mesh, self._rr)
-            wv = replicate(self._active_mesh, wv)
-            if nom is not None:
-                nom = enc.Nominations(
-                    *replicate(self._active_mesh, tuple(nom)))
-        # the Pallas taint/port kernel is HOISTED out of the round's
-        # lax.scan (ops/kernel.py schedule_round: one call covering all
-        # waves) — under the scan it faults on Mosaic. A pallas round
-        # that still fails falls back to the XLA formulation once and
-        # demotes the round path permanently; wave_path() reports what
-        # actually executed, never a prediction.
-        if self._round_pallas is None:
-            self._round_pallas = pallas_default()
-
         # score decomposition rides along EXACTLY when tracing: the
         # compiled program (and its jit cache bucket) is byte-identical
         # to the pre-observatory kernel otherwise
         collect = rt is not None
 
         def _attempt(use_p: bool):
+            # the Pallas taint/port kernel is HOISTED out of the round's
+            # lax.scan (ops/kernel.py schedule_round: one call covering
+            # all waves) — under the scan it faults on Mosaic
             (chosen_d, fail_d, _usage_end, rr_end, deco_d,
              fin_d) = schedule_round(
                 nt, pm, tt, pbs_stacked, usage, self._rr, pm_rows,
@@ -1844,98 +1786,18 @@ class Scheduler:
                 rt.mark("fetch", cat="device", bytes=int(fetched))
             return chosen, rr_end, deco, fin
 
-        round_pallas = self._round_pallas
-        try:
-            try:
-                chosen_all, rr_end, deco_all, fin_all = \
-                    _attempt(round_pallas)
-                if round_pallas and not self._round_pallas_checked:
-                    # unwarmed process: first-round on-device cross-check
-                    # (see warm_pipeline; one-time compile+exec cost)
-                    want, want_rr, want_deco, want_fin = _attempt(False)
-                    if not np.array_equal(chosen_all, want):
-                        self._pallas_demoted(
-                            "round", "MISMATCHES the XLA formulation on "
-                            "this backend")
-                        self._round_pallas = round_pallas = False
-                        chosen_all, rr_end, deco_all, fin_all = (
-                            want, want_rr, want_deco, want_fin)
-                    self._round_pallas_checked = True
-            except Exception as e:
-                if isinstance(e, DispatchTimeout):
-                    raise  # wedged runtime, not a pallas failure: no retry
-                if not round_pallas:
-                    raise
-                self._pallas_demoted("round", f"{type(e).__name__}: {e}",
-                                     exc=e)
-                self._round_pallas = round_pallas = False
-                chosen_all, rr_end, deco_all, fin_all = _attempt(False)
-            self._last_path = "pallas" if round_pallas else "xla"
-        except Exception as e:
-            # capacity-fault attribution FIRST: a device OOM replays
-            # clean on the host twin, so the input-fault verdict would
-            # misclassify it as a device fault — and the scheduler's
-            # own footprint must never convict a device, reform the
-            # mesh, or convict a pod (sched/breaker.py
-            # is_capacity_error walks the cause chain)
-            if is_capacity_error(e):
-                for p in pods:
-                    self.snapshot.unstage(p)
-                return self._capacity_fault(pods, e, rt, rec,
-                                            self._run_pipeline)
-            # input-fault attribution BEFORE breaker/reform accounting:
-            # bad work must never blame (or reform) the runtime
-            verdict = self._input_fault_verdict(pods, e)
-            if verdict is not None:
-                for p in pods:
-                    self.snapshot.unstage(p)
-                if rt is not None:
-                    rec.end_round(rt, outcome="input_fault",
-                                  error=type(e).__name__)
-                return self._isolate_poison(pods, verdict,
-                                            self._run_pipeline)
-            # round failed on every formulation: breaker accounting,
-            # then hand the backlog back — schedule_pending's per-wave
-            # iteration (or, once tripped, the degraded host path)
-            # carries on
-            reformed = self._device_failure(e)
+        out, verdict = self._dispatch(
+            "round", pods, _attempt,
+            finite=lambda o: [o[3][wi, i] for wi, wv_pods in
+                              enumerate(waves) for i in range(len(wv_pods))],
+            same=lambda a, b: np.array_equal(a[0], b[0]))
+        if verdict is not None:
             for p in pods:
                 self.snapshot.unstage(p)
-            if rt is not None:
-                rec.end_round(rt, outcome="device_failure",
-                              error=type(e).__name__,
-                              mesh=self._mesh_ledger())
-            if reformed or isinstance(e, DispatchTimeout):
-                # partial-round salvage: the dispatch is wedged or a
-                # mesh device was lost, not a wrong program — the mesh
-                # reformed (or the breaker opened via record_hang) and
-                # the SAME round's pods place NOW through the hostwave
-                # twin instead of re-queueing behind a per-wave retry;
-                # the NEXT round dispatches on the reformed mesh.
-                # golden is NOT re-passed: this round's (failed) record
-                # already ledgered it at begin_round.
-                return self._schedule_degraded(pods)
-            for p in pods:
-                self.queue.add_if_not_present(p)
-            return 0
-        self.breaker.record_success()
-        self._capacity_strikes = 0
-        # numeric-integrity sentinel, fetched with the round's chosen
-        # planes: any non-finite row means a poison pod contaminated the
-        # scan's shared usage carry — DISCARD the whole round (a NaN
-        # carry silently shifts innocent pods' placements), convict the
-        # flagged pods, and re-run the survivors, whose placements are
-        # then bit-equal a clean run's. rr deliberately not advanced.
-        bad = [wv_pods[i].uid for wi, wv_pods in enumerate(waves)
-               for i in range(len(wv_pods)) if not fin_all[wi, i]]
-        if bad:
-            for p in pods:
-                self.snapshot.unstage(p)
-            if rt is not None:
-                rec.end_round(rt, outcome="input_fault", poison=len(bad))
-            return self._isolate_poison(
-                pods, PoisonError("numeric-integrity sentinel", uids=bad),
-                self._run_pipeline)
+            # golden is NOT re-passed to a salvage: this round's (failed)
+            # record already ledgered it at begin_round
+            return self._salvage("round", pods, verdict, rt, rec)
+        chosen_all, rr_end, deco_all, _fin = out
         # exact shadow sampling runs BEFORE any commit mutates the
         # snapshot: the twin must replay the identical pre-round state
         # the device program scored
@@ -2013,7 +1875,7 @@ class Scheduler:
             rec.end_round(
                 rt, outcome="ok", placed=placed, retried=len(retry),
                 preempted=len(handled), scores=scores, shadow=shadow,
-                path=self._last_path or "unresolved",
+                path=self.formulation.last_path or "unresolved",
                 snapshot=self._round_snapshot_shape(),
                 breaker=self.breaker.state, mesh=self._mesh_ledger())
         trace.log_if_long(0.5)
@@ -2035,8 +1897,8 @@ class Scheduler:
             return set()
         if not self.device_preemption:
             # device what-ifs disabled: the numpy twin carries the same
-            # batched pipeline (this used to bail to the 0.8 pods/s
-            # per-pod host cascade — the BENCH_r05 cliff)
+            # batched pipeline, instead of a per-pod host what-if for
+            # every failed pod on every node
             host = True
         cands = [p for p in pods
                  if pod_eligible_to_preempt_others(p, self.cache)]
@@ -2271,17 +2133,6 @@ class Scheduler:
         self.metrics.preemption_evaluation.observe(self.clock() - t0)
         return handled
 
-    def _needs_golden(self, pod: api.Pod) -> bool:
-        """Must this pod take the exact golden path instead of the
-        vectorized numpy host wave? Only for the one encoding the twin
-        (like the device kernel) does not carry: multi-topology-key
-        required affinity (needs_host_path). The inter-pod affinity
-        plane itself is twinned (ops/hostwave.py incoming_statics_host,
-        bitwise parity with ops/affinity.py), so degraded and
-        reform-salvage rounds keep batched throughput for affinity pods
-        — the routing is now identical to the device path's."""
-        return self.featurizer.needs_host_path(pod)
-
     def _count_degraded_golden(self, pods: List[api.Pod], rt=None) -> None:
         """Degraded-mode visibility: pods the hostwave twin can't encode
         drain through the exact per-pod golden path at a fraction of the
@@ -2300,47 +2151,34 @@ class Scheduler:
 
     def _schedule_degraded(self, pods: List[api.Pod],
                            golden: Optional[Dict[str, int]] = None) -> int:
-        """Breaker-open degraded mode: the backlog drains through the
-        vectorized numpy host twin (ops/hostwave.py) — one batched
-        mask+score wave per wave_size chunk, batched host-twin
-        preemption for its failures, and all-or-nothing gang placement
-        through the twin's count-feasibility plane. Pods the twin can't
-        encode (inter-pod affinity, multi-topology keys) take the exact
-        per-pod golden path, as they do on the device path. Degraded
-        mode is merely slower than the device path, not three orders of
-        magnitude slower."""
+        """Degraded mode: the backlog drains through the vectorized numpy
+        host twin (ops/hostwave.py) — one batched wave per wave_size
+        chunk, batched twin preemption for its failures, all-or-nothing
+        gangs through the twin's count-feasibility plane, and the exact
+        golden path for what the twin can't encode."""
         # ONE weight view per round (see _run_pipeline); every twin
         # chunk below dispatches under it
         gating, wvec, wver = self._weights_kw()
-        rec = tracing.active()
-        rt = None
-        if rec is not None:
-            rt = rec.begin_round("degraded", pending=len(pods),
-                                 weights_version=wver)
-            self._trace_queue_waits(rt, pods)
-            if golden:
-                # coverage gap counted by the caller BEFORE it fell back
-                # here (golden-path pods it already scheduled) — must
-                # not vanish just because the round went degraded
-                g = rt.ledger.setdefault("golden", {})
-                for r, n in golden.items():
-                    g[r] = g.get(r, 0) + n
-        placed = 0
+        # the coverage gap counted by the caller BEFORE it fell back here
+        # (golden-path pods it already scheduled) must not vanish just
+        # because the round went degraded
+        rec, rt = self._begin_round("degraded", pods, wver, golden)
         # gangs stay atomic in degraded mode: the twin's count
         # feasibility IS the joint-assignment proof (host twin). Gangs
         # with golden-only members still place individually — atomicity
         # is not offered for that combination on either backend.
-        gang_pods = [p for p in pods if self.gangs.key(p) is not None]
-        if gang_pods:
-            pods = [p for p in pods if self.gangs.key(p) is None]
-            groups: Dict[str, List[api.Pod]] = {}
-            for p in gang_pods:
-                groups.setdefault(self.gangs.key(p), []).append(p)
-            for key, members in groups.items():
-                placed += self._schedule_degraded_gang(key, members, rt)
-        golden_pods = [p for p in pods if self._needs_golden(p)]
+        placed, pods = self._gangs_first(
+            pods, lambda key, members: self._schedule_degraded_gang(
+                key, members, rt))
+        # the twin, like the device kernel, does not carry multi-
+        # topology-key required affinity (needs_host_path); its
+        # inter-pod affinity plane is twinned (ops/hostwave.py
+        # incoming_statics_host), so affinity pods keep batched
+        # throughput here, routed as on the device path
+        needs_golden = self.featurizer.needs_host_path
+        golden_pods = [p for p in pods if needs_golden(p)]
         if golden_pods:
-            pods = [p for p in pods if not self._needs_golden(p)]
+            pods = [p for p in pods if not needs_golden(p)]
             self._count_degraded_golden(golden_pods, rt)
             placed += self._schedule_host_batch(golden_pods)
         # chunk at wave_size: featurize buckets caps.P by batch length,
@@ -2378,18 +2216,13 @@ class Scheduler:
                    deco_acc: Optional[List] = None,
                    committed: Optional[set] = None,
                    weights_view=None) -> int:
-        """One batched host-twin wave: numpy masks+scores+greedy commit
-        over the snapshot's host planes (no device touch — a wedged
-        runtime must not be dispatched to), then the same exact int64
-        recheck -> assume -> bind commit as the device path. Failures go
-        through ONE batched host-twin preemption pass (claimed-capacity
-        accounting included), then park with exact FitError attribution
-        from the twin's mask stack.
-
-        deco_acc: when tracing, the twin collects the same per-priority
-        score decomposition as the device kernel; (pods, chosen, deco)
-        is appended here for the degraded round's single decision-
-        recording pass."""
+        """One batched host-twin wave over the snapshot's host planes (no
+        device touch: a wedged runtime must not be dispatched to), then
+        the device path's exact recheck -> assume -> bind commit.
+        Failures take ONE batched host-twin preemption pass, then park
+        with FitError attribution from the twin's mask stack. deco_acc
+        collects (pods, chosen, deco) for the degraded round's one
+        decision-recording pass."""
         from ..ops import hostwave
 
         if not pods:
@@ -2406,14 +2239,10 @@ class Scheduler:
         if not pods:
             return 0  # the whole chunk was convicted at featurize time
         P = pb.req.shape[0]
-        try:
-            extra = self._host_plugin_mask(pods, P)
-            extra_scores = self._host_score_matrix(pods, P)
-        except ExtenderError:
-            self.metrics.scheduling_errors.labels(stage="extender").inc()
-            for p in pods:
-                self._park_with_backoff(p)
+        planes = self._host_planes(pods, P)
+        if planes is None:
             return 0
+        extra, extra_scores = planes
         trace.step("featurized")
         if rt is not None:
             rt.mark("featurize", pods=len(pods))
@@ -2424,8 +2253,7 @@ class Scheduler:
                         else self._weights_kw()[:2])
         # the same has_ipa resolution as the device path: the twin
         # carries the full inter-pod affinity plane
-        has_ipa = bool(self.snapshot.has_affinity_terms or pb.ra_has.any()
-                       or pb.rn_has.any() or (pb.pa_w != 0).any())
+        has_ipa = self._has_ipa([pb])
         try:
             self._wave_poison_seam(pods, pb)
             res, _usage = hostwave.schedule_wave_host(
@@ -2459,12 +2287,9 @@ class Scheduler:
             return self._isolate_poison(pods, verdict, runner)
         # numeric-integrity sentinel: discard the chunk, convict the
         # flagged pods, re-run the survivors (host rr not advanced)
-        fin = np.asarray(res.finite)
-        bad = [pods[i].uid for i in range(len(pods)) if not fin[i]]
-        if bad:
-            return self._isolate_poison(
-                pods, PoisonError("numeric-integrity sentinel", uids=bad),
-                runner)
+        verdict = self._sentinel(pods, np.asarray(res.finite))
+        if verdict is not None:
+            return self._isolate_poison(pods, verdict.exc, runner)
         if deco_acc is not None and res.deco is not None:
             # slice off featurize's P-bucket pad rows: the degraded round
             # concatenates chunks, so a padded chunk would shift every
@@ -2474,7 +2299,7 @@ class Scheduler:
                              tuple(np.asarray(a)[:n] for a in res.deco)))
         self._host_rr = int(res.rr_end)
         self._rr = None  # device resumption re-seeds from the mirror
-        self._last_path = "vector"
+        self.formulation.last_path = "vector"
         trace.step("host wave")
         if rt is not None:
             rt.mark("host_wave", cat="host", backend="vector",
@@ -2516,115 +2341,116 @@ class Scheduler:
         trace.log_if_long(0.5)
         return placed
 
-    def _schedule_degraded_gang(self, key: str, members: List[api.Pod],
-                                rt=None) -> int:
-        """Degraded-mode gang placement through the host twin's
-        all-or-nothing count-feasibility plane (ops/hostwave.py
-        schedule_gang_host): either minMember members hold capacity
-        simultaneously or nothing commits — the atomicity PR 2 suspended
-        in degraded mode, restored. Gangs with golden-only members fall
-        back to individual placement (atomicity not offered, as on the
-        device path for multi-topology members)."""
-        from ..ops import hostwave
+    # -- the dispatch path (sched/dispatch.py) ---------------------------------
 
-        self.metrics.gang_schedule_attempts.inc()
-        for _p in members:
-            self.metrics.schedule_attempts.inc()
-        if any(self._needs_golden(p) for p in members):
-            self._count_degraded_golden(
-                [p for p in members if self._needs_golden(p)], rt)
-            return self._schedule_host_batch(members)
-        min_member = self.gangs.min_member(members[0])
-        bound = self.gangs.bound_count(self.cache, key,
-                                       exclude={p.uid for p in members})
-        need = max(min_member - bound, 0)
+    def _dispatch(self, program: str, pods: List[api.Pod], attempt,
+                  finite, same=None):
+        """Run one device program of the round, the wave or the gang:
+        attempt(use_pallas) dispatches it, finite(result) is its
+        numeric-integrity plane, one flag per pod. Returns (result,
+        None), (None, verdict) on a failure, or (result, verdict) when
+        the sentinel flagged pods (see _sentinel)."""
         try:
-            pb = self.featurizer.featurize(members)
-        except PodFeaturizeError as e:
-            # gang-atomic conviction, exactly like the device path
-            self._gang_input_fault(members, e, rt)
-            return 0
-        P = pb.req.shape[0]
-        try:
-            extra = self._host_plugin_mask(members, P)
-            extra_scores = self._host_score_matrix(members, P)
-        except ExtenderError:
-            self.metrics.scheduling_errors.labels(stage="extender").inc()
-            for p in members:
-                self._park_with_backoff(p)
-            return 0
-        nt, pm, tt = self.snapshot.host_tensors()
-        gating, wvec, _wver = self._weights_kw()
-        has_ipa = bool(self.snapshot.has_affinity_terms or pb.ra_has.any()
-                       or pb.rn_has.any() or (pb.pa_w != 0).any())
-        try:
-            self._wave_poison_seam(members, pb)
-            res = hostwave.schedule_gang_host(
-                nt, pm, tt, pb, extra, self._host_rr, extra_scores, need,
-                weights=gating,
-                num_zones=self.snapshot.caps.Z,
-                num_label_values=self.snapshot.num_label_values,
-                has_ipa=has_ipa,
-                weight_vec=wvec)
+            out, path = self.formulation.run(program, attempt, same)
         except Exception as e:
-            # a host-path crash follows the data: the gang convicts whole
-            verdict = (e if isinstance(e, (PoisonError, PodFeaturizeError))
-                       else PoisonError(f"host twin gang pass failed: "
-                                        f"{type(e).__name__}: {e}"))
-            self._gang_input_fault(members, verdict, rt)
+            return None, self._classify(pods, e)
+        self.breaker.record_success()
+        self._capacity_strikes = 0
+        self.formulation.last_path = path
+        return out, self._sentinel(pods, finite(out))
+
+    @staticmethod
+    def _sentinel(pods: List[api.Pod], finite) -> Optional[Verdict]:
+        """The numeric-integrity verdict, None when every pod is finite.
+        A non-finite row means a poison pod contaminated the scan's
+        shared carries: the whole result is discarded (a NaN carry
+        silently shifts innocent pods' placements) and rr is not
+        advanced; the flagged pods convict and the survivors re-run,
+        placing bit-equal a clean run."""
+        bad = [p.uid for p, ok in zip(pods, finite) if not ok]
+        if not bad:
+            return None
+        return Verdict(INPUT, PoisonError("numeric-integrity sentinel",
+                                          uids=bad), {"poison": len(bad)})
+
+    def _classify(self, pods: List[api.Pod], exc: BaseException,
+                  stage: str = "device") -> Verdict:
+        """The one ordered classifier of a failure at a site: capacity,
+        input fault, then the device (sched/dispatch.py). `stage`
+        "featurize" lets any other error out (a featurizer bug is
+        neither the work's nor the device's); "seam", before any
+        dispatch, charges the device nothing when the replay is clean."""
+        # capacity first: an OOM replays clean on the twin, so the input
+        # verdict would blame the device, and the scheduler's own
+        # footprint must never convict a device, reform the mesh or
+        # convict a pod; then the input replay, as bad work must never
+        # blame or reform the runtime
+        ledger = {"error": type(exc).__name__}
+        if is_capacity_error(exc):
+            return Verdict(CAPACITY, exc, ledger)
+        if stage == "featurize" and not isinstance(exc, PodFeaturizeError):
+            raise exc
+        verdict = self._input_fault_verdict(pods, exc)
+        if verdict is not None:
+            return Verdict(INPUT, verdict, ledger)
+        if stage == "seam":
+            return Verdict(TRANSIENT, exc, ledger)
+        if self._device_failure(exc):
+            return Verdict(REFORMED, exc, ledger)
+        return Verdict(HUNG if isinstance(exc, DispatchTimeout) else FAILED,
+                       exc, ledger)
+
+    def _salvage(self, site: str, pods: List[api.Pod], verdict: Verdict,
+                 rt=None, rec=None, degrade=None) -> int:
+        """The one salvage ladder: what happens to a site's pods under a
+        verdict, by the site's row of SALVAGE. The round and the wave
+        retry and degrade through themselves and _schedule_degraded;
+        the gang passes `degrade` (its all-or-nothing twin) and ledgers
+        into the round its caller ends. Returns pods placed."""
+        if site == "gang" and verdict.kind == INPUT:
+            self._gang_input_fault(pods, verdict.exc, rt)
             return 0
-        self._last_path = "vector"
-        if rt is not None:
-            rt.mark("host_wave", cat="host", backend="vector", gang=key,
-                    pods=len(members))
-        fin = np.asarray(res.finite)
-        bad = [members[i].uid for i in range(len(members)) if not fin[i]]
-        if bad:
-            # sentinel verdict: the twin discarded nothing on its own
-            # (count feasibility may even have passed) — the gang
-            # convicts atomically before any commit
-            self._gang_input_fault(
-                members,
-                PoisonError("numeric-integrity sentinel", uids=bad), rt)
-            return 0
-        if not bool(res.ok):
-            self._fail_gang(key, members, need, res)
-            return 0
-        self._host_rr = int(res.rr_end)
-        self._rr = None  # device resumption re-seeds from the mirror
-        pairs: List = []
-        leftover: List = []
-        for i, pod in enumerate(members):
-            n = int(res.chosen[i])
-            if n >= 0:
-                pairs.append((pod, self.snapshot.node_names[n]))
+        retry = self._run_pipeline if site == "round" else self._run_wave
+        degrade = degrade or self._schedule_degraded
+        if verdict.kind == INPUT:
+            self._ledger(rt, rec, outcome="input_fault", **verdict.ledger)
+            return self._isolate_poison(pods, verdict.exc, retry)
+        if verdict.kind == CAPACITY:
+            return self._capacity_fault(site, pods, verdict.exc, rt, rec,
+                                        retry, degrade)
+        self._ledger(rt, rec, outcome="device_failure", **verdict.ledger,
+                     mesh=(None if verdict.kind == TRANSIENT
+                           else self._mesh_ledger()))
+        then = SALVAGE[site][verdict.kind]
+        if then == DEGRADE:
+            return degrade(pods)
+        for p in pods:
+            if then == PARK:
+                self._park_with_backoff(p)
             else:
-                leftover.append((i, pod))
-        if not self._commit_gang(pairs):
-            for pod in members:
-                self.queue.add_if_not_present(pod)
-            return 0
-        self.backoff.clear("gang:" + key)
-        self.metrics.waves_total.labels(path="host").inc()
-        if leftover:
-            for i, pod in leftover:
-                self._handle_failure(pod, i, res.fail_counts, res)
-        return len(pairs)
+                self.queue.add_if_not_present(p)
+        return 0
+
+    @staticmethod
+    def _ledger(rt, rec, **fields) -> None:
+        """Ledger a site's failure: the round and the wave end their
+        round here, the gang (rec None) writes the fields into the round
+        _schedule_one_gang ends."""
+        if rt is None:
+            return
+        if rec is not None:
+            rec.end_round(rt, **fields)
+        else:
+            rt.ledger.update({k: v for k, v in fields.items()
+                              if v is not None})
 
     def _device_failure(self, exc: BaseException) -> bool:
-        """Account one device-path failure. With a multi-device mesh the
-        failure first walks the degradation LADDER (_maybe_reform):
-        quarantine the culprit device and reform a smaller mesh — the
-        caller then salvages the in-flight round through the hostwave
-        twin and the NEXT round dispatches on the reformed mesh, with
-        the whole-path breaker untouched (losing 1 of 8 chips must cost
-        1/8 of device throughput, not 8/8). Only when no reform is
-        possible (mesh exhausted / below --mesh-min-devices / no mesh)
-        does the failure feed the classic breaker: a watchdog
-        abandonment (DispatchTimeout) trips it IMMEDIATELY — a wedged
-        runtime won't heal by retrying, and each retry would burn a
-        full wave_deadline_s. Returns True when the mesh reformed (the
-        caller must salvage this round through the twin)."""
+        """Account one device failure: with a multi-device mesh, first
+        one step down the reform ladder (_maybe_reform), leaving the
+        breaker untouched — losing 1 of 8 chips must cost 1/8 of device
+        throughput, not 8/8. Otherwise the breaker counts it, and a
+        watchdog abandonment trips it at once: a wedged runtime won't
+        heal by retrying. True when the mesh reformed."""
         self.metrics.scheduling_errors.labels(stage="wave").inc()
         reformed = self._maybe_reform(exc)
         if not reformed:
@@ -2639,21 +2465,15 @@ class Scheduler:
             type(exc).__name__, exc, exc_info=exc)
         return reformed
 
-    def _capacity_fault(self, pods: List[api.Pod], exc: BaseException,
-                        rt, rec, retry_fn) -> int:
-        """Capacity-fault recovery ladder (RESOURCE_EXHAUSTED /
-        MemoryError at the device boundary). A capacity fault is the
-        scheduler's OWN footprint outgrowing the device — never the
-        device's fault and never the work's, so it must not convict a
-        device, reform the mesh, or convict a pod. Strike 1 compacts
-        the snapshot (vocab mark-and-sweep + bucket shrink,
-        state/scrubber.py) and retries; strike 2 additionally halves
-        the adaptive wave cap (floor MIN_ADAPTIVE_WAVE); strike 3
-        salvages the round through the hostwave twin, which needs no
-        device memory at all. The breaker sees a failure ONLY when
-        compaction itself cannot restore headroom (budget configured
-        and still exceeded after the sweep). Strikes reset on the next
-        successful device round."""
+    def _capacity_fault(self, site: str, pods: List[api.Pod],
+                        exc: BaseException, rt, rec, retry, degrade) -> int:
+        """The capacity strike ladder: strike 1 compacts the snapshot
+        (vocab mark-and-sweep + bucket shrink, state/scrubber.py) and
+        retries, strike 2 also halves the adaptive wave cap, strike 3
+        degrades through the twin, which needs no device memory; a site
+        whose SALVAGE row says DEGRADE does so after compacting. The
+        breaker counts it only when compaction cannot restore headroom.
+        Strikes reset on the next device success."""
         self._capacity_strikes += 1
         strike = self._capacity_strikes
         self.metrics.capacity_faults.inc()
@@ -2676,16 +2496,15 @@ class Scheduler:
             # fault feed the breaker — threshold trips route waves
             # through the host twin until a half-open probe clears
             self.breaker.record_failure()
-        if rt is not None:
-            rec.end_round(rt, outcome="capacity_fault",
-                          error=type(exc).__name__,
-                          memory=self._memory_ledger())
-        if strike >= 3 or summary is None or exhausted:
+        self._ledger(rt, rec, outcome="capacity_fault",
+                     error=type(exc).__name__, memory=self._memory_ledger())
+        if (SALVAGE[site][CAPACITY] == DEGRADE or strike >= 3
+                or summary is None or exhausted):
             # third strike, compaction deferred (staged rows held by a
             # concurrent round), or budget still exceeded: salvage the
             # round host-side instead of burning another dispatch
-            return self._schedule_degraded(pods)
-        return retry_fn(pods)
+            return degrade(pods)
+        return retry(pods)
 
     def _compact_guarded(self, trigger: str):
         """scrubber.compact hardened for the scheduling loop: a crash
@@ -2718,17 +2537,12 @@ class Scheduler:
         }
 
     def _maybe_reform(self, exc: BaseException) -> bool:
-        """One ladder step down: attribute the failure to a device (the
-        exception names one — sched/breaker.py DeviceLost or an XLA
-        error embedding the device id — else quarantine-and-probe
-        bisection), quarantine, and rebuild a smaller valid mesh from
-        the survivors. Runs under _mu (callers hold it around the
-        device step), so the swap is atomic w.r.t. the next upload.
-        False when there is nothing to reform — no mesh, single-device
-        mesh, the reform floor (--mesh-min-devices) reached, or the
-        `mesh.reform` fault point failed the reform — in which case the
-        caller falls through to the whole-path breaker."""
-        from ..ops import kernel as _kernel
+        """One ladder step down, under _mu: attribute the failure to a
+        device (named by the exception, else bisection), quarantine it
+        and rebuild a smaller mesh from the survivors. False when there
+        is nothing to reform (no multi-device mesh, the
+        --mesh-min-devices floor, or a failed reform): the breaker owns
+        the failure then."""
         from ..parallel.mesh import reform_mesh
 
         mf = self.meshfaults
@@ -2765,17 +2579,16 @@ class Scheduler:
             # heal them) but the failure feeds the classic breaker
             return False
         self._swap_mesh(new_mesh, direction="down")
-        _kernel.set_devices([str(d) for d in new_mesh.devices.flat])
         return True
 
     def _swap_mesh(self, new_mesh, direction: str) -> None:
         """Install a reformed mesh (under _mu): the next _to_device
-        re-resolves against it, finds a NEW mesh object in the snapshot
-        cache key, and re-commits every node-tensor group to the new
-        "nodes"-axis sharding (full re-upload; delta row tracking
-        resets with the cache — state/snapshot.py to_device). No
-        dispatch happens between the swap and that re-commit: the
-        in-flight round is salvaged host-side."""
+        re-commits every node-tensor group to its sharding (a full
+        re-upload); the in-flight round is salvaged host-side, so no
+        dispatch happens in between."""
+        from ..ops import kernel as _kernel
+
+        _kernel.set_devices([str(d) for d in new_mesh.devices.flat])
         self.mesh = new_mesh
         self._active_mesh = None
         ndev = int(new_mesh.devices.size)
@@ -2829,7 +2642,6 @@ class Scheduler:
         """Probe quarantined devices whose cooldown elapsed; re-admit
         the healed and reform UPWARD (4 -> 8) so a recovered chip
         rejoins the serving mesh. Called from housekeeping."""
-        from ..ops import kernel as _kernel
         from ..parallel.mesh import reform_mesh
 
         mf = self.meshfaults
@@ -2858,8 +2670,6 @@ class Scheduler:
             new_mesh = reform_mesh(mf.healthy(), min_devices=1)
             if new_mesh is not None and int(new_mesh.devices.size) > cur:
                 self._swap_mesh(new_mesh, direction="up")
-                _kernel.set_devices(
-                    [str(d) for d in new_mesh.devices.flat])
 
     # -- poison-work isolation (input-fault attribution) -----------------------
     #
@@ -2925,16 +2735,13 @@ class Scheduler:
 
     def _input_fault_verdict(self, pods: List[api.Pod],
                              exc: BaseException):
-        """Fault ATTRIBUTION, run before any breaker/reform accounting:
-        replay the failed batch through the numpy twin over the host
-        planes (commits discarded, rr untouched). The twin failing too
-        — or its numeric-integrity sentinel flagging non-finite planes
-        — convicts the WORK, because a runtime fault cannot follow the
-        data onto the host: returns the verdict exception (uids when
-        attribution is direct, empty for the bisection path). A clean
-        replay returns None: genuine device fault, the mesh ladder and
-        the whole-path breaker own it. DispatchTimeout skips the replay
-        outright — a wedge is a runtime property, never the work's."""
+        """Fault attribution: replay the failed batch through the numpy
+        twin over the host planes (commits discarded, rr untouched). A
+        runtime fault cannot follow the data onto the host, so a replay
+        that fails too, or that its sentinel flags, convicts the WORK:
+        returns the verdict exception (with uids when attribution is
+        direct). None when the replay is clean, or for a wedge, which
+        is the runtime's alone."""
         if isinstance(exc, DispatchTimeout):
             return None
         if isinstance(exc, (PoisonError, PodFeaturizeError)):
@@ -2957,20 +2764,15 @@ class Scheduler:
                 self._wave_poison_seam(chunk, pb)
                 nt, pm, tt = self.snapshot.host_tensors()
                 extra = np.ones((pb.req.shape[0], nt.valid.shape[0]), bool)
-                has_ipa = bool(self.snapshot.has_affinity_terms
-                               or pb.ra_has.any() or pb.rn_has.any()
-                               or (pb.pa_w != 0).any())
+                has_ipa = self._has_ipa([pb])
                 res, _usage = hostwave.schedule_wave_host(
                     nt, pm, tt, pb, extra, self._host_rr, None,
                     weights=gating, num_zones=self.snapshot.caps.Z,
                     num_label_values=self.snapshot.num_label_values,
                     has_ipa=has_ipa, weight_vec=wvec)
-                fin = np.asarray(res.finite)
-                bad = [p.uid for j, p in enumerate(chunk) if not fin[j]]
-                if bad:
-                    return PoisonError(
-                        "numeric-integrity sentinel flagged the twin "
-                        "replay", uids=bad)
+                flagged = self._sentinel(chunk, np.asarray(res.finite))
+                if flagged is not None:
+                    return flagged.exc
         except PodFeaturizeError as fe:
             return fe
         except Exception as replay_exc:
@@ -2987,15 +2789,11 @@ class Scheduler:
 
     def _isolate_poison(self, pods: List[api.Pod], verdict,
                         runner: Callable[[List[api.Pod]], int]) -> int:
-        """Input-fault isolation. Direct conviction when the verdict
-        names UIDs (typed featurizer error / sentinel planes) — the
-        survivors requeue and place bit-equal a clean run on the next
-        round. Otherwise WAVE BISECTION along the pod axis, mirroring
-        PR 14's device bisection: split in half preserving order and
-        re-run each half through `runner` — the clean half places
-        normally (order and the snapshot-carried usage/rr flows make it
-        bit-equal a clean run), the poisoned half fails again and
-        recurses, converging on the culprit in log2(wave) rounds.
+        """Input-fault isolation: direct conviction when the verdict
+        names UIDs, the survivors requeued. Otherwise bisection along
+        the pod axis: each half re-runs through `runner` in order, so
+        the clean half places bit-equal a clean run and the poisoned
+        half recurses, converging on the culprit in log2(wave) rounds.
         Returns pods placed by the retries."""
         self.metrics.scheduling_errors.labels(stage="poison").inc()
         victims, reason = self._verdict_attribution(verdict, pods)
@@ -3102,94 +2900,34 @@ class Scheduler:
                           if isinstance(verdict, PodFeaturizeError)
                           else "sentinel")
 
-    def _pallas_demoted(self, program: str, why: str,
-                        exc: Optional[BaseException] = None) -> None:
-        """Pallas-path demotion visibility (the PR 2 _bind_done
-        convention): what used to be a bare stderr print becomes
-        scheduling_errors_total{stage=pallas} + a logged traceback + a
-        flight-recorder event, so dashboards and traces can see the
-        fast path silently falling back to XLA."""
-        self.metrics.scheduling_errors.labels(stage="pallas").inc()
-        logging.getLogger(__name__).error(
-            "pallas %s demoted to the XLA formulation: %s", program, why,
-            exc_info=exc)
-        tracing.event("pallas_demoted", program=program, why=why,
-                      error=type(exc).__name__ if exc is not None else "")
-
     def _run_wave(self, pods: List[api.Pod]) -> int:
-        import jax
-        import jax.numpy as jnp
+        return self._route(pods, self._wave)
 
-        if not self._device_admitted():
-            return self._schedule_degraded(pods)
-        # gang members place through the all-or-nothing joint-assignment
-        # path; pop_wave delivers gangs whole, so this partition never
-        # sees a fragment of a released gang
-        placed_gang = 0
-        gang_pods = [p for p in pods if self.gangs.key(p) is not None]
-        if gang_pods:
-            pods = [p for p in pods if self.gangs.key(p) is None]
-            placed_gang = self._schedule_gangs(gang_pods)
-            if not pods:
-                return placed_gang
-            if not self._device_admitted():
-                # a gang dispatch was just watchdog-abandoned: the
-                # wave must not follow it onto the wedged runtime
-                return placed_gang + self._schedule_degraded(pods)
-        # pods whose required pod-(anti)affinity spans >1 topology key, or
-        # that nominated pods' affinity terms bear on, take the exact host
-        # path (_split_golden)
-        host_path, pods = self._split_golden(pods)
-        placed_host = placed_gang
-        golden = self._golden_reasons(host_path)
-        if host_path:
-            placed_host += self._schedule_host_batch(host_path)
-            if not pods:
-                if golden:
-                    tracing.event("golden_gap", **golden)
-                return placed_host
+    def _wave(self, pods: List[api.Pod],
+              golden: Optional[Dict[str, int]] = None) -> int:
         trace = Trace(f"wave of {len(pods)}", clock=self.clock,
                       steps=WAVE_STEPS)
         start = self.clock()
         # ONE weight view per round (see _run_pipeline)
         gating, wvec, wver = self._weights_kw()
-        rec = tracing.active()
-        rt = None
-        if rec is not None:
-            rt = rec.begin_round("wave", pending=len(pods),
-                                 weights_version=wver)
-            self._trace_queue_waits(rt, pods)
-            if golden:
-                rt.ledger["golden"] = golden
+        rec, rt = self._begin_round("wave", pods, wver, golden)
         try:
             pb, pods = self._featurize_guarded(pods)
         except Exception as e:
             # allocation-site MemoryError routed into the capacity
             # verdict (see _run_pipeline's featurize loop) instead of
             # propagating raw out of the scheduling loop
-            if not is_capacity_error(e):
-                raise
-            return placed_host + self._capacity_fault(pods, e, rt, rec,
-                                                      self._run_wave)
+            return self._salvage("wave", pods, self._classify(
+                pods, e, "featurize"), rt, rec)
         if not pods:
             # the whole wave was convicted at featurize time
-            if rt is not None:
-                rec.end_round(rt, outcome="input_fault")
-            return placed_host
-        try:
-            extra = self._host_plugin_mask(pods, pb.req.shape[0])
-            extra_scores = self._host_score_matrix(pods, pb.req.shape[0])
-        except ExtenderError:
-            # a non-ignorable extender is unreachable: fail only this
-            # attempt — park the wave for retry on the next cluster event /
-            # flush, don't crash the loop (reference: scheduleOne records
-            # the error and MakeDefaultErrorFunc requeues with backoff)
-            self.metrics.scheduling_errors.labels(stage="extender").inc()
-            for p in pods:
-                self._park_with_backoff(p)
-            if rt is not None:
-                rec.end_round(rt, outcome="extender_error")
-            return placed_host
+            self._ledger(rt, rec, outcome="input_fault")
+            return 0
+        planes = self._host_planes(pods, pb.req.shape[0])
+        if planes is None:
+            self._ledger(rt, rec, outcome="extender_error")
+            return 0
+        extra, extra_scores = planes
         trace.step("featurized")
         if rt is not None:
             rt.mark("featurize", pods=len(pods))
@@ -3202,20 +2940,8 @@ class Scheduler:
             # sentinel path
             self._wave_poison_seam(pods, pb)
         except Exception as e:
-            verdict = self._input_fault_verdict(pods, e)
-            if rt is not None:
-                rec.end_round(rt, outcome=("input_fault"
-                                           if verdict is not None
-                                           else "device_failure"),
-                              error=type(e).__name__)
-            if verdict is None:
-                # transient (a times-bounded fault drained): park the
-                # wave for a clean retry
-                for p in pods:
-                    self._park_with_backoff(p)
-                return placed_host
-            return placed_host + self._isolate_poison(pods, verdict,
-                                                      self._run_wave)
+            return self._salvage(
+                "wave", pods, self._classify(pods, e, "seam"), rt, rec)
         nt, pm, tt = self._to_device()
         if rt is not None:
             rt.mark("upload", cat="device",
@@ -3224,132 +2950,30 @@ class Scheduler:
         # live CLI loop runs run_once -> HERE, and host-stage overruns
         # must shrink the wave there too, not only under the pipeline
         self._account_host_overrun(self.clock() - start)
-        if self._rr is None:
-            # re-seed from the host mirror: a twin-salvaged round nulls
-            # _rr after advancing _host_rr, so device resumption keeps
-            # the logical counter continuous (bit-equal tie-breaks)
-            self._rr = jnp.asarray(self._host_rr, jnp.int32)
-        has_ipa = bool(self.snapshot.has_affinity_terms or pb.ra_has.any()
-                       or pb.rn_has.any() or (pb.pa_w != 0).any())
-        wv = jnp.asarray(wvec)
-        nom = self._nominations([pods], pb.req.shape[0])
-        if self._active_mesh is not None:
-            from ..parallel.mesh import (mesh_divides, replicate, shard_extra,
-                                         shard_inputs)
-
-            mesh = self._active_mesh
-            # the rr carry may still be committed to a single device by
-            # rounds run before the cluster grew to divide the mesh —
-            # mixing commitments in one jit is an error, so re-commit
-            self._rr = replicate(mesh, self._rr)
-            wv = replicate(mesh, wv)
-            if nom is not None:
-                nom = enc.Nominations(*replicate(mesh, tuple(nom)))
-            if mesh_divides(mesh, nt.valid.shape[0], pb.req.shape[0]):
-                # nt/pm/tt are already committed by _to_device; re-putting
-                # to the identical shardings transfers nothing — this
-                # call shards the pod batch / extra mask
-                nt, pm, tt, pb, extra = shard_inputs(mesh, nt, pm, tt,
-                                                     pb, extra)
-                if extra_scores is not None:
-                    extra_scores = shard_extra(mesh, extra_scores)
-        if self._use_pallas is None:
-            self._use_pallas = pallas_default()
-            if self.mesh is not None and self.mesh.devices.size > 1:
-                # the fused pallas kernel is a single-device program; under
-                # a multi-device mesh the partitionable XLA formulation is
-                # the correct hot path (GSPMD can't shard a pallas_call)
-                self._use_pallas = False
+        has_ipa, wv, nom, _, (nt, pm, tt, pb, extra, extra_scores) = \
+            self._device_inputs(
+                [pb], wvec, nom=self._nominations([pods], pb.req.shape[0]),
+                wave=(nt, pm, tt, pb, extra, extra_scores))
         kw = dict(weights=gating, weight_vec=wv, nom=nom,
                   num_zones=self.snapshot.caps.Z,
                   num_label_values=self.snapshot.num_label_values,
-                  has_ipa=bool(has_ipa),
+                  has_ipa=has_ipa,
                   # decomposition rides along exactly when tracing; off,
                   # the compiled program is byte-identical to before
                   collect_scores=rt is not None)
-        try:
-            try:
-                res = schedule_wave(nt, pm, tt, pb, extra, self._rr,
-                                    extra_scores,
-                                    use_pallas=self._use_pallas, **kw)
-                # dispatch is async: a kernel that compiles but faults at
-                # execution raises only when results are consumed, so force
-                # materialization here — inside the try — or the fallback
-                # below could never catch it
-                jax.block_until_ready(res)
-            except Exception as e:
-                if isinstance(e, DispatchTimeout):
-                    # a watchdog abandonment is not a pallas problem:
-                    # retrying the XLA formulation would dispatch AGAIN
-                    # at the wedged runtime (under the compile-scaled
-                    # budget — the XLA variant was never warmed) and
-                    # burn another deadline; straight to the outer
-                    # handler, which trips the breaker and degrades
-                    raise
-                if not self._use_pallas:
-                    raise
-                self._pallas_demoted("wave", f"{type(e).__name__}: {e}",
-                                     exc=e)
-                self._use_pallas = False
-                try:
-                    res = schedule_wave(nt, pm, tt, pb, extra, self._rr,
-                                        extra_scores, use_pallas=False, **kw)
-                    jax.block_until_ready(res)
-                except Exception:
-                    # the XLA path failed too: the error was never
-                    # pallas-specific (bad shapes, transient device OOM), so
-                    # don't permanently demote the fast path on its account
-                    self._use_pallas = True
-                    raise
-        except Exception as e:
-            # capacity-fault attribution FIRST (see _run_pipeline's
-            # catch): the scheduler's own footprint must never blame
-            # the device or the work
-            if is_capacity_error(e):
-                return placed_host + self._capacity_fault(
-                    pods, e, rt, rec, self._run_wave)
-            # input-fault attribution BEFORE breaker/reform accounting:
-            # bad work must never blame — or degrade — the runtime
-            verdict = self._input_fault_verdict(pods, e)
-            if verdict is not None:
-                if rt is not None:
-                    rec.end_round(rt, outcome="input_fault",
-                                  error=type(e).__name__)
-                return placed_host + self._isolate_poison(pods, verdict,
-                                                          self._run_wave)
-            # every formulation failed: count it against the breaker
-            # and degrade THIS wave to the exact host path — a device
-            # fault must cost a slower wave, never a stopped scheduler
-            self._device_failure(e)
-            if rt is not None:
-                rec.end_round(rt, outcome="device_failure",
-                              error=type(e).__name__,
-                              mesh=self._mesh_ledger())
-            # golden is NOT re-passed: this wave's own (failed) round
-            # record already ledgered it at begin_round
-            return placed_host + self._schedule_degraded(pods)
-        self.breaker.record_success()
-        self._capacity_strikes = 0
-        self._last_path = "pallas" if self._use_pallas else "xla"
+        res, verdict = self._dispatch(
+            "wave", pods, lambda use_p: schedule_wave(
+                nt, pm, tt, pb, extra, self._rr, extra_scores,
+                use_pallas=use_p, **kw),
+            finite=lambda r: np.asarray(r.finite))
+        if verdict is not None:
+            return self._salvage("wave", pods, verdict, rt, rec)
         chosen = np.asarray(res.chosen)
         fin = np.asarray(res.finite)
-        # numeric-integrity sentinel, fetched alongside `chosen` (same
-        # program — zero extra dispatch): non-finite rows mean a poison
-        # pod contaminated the scan's shared carries, so the WHOLE wave
-        # is discarded (a NaN carry silently shifts innocent pods'
-        # placements), the flagged pods convict, and the survivors
-        # re-run — placing bit-equal a clean run. The rr carry is
-        # deliberately not advanced for a discarded wave.
-        bad = [pods[i].uid for i in range(len(pods)) if not fin[i]]
-        if bad:
-            if rt is not None:
-                rec.end_round(rt, outcome="input_fault", poison=len(bad))
-            return placed_host + self._isolate_poison(
-                pods, PoisonError("numeric-integrity sentinel", uids=bad),
-                self._run_wave)
         self._rr = res.rr_end
         if rt is not None:
-            rt.mark("device_wave", cat="device", path=self._last_path)
+            rt.mark("device_wave", cat="device",
+                    path=self.formulation.last_path)
         # mirror: one rr advance per placement (see _host_rr)
         self._host_rr += int(np.sum(chosen >= 0))
         fetched = chosen.nbytes + fin.nbytes
@@ -3389,31 +3013,20 @@ class Scheduler:
             # ledger's (state, placement, outcome) record carries the
             # per-priority breakdown + margin-over-runner-up for
             # offline scoring-weight analysis
-            scores = shadow = None
-            if deco is not None:
-                scores, shadow = self._record_decisions(
-                    rec, pods, chosen, *deco, committed=committed,
-                    wvec=wvec, wver=wver)
-            if scores is None and committed:
-                # summary only over placements that actually committed —
-                # a device choice the exact recheck rejected never
-                # became a binding and must not produce score stats
-                sc = np.asarray(res.score)
-                won = sc[[i for i, p in enumerate(pods)
-                          if p.uid in committed]]
-                scores = ({"min": round(float(won.min()), 4),
-                           "max": round(float(won.max()), 4),
-                           "mean": round(float(won.mean()), 4)}
-                          if won.size else None)
+            # a traced wave always carries its decomposition: summary
+            # only over placements that actually committed
+            scores, shadow = self._record_decisions(
+                rec, pods, chosen, *deco, committed=committed,
+                wvec=wvec, wver=wver)
             self._emit_telemetry(rt)
             rec.end_round(
                 rt, outcome="ok", placed=placed,
-                failed=len(pods) - placed, path=self._last_path,
+                failed=len(pods) - placed, path=self.formulation.last_path,
                 scores=scores, shadow=shadow,
                 snapshot=self._round_snapshot_shape(),
                 breaker=self.breaker.state, mesh=self._mesh_ledger())
         trace.log_if_long(0.1)
-        return placed + placed_host
+        return placed
 
     def _extender_node_labels(self) -> Optional[Dict[str, dict]]:
         """Full node -> labels map for non-cache-capable filter
@@ -3631,34 +3244,32 @@ class Scheduler:
 
     # -- gang (PodGroup) scheduling --------------------------------------------
 
-    def _schedule_gangs(self, pods: List[api.Pod]) -> int:
-        """All-or-nothing placement for the wave's gang pods, grouped by
-        PodGroup. Gangs are committed one group at a time so the second
-        gang's device pass sees the first gang's assumed usage (the
-        snapshot re-uploads its dirty resource group) — two gangs
-        contending for the same nodes can therefore never interleave
-        partial placements: the loser fails whole."""
+    def _gangs_first(self, pods: List[api.Pod], place):
+        """Place a batch's gangs through place(key, members), one
+        PodGroup at a time; returns (placed, the other pods). Gangs
+        commit one group at a time so the second gang's pass sees the
+        first gang's assumed usage: two gangs contending for the same
+        nodes can never interleave partial placements, the loser fails
+        whole. pop_wave delivers gangs whole; a batch with no gang pod
+        costs one annotation lookup per pod."""
         groups: Dict[str, List[api.Pod]] = {}
+        rest: List[api.Pod] = []
         for p in pods:
-            groups.setdefault(self.gangs.key(p), []).append(p)
-        placed = 0
-        for key, members in groups.items():
-            placed += self._schedule_one_gang(key, members)
-        return placed
+            key = self.gangs.key(p)
+            if key is None:
+                rest.append(p)
+            else:
+                groups.setdefault(key, []).append(p)
+        return sum(place(k, m) for k, m in groups.items()), rest
 
     def _schedule_one_gang(self, key: str, members: List[api.Pod]) -> int:
         self.metrics.gang_schedule_attempts.inc()
         for _p in members:
             self.metrics.schedule_attempts.inc()
-        rec = tracing.active()
-        rt = None
-        if rec is not None:
-            rt = rec.begin_round("gang", pending=len(members), gang=key,
-                                 weights_version=self.weightbook
-                                 .live_version())
-            self._trace_queue_waits(rt, members)
+        rec, rt = self._begin_round("gang", members,
+                                    self.weightbook.live_version(), gang=key)
         try:
-            placed = self._schedule_one_gang_inner(key, members, rt)
+            placed = self._place_gang(key, members, rt)
         finally:
             if rt is not None and rt.t1 is None:
                 rec.end_round(rt, snapshot=self._round_snapshot_shape(),
@@ -3666,195 +3277,146 @@ class Scheduler:
                               mesh=self._mesh_ledger())
         return placed
 
-    def _schedule_one_gang_inner(self, key: str, members: List[api.Pod],
-                                 rt=None) -> int:
-        import jax
+    def _schedule_degraded_gang(self, key: str, members: List[api.Pod],
+                                rt=None) -> int:
+        """Degraded-mode gang placement: all or nothing through the host
+        twin (see _place_gang)."""
+        self.metrics.gang_schedule_attempts.inc()
+        for _p in members:
+            self.metrics.schedule_attempts.inc()
+        return self._place_gang(key, members, rt, host=True)
+
+    def _place_gang(self, key: str, members: List[api.Pod], rt=None,
+                    host: bool = False) -> int:
+        """All-or-nothing placement of one PodGroup: the joint-assignment
+        program on the device or, with `host`, the host twin's
+        count-feasibility plane (ops/hostwave.py schedule_gang_host).
+        Either minMember members hold capacity at once or nothing
+        commits. Members the device can't encode (multi-topology-key
+        required affinity) take the exact golden path one at a time,
+        where atomicity is not offered; on the twin one such member
+        sends the whole gang there."""
         import jax.numpy as jnp
 
+        from ..ops import hostwave
         from ..ops.gang import schedule_gang
 
         # per-gang admission: an earlier gang in this very batch may
         # have been watchdog-abandoned — each remaining gang must
         # re-check before dispatching (and must not burn another full
         # wave_deadline_s against a runtime already presumed wedged)
-        if not self._device_admitted():
+        if not host and not self._device_admitted():
             return self._schedule_degraded_gang(key, members, rt)
         min_member = self.gangs.min_member(members[0])
         bound = self.gangs.bound_count(self.cache, key,
                                        exclude={p.uid for p in members})
         # members already holding capacity (earlier rounds, or a bind
-        # retry straggler) count toward minMember: the wave only needs
-        # to place the remainder
+        # retry straggler) count toward minMember: the program only
+        # needs to place the remainder
         need = max(min_member - bound, 0)
         placed = 0
-        host_path = [p for p in members if self.featurizer.needs_host_path(p)]
-        if host_path:
-            # multi-topology-key required affinity can't be device-
-            # encoded; such members take the exact host path one at a
-            # time — atomicity is not offered for this combination
-            placed += self._schedule_host_batch(host_path)
-            members = [p for p in members
-                       if not self.featurizer.needs_host_path(p)]
+        needs_golden = self.featurizer.needs_host_path
+        golden_members = [p for p in members if needs_golden(p)]
+        if golden_members:
+            if host:
+                self._count_degraded_golden(golden_members, rt)
+                return self._schedule_host_batch(members)
+            placed += self._schedule_host_batch(golden_members)
+            members = [p for p in members if not needs_golden(p)]
             if not members:
                 return placed
+
+        def salvage(verdict):
+            # a poisoned member quarantines the whole group (a
+            # sub-minMember remnant would wedge against its own
+            # admission gate forever); the twin salvages it whole
+            return placed + self._salvage(
+                "gang", members, verdict, rt, degrade=lambda ms: self
+                ._schedule_degraded_gang(key, ms, rt))
+
         try:
             pb = self.featurizer.featurize(members)
-        except PodFeaturizeError as e:
-            # gang-atomic conviction: one poisoned member quarantines
-            # the whole group (a sub-minMember remnant would wedge
-            # against its own admission gate forever)
-            self._gang_input_fault(members, e, rt)
-            return placed
-        P = pb.req.shape[0]
-        try:
-            extra = self._host_plugin_mask(members, P)
-            extra_scores = self._host_score_matrix(members, P)
-        except ExtenderError:
-            self.metrics.scheduling_errors.labels(stage="extender").inc()
-            for p in members:
-                self._park_with_backoff(p)
-            if rt is not None:
-                rt.ledger["outcome"] = "extender_error"
-            return placed
-        if rt is not None:
-            rt.mark("featurize", pods=len(members))
-        try:
-            # chaos seam while pb is still host-side (see _run_wave)
-            self._wave_poison_seam(members, pb)
         except Exception as e:
-            verdict = self._input_fault_verdict(members, e)
-            if verdict is None:
-                for p in members:
-                    self._park_with_backoff(p)
-                if rt is not None:
-                    rt.ledger["outcome"] = "device_failure"
-                return placed
-            self._gang_input_fault(members, verdict, rt)
+            if host and not isinstance(e, PodFeaturizeError):
+                raise
+            return salvage(self._classify(members, e, "featurize"))
+        planes = self._host_planes(members, pb.req.shape[0])
+        if planes is None:
+            self._ledger(rt, None, outcome="extender_error")
             return placed
-        nt, pm, tt = self._to_device()
-        if rt is not None:
-            rt.mark("upload", cat="device")
-        if self._rr is None:
-            # re-seed from the host mirror: a twin-salvaged round nulls
-            # _rr after advancing _host_rr, so device resumption keeps
-            # the logical counter continuous (bit-equal tie-breaks)
-            self._rr = jnp.asarray(self._host_rr, jnp.int32)
-        if self._use_pallas is None:
-            self._use_pallas = pallas_default()
-        has_ipa = bool(self.snapshot.has_affinity_terms or pb.ra_has.any()
-                       or pb.rn_has.any() or (pb.pa_w != 0).any())
+        extra, extra_scores = planes
         gating, wvec, _wver = self._weights_kw()
-        wv = jnp.asarray(wvec)
-        if self._active_mesh is not None:
-            from ..parallel.mesh import (mesh_divides, replicate, shard_extra,
-                                         shard_inputs)
-
-            mesh = self._active_mesh
-            self._rr = replicate(mesh, self._rr)  # see _run_wave
-            wv = replicate(mesh, wv)
-            if mesh_divides(mesh, nt.valid.shape[0], pb.req.shape[0]):
-                # joint-assignment runs under the mesh like a wave: node
-                # tensors stay sharded, the member batch shards on the
-                # wave axis (replicated at wave_parallel=1)
-                nt, pm, tt, pb, extra = shard_inputs(mesh, nt, pm, tt,
-                                                     pb, extra)
-                if extra_scores is not None:
-                    extra_scores = shard_extra(mesh, extra_scores)
-        kw = dict(weights=gating, weight_vec=wv,
-                  num_zones=self.snapshot.caps.Z,
-                  num_label_values=self.snapshot.num_label_values,
-                  has_ipa=has_ipa)
-        try:
+        if host:
+            nt, pm, tt = self.snapshot.host_tensors()
             try:
-                res = schedule_gang(nt, pm, tt, pb, extra, self._rr,
-                                    extra_scores,
-                                    jnp.asarray(need, jnp.int32),
-                                    use_pallas=self._use_pallas, **kw)
-                jax.block_until_ready(res)
+                self._wave_poison_seam(members, pb)
+                res = hostwave.schedule_gang_host(
+                    nt, pm, tt, pb, extra, self._host_rr, extra_scores,
+                    need, weights=gating, num_zones=self.snapshot.caps.Z,
+                    num_label_values=self.snapshot.num_label_values,
+                    has_ipa=self._has_ipa([pb]), weight_vec=wvec)
             except Exception as e:
-                if isinstance(e, DispatchTimeout):
-                    raise  # wedged runtime, not a pallas failure: no retry
-                if not self._use_pallas:
-                    raise
-                self._pallas_demoted("gang", f"{type(e).__name__}: {e}",
-                                     exc=e)
-                self._use_pallas = False
-                try:
-                    res = schedule_gang(nt, pm, tt, pb, extra, self._rr,
-                                        extra_scores,
-                                        jnp.asarray(need, jnp.int32),
-                                        use_pallas=False, **kw)
-                    jax.block_until_ready(res)
-                except Exception:
-                    self._use_pallas = True
-                    raise
-        except Exception as e:
-            # capacity-fault attribution first (see _run_pipeline's
-            # catch): compact and salvage the gang through the host
-            # twin's all-or-nothing plane — never a device conviction,
-            # mesh reform, or gang quarantine for the scheduler's own
-            # footprint
-            if is_capacity_error(e):
-                self._capacity_strikes += 1
-                self.metrics.capacity_faults.inc()
-                self._compact_guarded(trigger="oom")
+                # a host-path crash follows the data: the gang convicts
+                return salvage(Verdict(INPUT, e if isinstance(
+                    e, (PoisonError, PodFeaturizeError)) else PoisonError(
+                    f"host twin gang pass failed: {type(e).__name__}: {e}"),
+                    {}))
+            self.formulation.last_path = "vector"
+            if rt is not None:
+                rt.mark("host_wave", cat="host", backend="vector", gang=key,
+                        pods=len(members))
+            # the twin discards nothing on its own (count feasibility
+            # may even have passed): the gang convicts before any commit
+            verdict = self._sentinel(members, np.asarray(res.finite))
+        else:
+            if rt is not None:
+                rt.mark("featurize", pods=len(members))
+            try:
+                # chaos seam while pb is still host-side (see _wave)
+                self._wave_poison_seam(members, pb)
+            except Exception as e:
+                return salvage(self._classify(members, e, "seam"))
+            nt, pm, tt = self._to_device()
+            if rt is not None:
+                rt.mark("upload", cat="device")
+            # joint assignment runs under the mesh like a wave: node
+            # tensors stay sharded, the member batch shards on the wave
+            # axis (replicated at wave_parallel=1)
+            has_ipa, wv, _nom, _, (nt, pm, tt, pb, extra, extra_scores) = \
+                self._device_inputs([pb], wvec, wave=(nt, pm, tt, pb, extra,
+                                                      extra_scores))
+            res, verdict = self._dispatch(
+                "gang", members, lambda use_p: schedule_gang(
+                    nt, pm, tt, pb, extra, self._rr, extra_scores,
+                    jnp.asarray(need, jnp.int32), weights=gating,
+                    weight_vec=wv, num_zones=self.snapshot.caps.Z,
+                    num_label_values=self.snapshot.num_label_values,
+                    has_ipa=has_ipa, use_pallas=use_p),
+                finite=lambda r: np.asarray(r.finite))
+            if res is not None:
+                # counted before the sentinel: a discarded gang still ran
+                self.metrics.waves_total.labels(path="device").inc()
                 if rt is not None:
-                    rt.ledger.update(outcome="capacity_fault",
-                                     error=type(e).__name__,
-                                     memory=self._memory_ledger())
-                return placed + self._schedule_degraded_gang(key, members,
-                                                             rt)
-            # input-fault attribution first: a poisoned member must
-            # quarantine its gang, never feed the breaker or the ladder
-            verdict = self._input_fault_verdict(members, e)
-            if verdict is not None:
-                self._gang_input_fault(members, verdict, rt)
-                return placed
-            # the joint-assignment kernel IS the device path: park the
-            # gang for retry (atomicity is preserved — nothing placed)
-            # and let the breaker route future waves host-side once it
-            # trips
-            reformed = self._device_failure(e)
-            if rt is not None:
-                rt.ledger.update(outcome="device_failure",
-                                 error=type(e).__name__)
-            if reformed or isinstance(e, DispatchTimeout):
-                # wedged dispatch or a lost mesh device: salvage the
-                # gang through the host twin's all-or-nothing plane
-                # right now (the mesh reformed, or the breaker just
-                # opened; atomicity is preserved either way) — the next
-                # gang dispatches on the reformed mesh
-                return placed + self._schedule_degraded_gang(key, members,
-                                                             rt)
-            for p in members:
-                self._park_with_backoff(p)
-            return placed
-        self.breaker.record_success()
-        self._capacity_strikes = 0
-        self._last_path = "pallas" if self._use_pallas else "xla"
-        self.metrics.waves_total.labels(path="device").inc()
-        if rt is not None:
-            rt.mark("device_wave", cat="device", path=self._last_path)
-        chosen = np.asarray(res.chosen)
-        fin = np.asarray(res.finite)
-        self.metrics.device_fetch_bytes.inc(chosen.nbytes + fin.nbytes)
-        # numeric-integrity sentinel (same fetch): a poisoned member
-        # discards the whole gang's placements and convicts the group
-        # atomically — rr not advanced, nothing committed
-        bad = [members[i].uid for i in range(len(members)) if not fin[i]]
-        if bad:
-            self._gang_input_fault(
-                members,
-                PoisonError("numeric-integrity sentinel", uids=bad), rt)
-            return placed
+                    rt.mark("device_wave", cat="device",
+                            path=self.formulation.last_path)
+                self.metrics.device_fetch_bytes.inc(
+                    np.asarray(res.chosen).nbytes
+                    + np.asarray(res.finite).nbytes)
+        if verdict is not None:
+            return salvage(verdict)
         if not bool(np.asarray(res.ok)):
-            if rt is not None:
+            if rt is not None and not host:
                 rt.ledger.update(outcome="gang_unplaceable",
-                                 path=self._last_path)
+                                 path=self.formulation.last_path)
             self._fail_gang(key, members, need, res)
             return placed
-        self._rr = res.rr_end
-        self._host_rr += int(np.sum(chosen >= 0))  # see _host_rr mirror
+        chosen = np.asarray(res.chosen)
+        if host:
+            self._host_rr = int(res.rr_end)
+            self._rr = None  # device resumption re-seeds from the mirror
+        else:
+            self._rr = res.rr_end
+            self._host_rr += int(np.sum(chosen >= 0))  # see _host_rr
         pairs: List = []
         leftover: List = []
         for i, pod in enumerate(members):
@@ -3868,14 +3430,16 @@ class Scheduler:
             # retry the whole gang next wave, not unschedulable
             for pod in members:
                 self.queue.add_if_not_present(pod)
-            if rt is not None:
+            if rt is not None and not host:
                 rt.ledger["outcome"] = "recheck_race"
             return placed
         self.backoff.clear("gang:" + key)
-        if rt is not None:
+        if host:
+            self.metrics.waves_total.labels(path="host").inc()
+        elif rt is not None:
             rt.mark("commit", placed=len(pairs))
             rt.ledger.update(outcome="ok", placed=len(pairs),
-                             path=self._last_path)
+                             path=self.formulation.last_path)
         # surplus members beyond minMember that didn't fit park
         # individually with normal per-pod attribution
         if leftover:

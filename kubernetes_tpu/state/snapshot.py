@@ -942,7 +942,13 @@ class Snapshot:
                 rows.clear()
                 return
         self._account_upload(key, host)
-        cache[key] = jax.device_put(host, target)
+        # upload copies: on the CPU backend device_put may alias an
+        # aligned numpy buffer instead of copying it, and these host
+        # planes are written in place, so the "device" group would
+        # change under later rounds without an upload — or not,
+        # depending on where the allocator put the buffer
+        cache[key] = jax.device_put(tuple(np.array(a) for a in host),
+                                    target)
         rows.clear()
 
     def to_device(self, device=None, mesh=None

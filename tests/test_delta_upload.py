@@ -268,3 +268,27 @@ def test_trickle_upload_bytes_cut_10x():
     worst = max(steady)
     assert worst * 10 <= full, (per_round, full)
     sched.close()
+
+
+def test_device_groups_never_alias_host_planes():
+    """The host planes are written in place, so every device group must
+    be a copy: a host write reaches the device only through an upload.
+    On the CPU backend device_put may alias an aligned numpy buffer
+    instead of copying it, so several fresh worlds try."""
+    for seed in range(8):
+        nodes, existing, _ = random_world(random.Random(seed), n_nodes=64,
+                                          n_existing=64)
+        _cache, snap = build(nodes, existing)
+        snap.to_device()
+        uploaded = {g: [np.array(a, copy=True) for a in snap._device_cache[g]]
+                    for g in GROUPS}
+        for g in GROUPS:
+            for a in snap._group_host(g):
+                # in place, and no dirty row: no upload is due
+                a[...] = ~a if a.dtype == bool else a + 1
+        snap.to_device()
+        for g in GROUPS:
+            for i, a in enumerate(snap._device_cache[g]):
+                np.testing.assert_array_equal(
+                    np.asarray(a), uploaded[g][i],
+                    err_msg=f"group {g} array {i} follows the host plane")

@@ -102,15 +102,6 @@ class TestPallasParity:
         np.testing.assert_array_equal(np.asarray(base.masks),
                                       np.asarray(pal.masks))
 
-    def test_pallas_default_env(self, monkeypatch):
-        from kubernetes_tpu.ops.kernel import pallas_default
-        monkeypatch.setenv("KTPU_PALLAS", "1")
-        assert pallas_default() is True
-        monkeypatch.setenv("KTPU_PALLAS", "0")
-        assert pallas_default() is False
-        monkeypatch.setenv("KTPU_PALLAS", "auto")
-        assert pallas_default() is False  # tests run on cpu
-
     def test_round_with_hoisted_pallas_matches(self):
         """schedule_round with use_pallas (the hoisted pre-scan Pallas
         pass, interpret mode) == stock round on a taint/port-rich world:
