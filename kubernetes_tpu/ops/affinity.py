@@ -21,11 +21,14 @@ Dense shape of the problem:
     node axis, then the label-value vocabulary. A pod's domain is a
     function of its node, so matching pods are first counted per node
     by one segment-sum keyed by each pod's node, shared by every program
-    ([U, M] -> [U, N]); those counts are reduced by each node's domain
-    value ([U, LV]), then gathered at each node's domain value
-    ([U, N]). The sums are integer-valued f32, exact in any order. Pods
-    whose required terms span >1 topology key take the exact host path
-    (plugins/golden.py) instead.
+    ([U, M] -> [U, N]). The nodes then reach their domains through MXU
+    contractions (`anchor`): per topology key in the tables, a
+    node-to-domain one-hot O [N, LV] takes the node counts to per-domain
+    sums ([U, N] @ O -> [U, LV]) and those back to each node's own
+    domain ([U, LV] @ O.T -> [U, N]). Operands are bf16 0/1 or base-256
+    digits of the counts, accumulated in f32, so every sum is exact.
+    Pods whose required terms span >1 topology key take the exact host
+    path (plugins/golden.py) instead.
   * Wave-internal visibility (a pod must see placements made earlier in
     the same wave, like the reference's one-at-a-time assume) is handled
     in the commit scan in ops/kernel.py using [P, P] cross-match
@@ -44,6 +47,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from . import encoding as enc
 from .encoding import NodeTensors, PodBatch, PodMatrix, TermTable
@@ -127,18 +131,70 @@ def node_counts(match, node, num_nodes):
     return jax.ops.segment_sum(rows, node, num_segments=num_nodes).T
 
 
-def _anchored_hit(node_cnt, node_dom, num_segments, count=False):
-    """node_cnt: [B, N] matching pods (or bool presence) per node;
-    node_dom: i32 [B, N] each node's domain value under row b's topology
-    key, 0 = key absent. Segment-reduce the nodes over the label-value
-    vocab: returns [B, LV] (bool any, or f32 counts)."""
-    contrib = jnp.where(node_dom > 0, node_cnt.astype(jnp.float32), 0.0)
+def _digits(x):
+    """bf16 [3, ...] — base-256 digits, lowest first, of the integer-valued
+    f32 x (0 <= x < 2**24). Each digit is exact in bf16, so a contraction
+    of the digits on the MXU (f32 accumulation) rounds nothing."""
+    hi = jnp.floor(x / 65536.0)
+    rest = x - hi * 65536.0
+    mid = jnp.floor(rest / 256.0)
+    return jnp.stack([rest - mid * 256.0, mid, hi]).astype(jnp.bfloat16)
 
-    def seg(row, dom):
-        return jax.ops.segment_sum(row, dom, num_segments=num_segments)
 
-    hit = jax.vmap(seg)(contrib, node_dom)
-    return hit if count else hit > 0.5
+def _undigit(d):
+    """f32 [...] from f32 [3, ...] digit sums (inverse of _digits)."""
+    return d[0] + 256.0 * d[1] + 65536.0 * d[2]
+
+
+def anchor(x, tk, labels, num_label_values, count=False, to_nodes=False):
+    """Anchor per-node values at topology domains.
+
+    x: [B, N] matching pods per node (or bool presence), integer-valued
+    and below 2**24; tk: i32 [B] row b's topology key column, 0 = none
+    (read as node_domains reads it); labels: i32 [N, K] node label value
+    ids. Returns, per label value v > 0, the sum (count) or any (else)
+    of row b over the nodes whose value under b's key is v, [B, LV]; or,
+    to_nodes, that result at each node's own domain, 0 where the node
+    lacks the key, [B, N].
+
+    One pass per distinct key among the rows (a while loop, so the count
+    adapts to the tables): the key's node-to-domain one-hot O [N, LV]
+    contracts the key's rows on the MXU, x @ O, then back, @ O.T."""
+    N, K = labels.shape
+    B = x.shape[0]
+    key = jnp.where(tk > 0, jnp.clip(tk, 0, K - 1), -1)
+    lv = jnp.arange(num_label_values, dtype=jnp.int32)
+    if count:
+        planes = _digits(x.astype(jnp.float32))  # [3, B, N]
+    else:
+        planes = (x > 0).astype(jnp.bfloat16)[None]  # [1, B, N]
+
+    def contract(a, onehot, back):
+        dims = (((2,), (1 if back else 0,)), ((), ()))
+        out = lax.dot_general(a, onehot, dims,
+                              preferred_element_type=jnp.float32)
+        return _undigit(out) if count else out[0] > 0.5
+
+    def next_key(k):
+        return jnp.min(jnp.where(key > k, key, K))
+
+    def body(c):
+        k, acc = c
+        col = lax.dynamic_index_in_dim(labels, k, axis=1, keepdims=False)
+        onehot = ((col[:, None] == lv[None, :])
+                  & (lv > 0)[None, :]).astype(jnp.bfloat16)  # [N, LV]
+        rows = jnp.where((key == k)[None, :, None], planes, 0)
+        hit = contract(rows, onehot, False)  # [B, LV]
+        if to_nodes:
+            up = (_digits(hit) if count
+                  else hit.astype(jnp.bfloat16)[None])  # [3 or 1, B, LV]
+            hit = contract(up, onehot, True)  # [B, N]
+        return next_key(k), (acc + hit if count else acc | hit)
+
+    width = N if to_nodes else num_label_values
+    acc0 = jnp.zeros((B, width), jnp.float32 if count else bool)
+    _, out = lax.while_loop(lambda c: c[0] < K, body, (next_key(-1), acc0))
+    return out
 
 
 def incoming_statics(nt: NodeTensors, pm: PodMatrix, tt: TermTable,
@@ -160,18 +216,18 @@ def incoming_statics(nt: NodeTensors, pm: PodMatrix, tt: TermTable,
     # incoming pods' preferred terms, the same way (unique table pb.pu_*)
     pu_sel = _eval_programs(pm.labels, pb.pu_key, pb.pu_op, pb.pu_vals)
     pu_m = pu_sel & ns_match(pb.pu_ns, pm.ns) & pm.valid[None, :]  # [UP, M]
-    dom_pu = node_domains(nt, pb.pu_tk)  # [UP, N]
     U = u_m.shape[0]
     with jax.named_scope("affinity_anchor"):
         # both tables' matching pods per node in one shared-index scatter
         cnt_n = node_counts(jnp.concatenate([u_m, pu_m]), pm.node,
                             nt.valid.shape[0])  # [U + UP, N]
-        hit_u = _anchored_hit(cnt_n[:U], node_dom_u,
-                              num_label_values)  # [U, LV]
-        cnt_u = _anchored_hit(cnt_n[U:], dom_pu, num_label_values,
-                              count=True)  # [UP, LV]
-    # "a matching pod exists in node n's domain" per unique program
-    ok_u = jnp.take_along_axis(hit_u, node_dom_u, axis=1) & (node_dom_u > 0)
+        # "a matching pod exists in node n's domain" per unique program
+        ok_u = anchor(cnt_n[:U], pb.iu_tk, nt.labels, num_label_values,
+                      to_nodes=True)  # [U, N]
+        # matching pods in node n's domain per unique preferred term
+        cnt_node_u = anchor(cnt_n[U:], pb.pu_tk, nt.labels,
+                            num_label_values, count=True,
+                            to_nodes=True)  # [UP, N]
     any_u = jnp.any(u_m, axis=1)  # [U]
 
     ok_aff = ok_u[pb.ra_uid]  # [P, N]
@@ -191,8 +247,6 @@ def incoming_statics(nt: NodeTensors, pm: PodMatrix, tt: TermTable,
     counts = (em.astype(jnp.float32) * we[None, :]) @ sd.astype(jnp.float32)
     # incoming pods' preferred terms: per-slot gather of the unique
     # table's counts + weight (weights stay per-pod in pa_w)
-    cnt_node_u = (jnp.take_along_axis(cnt_u, dom_pu, axis=1)
-                  * (dom_pu > 0))  # [UP, N]
     PA = pb.pa_w.shape[1]
     for t in range(PA):
         counts = counts + pb.pa_w[:, t, None] * cnt_node_u[pb.pa_uid[:, t]]

@@ -465,10 +465,12 @@ def node_domains_host(nt, tk):
 
 
 def _anchored_hit_host(match, dom_m, num_segments, count=False):
-    """affinity._anchored_hit twin: segment-reduce matching pods by
-    their node's domain value; [P/U, M] -> [P/U, LV]. bincount
+    """Twin of affinity.anchor's per-domain sums (the callers here take
+    each node's view by indexing the result): segment-reduce matching
+    pods by their own node's domain value, [P/U, M] -> [P/U, LV], not
+    through per-node counts or a contraction. bincount
     accumulates in f64 and the counts are integers, so the f32 round is
-    exact (matches the device's f32 segment_sum bit-for-bit)."""
+    exact (matches the device's exact MXU contraction bit-for-bit)."""
     contrib = (match & (dom_m > 0)).astype(np.float32)
     B = match.shape[0]
     hit = np.zeros((B, num_segments), np.float32)
@@ -557,7 +559,7 @@ def topo_statics_host(nt, pm, pb, num_label_values: int):
     PodTopologySpread state as the same TopoStatics tuple over numpy
     planes. Counts go through the f64 bincount + f32 round of
     _anchored_hit_host (integer-valued, so bitwise with the device's
-    f32 segment_sum)."""
+    exact MXU contraction)."""
     from .topology import TopoStatics
 
     P, TS = pb.ts_tk.shape

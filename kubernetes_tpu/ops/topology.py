@@ -15,9 +15,9 @@ Dense shape of the problem:
     labels. Resident matching-pod counts per topology-domain VALUE are
     anchored as in ops/affinity.py: matching pods counted per node by
     one segment-sum keyed by each pod's node (`node_counts`), then the
-    nodes segment-reduced by their domain value (`_anchored_hit`; the
-    zone tally in ops/zonehealth.py, generalized from the fixed zone
-    column to arbitrary label keys).
+    nodes summed per domain value by an exact MXU contraction with the
+    key's node-to-domain one-hot (`anchor`, one pass per distinct key
+    among the constraints).
   * Per-node skew is then a gather at each node's domain value; global
     min/max match counts reduce over the domain values PRESENT among
     valid nodes (upstream's "global minimum matchNum"; domains are
@@ -52,7 +52,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .affinity import _anchored_hit, _eval_programs, node_counts, node_domains
+from .affinity import _eval_programs, anchor, node_counts, node_domains
 from .encoding import NodeTensors, PodBatch, PodMatrix
 
 
@@ -75,14 +75,14 @@ def topo_statics(nt: NodeTensors, pm: PodMatrix, pb: PodBatch,
     constraint row (upstream counts only the constraint owner's
     namespace; a nil selector was featurized as OP_FALSE and matches
     nothing). Counts reduce the matches per node, then the nodes by
-    their domain value; `present` segment-reduces valid nodes themselves
+    their domain value; `present` anchors the valid nodes themselves
     so empty domains still participate in the min (upstream enumerates
     domains from the node list, not the pod list)."""
     P, TS = pb.ts_tk.shape
     N = nt.labels.shape[0]
     dom = node_domains(nt, pb.ts_tk)  # [P, TS, N]
     dom = dom * nt.valid[None, None, :]
-    dom_f = dom.reshape(P * TS, N)
+    tk_f = pb.ts_tk.reshape(P * TS)
 
     live = pb.ts_valid[:, :, None]  # [P, TS, 1]
     sel = _eval_programs(pm.labels, pb.ts_key, pb.ts_op, pb.ts_vals)  # [P, TS, M]
@@ -91,10 +91,12 @@ def topo_statics(nt: NodeTensors, pm: PodMatrix, pb: PodBatch,
     M = pm.labels.shape[0]
     with jax.named_scope("affinity_anchor"):
         cnt_n = node_counts(match.reshape(P * TS, M), pm.node, N)
-        counts = _anchored_hit(cnt_n, dom_f, num_label_values, count=True)
-        present = _anchored_hit(
-            jnp.broadcast_to(nt.valid[None, :], (P * TS, N)), dom_f,
-            num_label_values)
+        # only valid nodes belong to a domain
+        counts = anchor(cnt_n * nt.valid[None, :], tk_f, nt.labels,
+                        num_label_values, count=True)
+        present = anchor(
+            jnp.broadcast_to(nt.valid[None, :], (P * TS, N)), tk_f,
+            nt.labels, num_label_values)
 
     wsel = _eval_programs(pb.pl_val, pb.ts_key, pb.ts_op, pb.ts_vals)  # [P, TS, P]
     wave_ns = (pb.ns_id[None, None, :] == pb.ns_id[:, None, None])

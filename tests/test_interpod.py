@@ -320,6 +320,72 @@ def test_interpod_priority_parity(seed):
                 f"device={dev} ({c[ni_idx]}) golden={gold_scores[node.name]}")
 
 
+RACK = api.LABEL_RACK
+
+
+def anchoring_world(on_node, in_zone, n_nodes=12):
+    """Nodes under three topology keys (hostname, zone z0-z2, rack r0-r3)
+    with `on_node` pods of group g0 on n0 and `in_zone` pods of group g1
+    spread over zone z1's nodes, and pending pods whose required and
+    preferred terms use all three keys, so one wave's unique tables hold
+    three keys. Odd counts past 256 are what bf16 cannot hold."""
+    nodes = [make_node(f"n{i}", cpu="64", memory="256Gi", pods=1000,
+                       labels={HOSTNAME: f"n{i}", ZONE: f"z{i % 3}",
+                               RACK: f"r{i % 4}"})
+             for i in range(n_nodes)]
+    z1 = [f"n{i}" for i in range(n_nodes) if i % 3 == 1]
+    existing = [make_pod(f"a{i}", labels={"grp": "g0"}, node_name="n0")
+                for i in range(on_node)]
+    existing += [make_pod(f"b{i}", labels={"grp": "g1"},
+                          node_name=z1[i % len(z1)])
+                 for i in range(in_zone)]
+    existing += [make_pod(f"c{i}", labels={"grp": "g2"},
+                          node_name=f"n{i % n_nodes}") for i in range(7)]
+    pods = []
+    for i, tk in enumerate((HOSTNAME, ZONE, RACK)):
+        for j, grp in enumerate(("g0", "g1", "g2")):
+            term = aff_term({"grp": grp}, tk)
+            aff = (pod_affinity(required=[term],
+                                preferred=[(7 + j, aff_term({"grp": grp}, tk))])
+                   if (i + j) % 2 else
+                   pod_anti_affinity(required=[term],
+                                     preferred=[(13 + i, term)]))
+            pods.append(make_pod(f"p{i}{j}", labels={"grp": grp},
+                                 affinity=aff))
+    return nodes, existing, pods
+
+
+@pytest.mark.parametrize("on_node,in_zone", [
+    pytest.param(5, 9, id="three-keys"),
+    pytest.param(301, 9, id="node-past-256"),
+    pytest.param(5, 333, id="zone-past-256")])
+def test_anchoring_matches_twin(on_node, in_zone):
+    """The device's anchoring of matches at node domains (exact MXU
+    contractions) equals the twin's per-pod bincount in every
+    IncomingStatics plane, with three topology keys in the wave's
+    tables and counts past bf16's exact integer range."""
+    from kubernetes_tpu.ops import hostwave
+    from kubernetes_tpu.ops.affinity import incoming_statics
+
+    nodes, existing, pods = anchoring_world(on_node, in_zone)
+    cache, snap = build(nodes, existing)
+    pb = PodFeaturizer(snap, group_selectors=lambda p: []).featurize(pods)
+    keys = {snap.label_key_col(k) for k in (HOSTNAME, ZONE, RACK)}
+    assert set(np.asarray(pb.iu_tk)) - {0} == keys
+    assert set(np.asarray(pb.pu_tk)) - {0} == keys
+    lv = snap.num_label_values
+    nt, pm, tt = snap.to_device()
+    dev = incoming_statics(nt, pm, tt, pb, lv, 1.0)
+    nth, pmh, tth = snap.host_tensors()
+    host = hostwave.incoming_statics_host(nth, pmh, tth, pb, lv, 1.0)
+    assert np.any(host.ok_aff) and np.any(host.blocked_anti)
+    assert np.abs(host.counts).max() > 7 * max(on_node, in_zone)
+    for f in dev._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(dev, f)),
+                                      np.asarray(getattr(host, f)),
+                                      err_msg=f)
+
+
 # --- full scheduler path ------------------------------------------------------
 
 

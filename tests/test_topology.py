@@ -142,10 +142,46 @@ def anchor_world(seed, n_nodes=12, n_existing=40, n_pending=10):
     return store, sched, pending
 
 
+def heavy_world(seed, n_nodes=12):
+    """World for exact counts past bf16's integer range: 301 matching pods
+    on one node, 333 over the nodes of another zone, each below 256 (odd
+    counts past 256, which bf16 rounds), and constraints on the
+    hostname, zone and rack keys in one wave."""
+    rng = np.random.RandomState(seed)
+    store = ObjectStore()
+    sched = Scheduler(store, wave_size=16)
+    for i in range(n_nodes):
+        store.create("nodes", make_node(
+            f"n{i}", cpu="64", memory="256Gi", pods=1000,
+            labels={api.LABEL_HOSTNAME: f"n{i}", api.LABEL_ZONE: f"z{i % 3}",
+                    api.LABEL_RACK: f"r{i % 4}"}))
+    z1 = [f"n{i}" for i in range(1, n_nodes, 3)]
+    homes = ["n0"] * 301 + [z1[i % len(z1)] for i in range(333)]
+    for i, node in enumerate(homes):
+        store.create("pods", make_pod(f"ex-{i}", labels={"app": "a"},
+                                      node_name=node))
+    snap = sched.snapshot
+    live = snap.ep_valid[:snap._next_slot]
+    per_node = np.bincount(snap.ep_node[:snap._next_slot][live])
+    assert per_node.max() == 301 and per_node[per_node < 301].max() < 256
+    keys = (api.LABEL_HOSTNAME, api.LABEL_ZONE, api.LABEL_RACK)
+    pending = []
+    for i in range(9):
+        p = make_pod(f"pend-{i}", cpu="100m",
+                     labels={"app": rng.choice(["a", "b"])})
+        p.spec.topology_spread_constraints = [
+            _spread(key=keys[i % 3], max_skew=400, match={"app": "a"}),
+            _spread(key=keys[(i + 1) % 3], when=api.SCHEDULE_ANYWAY,
+                    match={"app": "a"})]
+        pending.append(p)
+    return store, sched, pending
+
+
 class TestStaticsParity:
     @pytest.mark.parametrize("seed,world", [
         *(pytest.param(s, topo_world, id=str(s)) for s in range(3)),
-        pytest.param(7, anchor_world, id="anchor-7")])
+        pytest.param(7, anchor_world, id="anchor-7"),
+        pytest.param(11, heavy_world, id="heavy-11")])
     def test_topo_statics_matches_host(self, seed, world):
         """The wave-start spread statics — per-pod node domains, resident
         counts per domain value, domain presence, wave match matrix, self
