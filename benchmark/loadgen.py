@@ -11,9 +11,17 @@ change with the seed.
 
 A configuration may declare its pod kinds (`kinds`: name, shape, and
 optionally requests and priority), a mix for its running pods apart from
-the backlog's (`resident_mix`) and where its running pods are put
-(`resident_placement`). Without them it has one kind of each shape,
-named after the shape, at `pod_requests`, and one mix for every pod.
+the backlog's (`resident_mix`), where its running pods are put
+(`resident_placement`) and labels its nodes carry beyond the hostname
+(`node_labels`). Without them it has one kind of each shape, named after
+the shape, at `pod_requests`, and one mix for every pod.
+
+A kind of shape `template` is a pod template of upstream's
+scheduler_perf: its `labels`, its `namespace` (else "default") and its
+`affinity`, a JSON transcription of the template's affinity block
+(podAffinity and podAntiAffinity, required and preferred, each term with
+labelSelector.matchLabels, topologyKey, namespaces and, where preferred,
+weight).
 """
 
 from __future__ import annotations
@@ -29,7 +37,13 @@ import numpy as np
 # hostname anti-affinity within a group; also the kinds, in this order,
 # of a configuration that declares none
 KINDS = ("density", "affinity", "antiaffinity")
+# ... and a pod template of upstream's scheduler_perf
+SHAPES = KINDS + ("template",)
 HOST_LABEL = "kubernetes.io/hostname"
+# the template affinity block's keys, as the Kubernetes API spells them
+REQUIRED = "requiredDuringSchedulingIgnoredDuringExecution"
+PREFERRED = "preferredDuringSchedulingIgnoredDuringExecution"
+TERM_KEYS = {"labelSelector", "topologyKey", "namespaces"}
 
 
 def kinds(cfg) -> List[dict]:
@@ -43,10 +57,51 @@ def kinds(cfg) -> List[dict]:
     if len(set(names)) != len(names):
         raise ValueError(f"kinds named twice: {names}")
     for k in out:
-        if k["shape"] not in KINDS:
+        if k["shape"] not in SHAPES:
             raise ValueError(f"kind {k['name']!r}: shape {k['shape']!r} "
-                             f"is none of {KINDS}")
+                             f"is none of {SHAPES}")
+        if k["shape"] != "template" and {"labels", "namespace",
+                                         "affinity"} & set(k):
+            raise ValueError(f"kind {k['name']!r}: labels, namespace and "
+                             "affinity belong to shape 'template'")
     return out
+
+
+def template_terms(kind: dict) -> List[tuple]:
+    """The inter-pod terms of a template kind's affinity block, each
+    (block, which, weight, term): block podAffinity or podAntiAffinity,
+    which REQUIRED or PREFERRED, weight None where required, term the
+    JSON term. Refuses what the block may not hold."""
+    aff = kind.get("affinity") or {}
+    if set(aff) - {"podAffinity", "podAntiAffinity"}:
+        raise ValueError(f"kind {kind['name']!r}: affinity holds "
+                         f"{sorted(aff)}; only podAffinity and "
+                         "podAntiAffinity are carried")
+    out = []
+    for block in ("podAffinity", "podAntiAffinity"):
+        b = aff.get(block) or {}
+        if set(b) - {REQUIRED, PREFERRED}:
+            raise ValueError(f"kind {kind['name']!r}: {block} holds "
+                             f"{sorted(b)}")
+        for t in b.get(REQUIRED, []):
+            out.append((block, REQUIRED, None, t))
+        for wt in b.get(PREFERRED, []):
+            out.append((block, PREFERRED, int(wt["weight"]),
+                        wt["podAffinityTerm"]))
+    for _, _, _, t in out:
+        sel = t.get("labelSelector")
+        if set(t) - TERM_KEYS or (sel is not None
+                                  and set(sel) - {"matchLabels"}):
+            raise ValueError(f"kind {kind['name']!r}: term {t} holds more "
+                             f"than {sorted(TERM_KEYS)} and matchLabels")
+    return out
+
+
+def term_count(kind: dict) -> int:
+    """Inter-pod (anti-)affinity terms a pod of `kind` carries."""
+    if kind["shape"] == "template":
+        return len(template_terms(kind))
+    return int(kind["shape"] == "antiaffinity")
 
 
 def node_name(i: int) -> str:
@@ -57,14 +112,19 @@ def build_cluster(store, cfg):
     """bench.py build_cluster: `nodes` Ready nodes, each with a hostname
     label and, where the configuration has them, one of
     `affinity_labels` aff-<k> labels; no zone label, as upstream's node
-    templates have none."""
+    templates have none. `node_labels` {key: [values]} gives node i
+    values[i % len] under each key, as scheduler_perf's
+    labelNodePrepareStrategy labels its nodes."""
     from kubernetes_tpu.api import types as api
 
     alloc = cfg["node_allocatable"]
+    node_labels = cfg.get("node_labels", {})
     for i in range(cfg["nodes"]):
         labels = {HOST_LABEL: node_name(i)}
         if cfg["affinity_labels"]:
             labels[f"aff-{i % cfg['affinity_labels']}"] = "yes"
+        for key, values in node_labels.items():
+            labels[key] = values[i % len(values)]
         store.create("nodes", api.Node(
             metadata=api.ObjectMeta(name=node_name(i), labels=labels),
             status=api.NodeStatus(
@@ -160,11 +220,27 @@ def resident_nodes(cfg, plan: PodPlan, n: int, seed: int) -> np.ndarray:
     (at most 6 pods a node on average, 40 fit) no node comes near it, and
     one past it would read as a violation. "even" puts pod i on node
     i mod nodes, where 1.11's LeastRequested puts identical pods on an
-    empty uniform cluster; the run checks it at set-up."""
+    empty uniform cluster; the run checks it at set-up. "reference"
+    places them one at a time, in plan order, from the empty cluster,
+    where the configuration's reference (1.11's filters, scores and
+    selectHost) puts them: where upstream's scheduler puts its
+    initPods."""
     placement = cfg.get("resident_placement", "drawn")
     nodes = cfg["nodes"]
     if placement == "even":
         return np.arange(n, dtype=np.int64) % nodes
+    if placement == "reference":
+        import check
+
+        ref = check.reference(cfg["reference"])
+        _, pod, node, _ = ref.greedy(ref.Cluster.from_config(cfg), plan,
+                                     np.arange(n))
+        if len(pod) != n:
+            raise ValueError(f"the reference places {len(pod)} of the "
+                             f"{n} running pods")
+        out = np.empty(n, np.int64)
+        out[pod] = node
+        return out
     if placement != "drawn":
         raise ValueError(f"resident_placement {placement!r}")
     rng = np.random.default_rng([seed, 4])
@@ -198,19 +274,63 @@ def base_pod(api, name, prefix, requests, labels=None, affinity=None):
                     requests=dict(requests)))]))
 
 
+def template_pod(api, name, kind: dict, requests):
+    """A pod of a template kind: its labels, namespace and inter-pod
+    terms, no owner (scheduler_perf creates its pods directly)."""
+    from kubernetes_tpu.api.labels import LabelSelector
+
+    def term(t):
+        sel = t.get("labelSelector")
+        return api.PodAffinityTerm(
+            label_selector=(None if sel is None else LabelSelector(
+                match_labels=dict(sel.get("matchLabels", {})))),
+            namespaces=list(t.get("namespaces", [])),
+            topology_key=t.get("topologyKey", ""))
+
+    blocks = {"podAffinity": api.PodAffinity(),
+              "podAntiAffinity": api.PodAntiAffinity()}
+    for block, which, weight, t in template_terms(kind):
+        b = blocks[block]
+        if which == REQUIRED:
+            b.required.append(term(t))
+        else:
+            b.preferred.append(api.WeightedPodAffinityTerm(
+                weight=weight, pod_affinity_term=term(t)))
+    aff = kind.get("affinity") or {}
+    affinity = None
+    if aff:
+        affinity = api.Affinity(
+            pod_affinity=blocks["podAffinity"] if "podAffinity" in aff
+            else None,
+            pod_anti_affinity=(blocks["podAntiAffinity"]
+                               if "podAntiAffinity" in aff else None))
+    return api.Pod(
+        metadata=api.ObjectMeta(
+            name=name, namespace=kind.get("namespace", "default"),
+            labels=dict(kind.get("labels", {}))),
+        spec=api.PodSpec(
+            affinity=affinity, tolerations=[],
+            containers=[api.Container(
+                resources=api.ResourceRequirements(
+                    requests=dict(requests)))]))
+
+
 def make_pod(api, plan: PodPlan, i: int, kind: dict, requests,
              prefix: str = "pod"):
     """Pod i of the plan, of `kind` (one of kinds(cfg)), shaped as
     bench.py make_pods shapes its kind: plain (density); required node
     affinity on aff-<k> (scheduler_test.go); required hostname
-    anti-affinity within its group (scheduler_bench_test.go). A kind with
-    a priority sets spec.priority."""
+    anti-affinity within its group (scheduler_bench_test.go); or as its
+    template declares it (template). A kind with a priority sets
+    spec.priority."""
     from kubernetes_tpu.api.labels import LabelSelector, Requirement
 
     shape = kind["shape"]
     tag = f"{kind['name']}-pod"
     name = f"{prefix}-{i}"
-    if shape == "density":
+    if shape == "template":
+        pod = template_pod(api, name, kind, requests)
+    elif shape == "density":
         pod = base_pod(api, name, tag, requests)
     elif shape == "affinity":
         aff = api.Affinity(node_affinity=api.NodeAffinity(

@@ -131,7 +131,7 @@ class EventLog:
     def __init__(self):
         self.rows: List[tuple] = []  # (op, t, pod, node, round, pos)
         self.n_binds = 0  # binds the scheduler made
-        self.live = OrderedDict()  # names of the bound pods, oldest first
+        self.live = OrderedDict()  # bound pods' name: namespace, oldest first
         self.nominated = {}  # plan index -> nominated node name
         self.evicted = []  # (plan index, node index) not yet refilled
         self.completing = False  # the harness is deleting pods
@@ -167,7 +167,7 @@ class EventLog:
         node = int(obj.spec.node_name[5:])
         self.rows.append((op, time.perf_counter(), i, node, rnd, pos))
         if op == BIND:
-            self.live[name] = None
+            self.live[name] = obj.metadata.namespace
         else:
             self.live.pop(name, None)
             if op == EVICT:
@@ -302,9 +302,10 @@ WARM_PODS = 512  # throwaway pods of the warm-up, more than a wave
 
 def build_scheduler(cfg, work, log, residents):
     """Store + scheduler (the program's own wave size and settings) with
-    caps sized for the running pods and the backlog (completions keep
-    the cluster at `resident`, so nothing grows, and nothing compiles,
-    inside the window); the cluster and the running pods, bound; and the
+    caps sized for the running pods and the backlog, and the term table
+    for every inter-pod term they carry (completions keep the cluster at
+    `resident`, so nothing grows, and nothing compiles, inside the
+    window); the cluster and the running pods, bound; and the
     cell's round programs warmed on throwaway pods of the run's own
     mix."""
     import jax
@@ -315,10 +316,10 @@ def build_scheduler(cfg, work, log, residents):
     from kubernetes_tpu.state.vocab import bucket_size
 
     held = cfg["resident"] + cfg["backlog"] + WARM_PODS
-    anti = anti_share(cfg, cfg["mix"])
-    terms = anti * held
+    per_pod = term_share(cfg, cfg["mix"])
+    terms = per_pod * held
     if "resident_mix" in cfg:
-        terms += (anti_share(cfg, cfg["resident_mix"]) - anti) \
+        terms += (term_share(cfg, cfg["resident_mix"]) - per_pod) \
             * cfg["resident"]
     n_terms = int(math.ceil(terms))
     caps = Caps(M=bucket_size(held),
@@ -338,15 +339,15 @@ def build_scheduler(cfg, work, log, residents):
         for w in work["warm_waves"]:
             sched.warm_pipeline(warm, n_waves=w)
     for p in warm:
-        store.delete("pods", "default", p.metadata.name)
+        store.delete("pods", p.metadata.namespace, p.metadata.name)
     return store, sched
 
 
-def anti_share(cfg, mix: dict) -> float:
-    """Share of a mix's pods that carry an anti-affinity term."""
-    shape = {k["name"]: k["shape"] for k in loadgen.kinds(cfg)}
-    return sum(w for k, w in mix.items()
-               if shape[k] == "antiaffinity") / sum(mix.values())
+def term_share(cfg, mix: dict) -> float:
+    """Inter-pod (anti-)affinity terms a pod of the mix carries, on
+    average: each one a row of the program's term table."""
+    terms = {k["name"]: loadgen.term_count(k) for k in loadgen.kinds(cfg)}
+    return sum(w * terms[k] for k, w in mix.items()) / sum(mix.values())
 
 
 def complete(ctx):
@@ -371,11 +372,11 @@ def complete(ctx):
         try:
             if done is None:
                 while len(live) > keep:
-                    ctx.store.delete("pods", "default",
-                                     live.popitem(last=False)[0])
+                    name, ns = live.popitem(last=False)
+                    ctx.store.delete("pods", ns, name)
             else:
                 for name in done:
-                    ctx.store.delete("pods", "default", name)
+                    ctx.store.delete("pods", live[name], name)
         finally:
             log.completing = False
 

@@ -4,7 +4,10 @@ offers a slowed scheduler the same due times, and no result without a
 TPU or without the program. A preemption cell (tiny-preempt: declared
 kinds and priorities, even residency, completions by kind, refills of
 evicted pods) keeps its invariants, and the plans of the benchmark's own
-cells stay what they were before configurations could declare kinds.
+cells stay what they were before configurations could declare kinds. A
+cell of template kinds (tiny-ipa: namespaces, node labels, running pods
+placed by the reference) runs through the harness with pods in their own
+namespaces and nothing compiled in the window.
 
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
 """
@@ -174,6 +177,70 @@ def test_plan_pods_and_residents_pinned(cell, seed, n, digest):
     for p in pods:
         h.update(re.sub(r"uid='uid-\d+'", "", repr(p)).encode())
     assert (len(rp.plan), h.hexdigest()) == (n, digest)
+
+
+# (seed, plan length, digest) of tiny-ipa's plan at a 2-s window, as
+# PINNED is taken
+PINNED_TEMPLATE = [
+    (7, 8480,
+     "b4368e656160ddd729e4c0ce80cf598668e64ae5087609e9f2b28534a796a0df"),
+    (4200002401, 8480,
+     "1eb88310b54c2896a1f3a4109d68d37fe2c67d73136f8041fa822aa334d9b9cd"),
+]
+
+
+@pytest.mark.parametrize("seed,n,digest", PINNED_TEMPLATE)
+def test_template_plan_and_residents_pinned(seed, n, digest):
+    c = tiny("tiny-ipa")
+    rp = run.plan_run(c, seed, 2.0)
+    pods = loadgen.build_pods(c["config"], rp.plan[:PINNED_PODS])
+    h = hashlib.sha256()
+    for a in (rp.plan.kind, rp.plan.aff, rp.plan.group):
+        h.update(np.ascontiguousarray(a, np.int32).tobytes())
+    h.update(np.ascontiguousarray(rp.res_nodes, np.int64).tobytes())
+    for p in pods:
+        h.update(re.sub(r"uid='uid-\d+'", "", repr(p)).encode())
+    assert (len(rp.plan), h.hexdigest()) == (n, digest)
+
+
+def test_template_cell_runs_in_its_namespaces(monkeypatch):
+    from kubernetes_tpu.runtime.store import ObjectStore
+
+    made, gone = {}, []
+    real_create, real_delete = ObjectStore.create, ObjectStore.delete
+
+    def create(self, kind, obj):
+        if kind == "pods":
+            made[obj.metadata.name] = obj.metadata.namespace
+        return real_create(self, kind, obj)
+
+    def delete(self, kind, namespace, name):
+        if kind == "pods":
+            gone.append((namespace, name))
+        return real_delete(self, kind, namespace, name)
+
+    monkeypatch.setattr(ObjectStore, "create", create)
+    monkeypatch.setattr(ObjectStore, "delete", delete)
+    seen = observe(monkeypatch)
+    cell = tiny("tiny-ipa")
+    out = run.run(cell, 4200003021, 2.0, False, require_tpu=False)
+    assert out["info"]["window_compiles"] == 0
+    checks = {k: c["value"] for k, c in out["checks"].items()}
+    assert (checks["violations"], checks["lost"], checks["fallbacks"]) \
+        == (0, 0, 0)
+    assert out["info"]["checked"] > 0
+    # every pod in its kind's namespace, both of them used, and every
+    # pod the harness deleted (warm-up pods, completions) deleted there
+    ks = loadgen.kinds(cell["config"])
+    plan = run.plan_run(cell, 4200003021, 2.0).plan
+    for name, ns in made.items():
+        if name.startswith("pod-"):
+            assert ns == ks[plan.kind[int(name[4:])]]["namespace"], name
+    assert {"sched-0", "sched-1"} <= set(made.values())
+    assert gone and all(made[name] == ns for ns, name in gone)
+    completed = int(np.sum(seen["r"].log["op"] == COMPLETE))
+    assert completed > 0
+    assert len([n for _, n in gone if n.startswith("pod-")]) == completed
 
 
 def test_replay_skips_nominations():
